@@ -10,7 +10,8 @@ and the shared AOT executable cache, then announces readiness as one
 JSON line on stdout::
 
     {"event": "ready", "replica_id": ..., "port": ..., "ready_s": ...,
-     "restore_outcome": ..., "templates": N}
+     "restore_outcome": ..., "templates": N,
+     "device": {"platform": ..., "device_kind": ..., "count": N}}
 
 and serves until stdin closes (the parent dropping its pipe is the stop
 signal — no PID files, no signal races) or SIGTERM.
@@ -74,8 +75,9 @@ def _child_parser() -> argparse.ArgumentParser:
                    help="webhook port (0 = ephemeral, announced on stdout)")
     p.add_argument("--snapshot-dir", default="",
                    help="shared warm snapshot dir (restored, never written)")
-    p.add_argument("--xla-cache-dir", default="",
-                   help="shared XLA + AOT executable cache dir")
+    p.add_argument("--xla-cache-dir", default=None,
+                   help="shared XLA + AOT executable cache dir (default: "
+                        "the App's — ops/xlacache.resolve_cache_dir)")
     p.add_argument("--driver", choices=["interp", "tpu"], default="tpu")
     p.add_argument("--webhook-batch-static", action="store_true")
     p.add_argument("--webhook-max-pending", type=int, default=None,
@@ -317,7 +319,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if args.snapshot_dir:
         flags += ["--snapshot-dir", args.snapshot_dir,
                   "--snapshot-no-resync"]
-    if args.xla_cache_dir:
+    if args.xla_cache_dir is not None:
         flags += ["--xla-cache-dir", args.xla_cache_dir]
     if args.webhook_batch_static:
         flags += ["--webhook-batch-static"]
@@ -372,6 +374,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             "templates": len(app.client.templates()),
             "namespaces_seeded": seeded,
         }
+        if hasattr(drv, "device_info"):
+            # platform / device_kind / count of the backend this replica
+            # evaluates on (absent under --driver interp)
+            ready["device"] = drv.device_info()
         print(json.dumps(ready), flush=True)
         # serve until the parent closes our stdin (or EOF on a detached
         # run): the pipe IS the lifetime — a dead parent reaps the fleet.

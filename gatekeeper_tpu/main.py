@@ -81,11 +81,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--jax-profile-port", type=int, default=0,
                    help="start a jax.profiler server on this port "
                         "(0 = disabled; capture via TensorBoard)")
-    p.add_argument("--xla-cache-dir",
-                   default=os.environ.get("GK_XLA_CACHE", ""),
+    from .ops.xlacache import resolve_cache_dir
+
+    p.add_argument("--xla-cache-dir", default=resolve_cache_dir(),
                    help="persistent XLA compilation cache directory: a "
                         "restarted pod reloads its fused executables from "
-                        "disk instead of recompiling (empty = disabled)")
+                        "disk instead of recompiling (default: "
+                        "<checkout>/.xla-cache; $JAX_COMPILATION_CACHE_DIR "
+                        "wins over this flag; empty = disabled)")
     # operations.go:77
     p.add_argument("--operation", action="append", default=[],
                    choices=list(ops_mod.ALL_OPERATIONS),
@@ -477,15 +480,10 @@ class App:
             level_key=getattr(args, "log_level_key", "level"),
             level_encoder=getattr(args, "log_level_encoder", "lower"),
         )
-        if getattr(args, "xla_cache_dir", ""):
-            from .ops.aotcache import enable as enable_aot_cache
-            from .ops.xlacache import enable as enable_xla_cache
+        if args.driver == "tpu":
+            from .ops.xlacache import enable_caches
 
-            enable_xla_cache(args.xla_cache_dir)
-            # serialized-executable cache rides in a subdir: it is what
-            # lets a warm restart skip the fused programs' TRACE time,
-            # which the XLA compile cache alone cannot save
-            enable_aot_cache(os.path.join(args.xla_cache_dir, "aot"))
+            enable_caches(getattr(args, "xla_cache_dir", None))
         if getattr(args, "debug_use_fake_pod", False):
             # run outside Kubernetes: fixed pod identity, no owner refs on
             # status CRs (controller.go:133-142)
@@ -716,6 +714,7 @@ class App:
         from .obs import slo as obsslo
 
         breaker_fn = getattr(self.client.driver, "breaker_status", None)
+        device_fn = getattr(self.client.driver, "device_info", None)
         slo_engine = obsslo.get_engine()
         from .obs import brownout as obsbrownout
 
@@ -726,6 +725,8 @@ class App:
                   "brownout": brownout_ctl.status()}
             if breaker_fn is not None:
                 st["tpu_breaker"] = breaker_fn()
+            if device_fn is not None:
+                st["device"] = device_fn()
             return st
 
         if getattr(args, "slo_trip_breaker", False):

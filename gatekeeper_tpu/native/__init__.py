@@ -11,6 +11,7 @@ when the extension can't be built (CI lane for the native path).
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -26,25 +27,36 @@ _SRC = os.path.join(os.path.dirname(__file__), "_gknative.cpp")
 
 
 def _so_path() -> str:
+    """The built file is keyed by a hash of its SOURCE: a copied tree
+    (file copies keep no meaningful mtimes) can then never load a binary
+    built from another revision of _gknative.cpp — a stale name simply
+    does not exist and the build runs."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
     suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    return os.path.join(os.path.dirname(__file__), f"_gknative{suffix}")
+    return os.path.join(
+        os.path.dirname(__file__), f"_gknative-{digest}{suffix}")
 
 
 def build(force: bool = False) -> str:
     """Compile the extension with g++; returns the .so path."""
     so = _so_path()
-    if (
-        not force
-        and os.path.exists(so)
-        and os.path.getmtime(so) >= os.path.getmtime(_SRC)
-    ):
+    if not force and os.path.exists(so):
         return so
     include = sysconfig.get_paths()["include"]
+    # build beside the target and rename: concurrent first loads (a
+    # fleet of replicas on a fresh checkout) never see a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-        f"-I{include}", _SRC, "-o", so,
+        f"-I{include}", _SRC, "-o", tmp,
     ]
-    subprocess.run(cmd, check=True, capture_output=True)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return so
 
 

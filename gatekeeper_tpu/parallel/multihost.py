@@ -156,9 +156,7 @@ def multihost_capped_sweep(driver, K: int):
             jax.tree_util.tree_map(lambda a: row_spec(a), cols_g),
             jax.tree_util.tree_map(lambda a: repl, gp_g),
         )
-        from ..util.jaxcompat import shard_map
-
-        sharded = jax.jit(shard_map(
+        sharded = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=repl,
             check_vma=False,
         ))

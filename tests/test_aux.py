@@ -408,5 +408,66 @@ class TestXlaCache:
 
     def test_flag_wires_cache(self, tmp_path, monkeypatch):
         from gatekeeper_tpu.main import build_parser
+        from gatekeeper_tpu.ops import xlacache
+
+        monkeypatch.delenv(xlacache.ENV_VAR, raising=False)
         args = build_parser().parse_args(["--xla-cache-dir", str(tmp_path)])
         assert args.xla_cache_dir == str(tmp_path)
+        assert xlacache.resolve_cache_dir(args.xla_cache_dir) == str(tmp_path)
+        # an explicit empty flag still means "no cache"
+        assert xlacache.resolve_cache_dir("") == ""
+
+    def test_variable_wins_and_is_left_untouched(self, tmp_path,
+                                                 monkeypatch):
+        """$JAX_COMPILATION_CACHE_DIR set: that directory is the cache —
+        over the flag too — and the code never points jax at one (jax
+        read the variable itself)."""
+        import jax
+        from gatekeeper_tpu.main import build_parser
+        from gatekeeper_tpu.ops import xlacache
+
+        env_dir = str(tmp_path / "from-env")
+        monkeypatch.setenv(xlacache.ENV_VAR, env_dir)
+        assert xlacache.resolve_cache_dir() == env_dir
+        assert xlacache.resolve_cache_dir(str(tmp_path / "flag")) == env_dir
+        assert build_parser().parse_args([]).xla_cache_dir == env_dir
+        prior = jax.config.jax_compilation_cache_dir
+        knobs = (jax.config.jax_persistent_cache_min_entry_size_bytes,
+                 jax.config.jax_persistent_cache_min_compile_time_secs)
+        try:
+            assert xlacache.enable(env_dir) is True
+            assert jax.config.jax_compilation_cache_dir == prior
+        finally:
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes", knobs[0])
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", knobs[1])
+            xlacache._enabled_dir = None
+
+    def test_unset_resolves_to_the_checkout(self):
+        """Unset: <checkout>/.xla-cache — asked of a fresh process (this
+        session's conftest blanks the default for test isolation), from a
+        cwd that is NOT the checkout."""
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["PYTHONPATH"] = repo
+        code = (
+            "import sys\n"
+            "from gatekeeper_tpu.ops.xlacache import resolve_cache_dir\n"
+            "from gatekeeper_tpu.main import build_parser\n"
+            "print(resolve_cache_dir())\n"
+            "print(build_parser().parse_args([]).xla_cache_dir)\n"
+            "assert 'jax' not in sys.modules\n"
+        )
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, cwd="/",
+            capture_output=True, text=True, timeout=120,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        want = os.path.join(repo, ".xla-cache")
+        assert out.stdout.split() == [want, want]

@@ -213,3 +213,41 @@ def test_uncapped_audit_incremental_after_churn():
     ct.driver._audit_cache = None
     _r, _o, fresh = ct.driver._audit_masks()
     assert (st.host_mask == fresh).all()
+
+
+def test_status_write_back_keeps_the_delta_basis():
+    """An all-roles pod's audit writes every constraint's status each
+    interval, and the MODIFIED event comes back through the constraint
+    controller as add_constraint(same spec, new status/resourceVersion).
+    That must not bump the constraint-side epoch (the reference's
+    constraintSemanticEquals: spec + labels) — or no sweep of a deployed
+    pod is ever a delta sweep; a spec change still invalidates."""
+    import copy
+
+    ct, _ci = _pair()
+    ct.audit_capped(5)
+    driver = ct.driver
+    epoch = driver._cs_epoch
+    _templates, constraints = make_templates(8)
+    written = copy.deepcopy(constraints[0])
+    written["metadata"]["resourceVersion"] = "4711"
+    written["status"] = {"auditTimestamp": "2026-01-01T00:00:00Z",
+                         "totalViolations": 3,
+                         "violations": [{"kind": "Pod", "name": "p"}]}
+    ct.add_constraint(written)
+    assert driver._cs_epoch == epoch
+
+    newp = make_pods(1, seed=900, violation_rate=1.0)[0]
+    newp["metadata"]["name"] = "after-status-write"
+    ct.add_data(newp)
+    ct.audit_capped(5)
+    assert driver.last_sweep_stats.get("delta_rows") == 1.0
+
+    relabeled = copy.deepcopy(written)
+    relabeled["metadata"]["labels"] = {"tier": "gold"}
+    ct.add_constraint(relabeled)
+    assert driver._cs_epoch == epoch + 1
+    respecced = copy.deepcopy(relabeled)
+    respecced["spec"]["enforcementAction"] = "dryrun"
+    ct.add_constraint(respecced)
+    assert driver._cs_epoch == epoch + 2

@@ -400,7 +400,11 @@ class TestFullStackOverHTTP:
                 while time.monotonic() < deadline:
                     st = admin.get(CGVK, "ns-must-have-gk").get(
                         "status") or {}
-                    if st.get("violations"):
+                    # wait for the sweep that SAW the new namespace: an
+                    # earlier sweep's status (gatekeeper-system alone)
+                    # may already be there
+                    if any(v["name"] == "unlabeled"
+                           for v in st.get("violations", [])):
                         break
                     time.sleep(0.1)
                 assert any(v["name"] == "unlabeled"

@@ -326,8 +326,8 @@ class TestMicroBatcher:
         orig = client.review_batch
 
         def counting_slow_batch(objs, tracing=False):
-            # batching matters when evaluation is slow (a device dispatch
-            # behind a network relay); with instant evals a concurrent
+            # batching matters when evaluation is slow (a device
+            # dispatch); with instant evals a concurrent
             # burst legitimately serializes through the idle fast path
             calls.append(len(objs))
             time.sleep(0.01)

@@ -143,9 +143,9 @@ def run_checks(edge: str = "both") -> list:
     problems: list = []
     root = tempfile.mkdtemp(prefix="gk-fleet-parity-")
     snap_dir = os.path.join(root, "snap")
-    cache_dir = os.path.join(root, "cache")
+    # no cache dir is handed to the replicas: each resolves the fixed one
+    # itself (ops/xlacache.py) — a directory that moves never hits
     os.makedirs(snap_dir)
-    os.makedirs(cache_dir)
     solo = None
     fleet = []
     door = None
@@ -159,8 +159,8 @@ def run_checks(edge: str = "both") -> list:
         oracle_verdicts = _oracle_verdicts(reqs)
 
         env = {"JAX_PLATFORMS": "cpu"}
-        solo = spawn_replica("solo", snap_dir, cache_dir, env=env)
-        fleet = spawn_fleet(2, snapshot_dir=snap_dir, cache_dir=cache_dir,
+        solo = spawn_replica("solo", snap_dir, env=env)
+        fleet = spawn_fleet(2, snapshot_dir=snap_dir,
                             env=env)
         for h in [solo] + fleet:
             if h.ready.get("restore_outcome") != "restored":

@@ -222,9 +222,9 @@ def run_checks(edge: str = "evloop") -> list:
     problems: list = []
     root = tempfile.mkdtemp(prefix="gk-overload-")
     snap_dir = os.path.join(root, "snap")
-    cache_dir = os.path.join(root, "cache")
+    # no cache dir is handed to the replicas: each resolves the fixed one
+    # itself (ops/xlacache.py) — a directory that moves never hits
     os.makedirs(snap_dir)
-    os.makedirs(cache_dir)
     handles: list = []
     try:
         client = build_driver(N_TEMPLATES, N_RESOURCES)
@@ -236,7 +236,7 @@ def run_checks(edge: str = "evloop") -> list:
         bodies = [json.dumps({"request": r}).encode() for r in reqs]
 
         handles = spawn_fleet(
-            2, snapshot_dir=snap_dir, cache_dir=cache_dir,
+            2, snapshot_dir=snap_dir,
             env={"JAX_PLATFORMS": "cpu"},
             extra_flags=["--webhook-max-pending", str(MAX_PENDING)],
         )

@@ -120,9 +120,9 @@ def run_checks() -> list:
     problems: list = []
     root = tempfile.mkdtemp(prefix="gk-self-heal-")
     snap_dir = os.path.join(root, "snap")
-    cache_dir = os.path.join(root, "cache")
+    # no cache dir is handed to the replicas: each resolves the fixed one
+    # itself (ops/xlacache.py) — a directory that moves never hits
     os.makedirs(snap_dir)
-    os.makedirs(cache_dir)
     sup = None
     door = None
     try:
@@ -145,7 +145,7 @@ def run_checks() -> list:
                 d.set_backend(rid, backend["host"], backend["port"])
 
         sup = ReplicaSupervisor(
-            snapshot_dir=snap_dir, cache_dir=cache_dir,
+            snapshot_dir=snap_dir,
             env={"JAX_PLATFORMS": "cpu"},
             heartbeat_s=0.25, miss_threshold=2, backoff_base_s=0.1,
             on_backend_change=on_change,
