@@ -9,11 +9,13 @@ regolib/src.go:13-19, Reset and Dump — over the Driver seam.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 from ..apis.templates import ConstraintTemplate, TemplateError
 from ..engine.interp import TemplatePolicy
+from ..obs import trace as obstrace
 from ..rego.ast import RegoError
 from ..target.target import K8sValidationTarget, WipeData
 from . import crd as crdlib
@@ -172,12 +174,23 @@ class Client:
     # ---- data -------------------------------------------------------------
 
     def add_data(self, obj: Any):
-        handled, segments, data = self.target.process_data(obj)
-        if not handled:
-            raise ClientError("data not handled by target")
-        if data is None:
-            raise ClientError("cannot add WipeData")
-        self.driver.put_data(segments, data)
+        # `ingest` on the calling thread's audit stage clock (obs/trace.py)
+        clock = obstrace.stage_clock(obstrace.PATH_AUDIT)
+        own = clock.stage is None
+        if own:
+            clock.mark("ingest")
+        try:
+            handled, segments, data = self.target.process_data(obj)
+            if not handled:
+                raise ClientError("data not handled by target")
+            if data is None:
+                raise ClientError("cannot add WipeData")
+            self.driver.put_data(segments, data)
+        finally:
+            # a thread that only ingests (a watch) never sweeps: its
+            # clock reaches the counters here
+            if own:
+                clock.flush_due(clock.stop())
 
     def remove_data(self, obj: Any) -> bool:
         handled, segments, _data = self.target.process_data(obj)
@@ -196,34 +209,47 @@ class Client:
     def review_batch(self, objs: List[Any], tracing: bool = False) -> List[Responses]:
         """Batched review: one driver dispatch for N review inputs (the
         webhook micro-batching path)."""
-        reviews = []
-        for obj in objs:
-            handled, review = self.target.handle_review(obj)
-            if not handled:
-                raise ClientError("review input not handled by target")
-            reviews.append(review)
-        out = []
-        for review, (results, trace) in zip(
-            reviews, self.driver.review_batch(reviews, tracing=tracing)
-        ):
-            self._rebuild_resources(results)
-            out.append(
-                Responses(
-                    by_target={
-                        self.target.name: Response(
-                            target=self.target.name,
-                            results=results,
-                            trace=trace,
-                            input=review if tracing else None,
-                        )
-                    }
+        # the batch stage clock of this thread (obs/trace.py): the
+        # batcher loop's when it called, else started and stopped here
+        # (direct callers, the inline fast path)
+        clock = obstrace.stage_clock(obstrace.PATH_BATCH)
+        own = clock.stage is None
+        if own:
+            clock.mark("collect")
+        try:
+            reviews = []
+            for obj in objs:
+                handled, review = self.target.handle_review(obj)
+                if not handled:
+                    raise ClientError("review input not handled by target")
+                reviews.append(review)
+            evaled = self.driver.review_batch(reviews, tracing=tracing)
+            clock.mark("render")  # resources rebuilt, Responses made
+            out = []
+            for review, (results, trace) in zip(reviews, evaled):
+                self._rebuild_resources(results)
+                out.append(
+                    Responses(
+                        by_target={
+                            self.target.name: Response(
+                                target=self.target.name,
+                                results=results,
+                                trace=trace,
+                                input=review if tracing else None,
+                            )
+                        }
+                    )
                 )
-            )
-        return out
+            return out
+        finally:
+            if own:
+                clock.flush_due(clock.stop())
 
     def audit(self, tracing: bool = False) -> Responses:
-        results, trace = self.driver.audit(tracing=tracing)
-        return self._audit_responses(results, trace)
+        with self._sweep_clock() as clock:
+            results, trace = self.driver.audit(tracing=tracing)
+            clock.mark("cap")
+            return self._audit_responses(results, trace)
 
     def audit_capped(self, cap: int, tracing: bool = False):
         """Audit keeping at most `cap` violations per constraint, with
@@ -232,8 +258,41 @@ class Client:
         On the TPU driver the host render walks the device candidate mask
         and stops at cap per constraint (the --constraint-violations-limit
         write-back never needs more)."""
-        results, totals, trace = self.driver.audit_capped(cap, tracing=tracing)
-        return self._audit_responses(results, trace), totals
+        with self._sweep_clock() as clock:
+            results, totals, trace = self.driver.audit_capped(
+                cap, tracing=tracing)
+            clock.mark("cap")
+            return self._audit_responses(results, trace), totals
+
+    @contextlib.contextmanager
+    def _sweep_clock(self):
+        """A sweep under the sweeping thread's audit stage clock
+        (obs/trace.py StageClock, path `audit`): it opens in `pack`, the
+        driver marks the rest, the caller marks `cap`.  Nested in a
+        running clock it is the no-op clock: the outer call owns it."""
+        clock = obstrace.stage_clock(obstrace.PATH_AUDIT)
+        if clock.stage is not None:
+            yield obstrace.NOOP_CLOCK
+            return
+        clock.mark("pack")
+        try:
+            yield clock
+        finally:
+            self._sweep_done(clock)
+
+    def _sweep_done(self, clock) -> None:
+        """Stop the sweep's clock, flush it to the counters, and add to
+        the driver's last_sweep_stats what only the clock knows: this
+        thread's `ingest` since its previous sweep, the `cap` stage, and
+        the collector's pauses inside the sweep's stages."""
+        clock.stop()
+        rows, gc_full_s = clock.lapse()
+        stats = getattr(self.driver, "last_sweep_stats", None)
+        if isinstance(stats, dict) and stats:
+            stats["ingest_ms"] = rows.get("ingest", (0.0,))[0] * 1e3
+            stats["cap_ms"] = rows.get("cap", (0.0,))[0] * 1e3
+            stats["gc_full_ms"] = gc_full_s * 1e3
+        clock.flush()
 
     def _rebuild_resources(self, results):
         """handle_violation deep-copies the object out of the review

@@ -225,28 +225,30 @@ class RetryBudget:
             return self._tokens
 
 
-class _StageClock:
-    """Contiguous wire-stage stopwatch: ``mark(stage)`` closes the
-    currently-open interval at *now*, records it as a stage span (under
-    the active wire trace) plus a ``frontdoor_stage_seconds`` sample,
-    and opens the next interval.  Adjacent by construction — stage
-    durations sum to the wire duration exactly, which is the bench's
-    no-dark-time criterion: every microsecond of the wire path lands in
-    SOME stage, bookkeeping included, instead of leaking between
-    bracketed measurements."""
+class _StageClock(obstrace.StageClock):
+    """Contiguous wire-stage stopwatch — the door's per-request use of
+    the shared obs/trace.py StageClock: ``mark(stage)`` closes the
+    currently-open interval at *now* AS ``stage`` (the door learns what
+    an interval was at its end, so this is the clock's ``lap``), records
+    it as a ``wire.<stage>`` stage span (under the active wire trace)
+    plus a ``frontdoor_stage_seconds`` sample, and opens the next
+    interval.  Adjacent by construction — stage durations sum to the
+    wire duration exactly, which is the bench's no-dark-time criterion:
+    every microsecond of the wire path lands in SOME stage, bookkeeping
+    included, instead of leaking between bracketed measurements."""
 
-    __slots__ = ("t",)
+    __slots__ = ()
+
+    SPAN_PREFIX = ""      # path "wire": spans stay wire.<stage>
+    STAGE_ATTR = True
 
     def __init__(self, start: float):
-        self.t = start
+        super().__init__("wire", start=start)
 
-    def mark(self, stage: str, **attrs) -> float:
-        now = time.perf_counter()
-        obstrace.record_span("wire." + stage, self.t, now, stage=stage,
-                             **attrs)
-        record_frontdoor_stage(stage, now - self.t)
-        self.t = now
-        return now
+    def _account(self, stage: str, seconds: float) -> None:
+        record_frontdoor_stage(stage, seconds)
+
+    mark = obstrace.StageClock.lap
 
 
 class Backend:

@@ -62,7 +62,36 @@ class _DoorConn(Conn):
         self.decoder = wireproto.FrameDecoder()
         super().__init__(loop, sock)
 
+    # the loop thread's share of the wire clock: `read` (recv),
+    # `decode` (frame decode + handoff to the workers), `write`; the
+    # clock is stopped while the loop waits in select
+    def _readable(self) -> None:
+        clock = self.listener._loop_clock()
+        clock.mark("read")
+        try:
+            super()._readable()
+        finally:
+            clock.stop()
+
+    def _writable(self) -> None:
+        clock = self.listener._loop_clock()
+        clock.mark("write")
+        try:
+            super()._writable()
+        finally:
+            clock.stop()
+
+    def send_frame(self, data: bytes) -> None:
+        """A response frame, written on the loop thread."""
+        clock = self.listener._loop_clock()
+        clock.mark("write")
+        try:
+            self.write(data)
+        finally:
+            clock.stop()
+
     def on_bytes(self, data: bytes) -> None:
+        self.listener._loop_clock().mark("decode")
         self.listener._wire_note("bytes_in", len(data))
         try:
             chunks = self.decoder.feed(data)
@@ -126,6 +155,8 @@ class WireListener:
         self._wstats: dict = {}
         self._wrecs: list = []
         self._wflush_t = time.monotonic()
+        self._lclock = None   # the loop thread's wire clock (made there)
+        self._wclocks: list = []   # the workers' (flushed at stop)
 
     # ---- lifecycle -------------------------------------------------------
 
@@ -194,6 +225,12 @@ class WireListener:
 
     # ---- wire telemetry --------------------------------------------------
 
+    def _loop_clock(self):
+        clock = self._lclock
+        if clock is None:
+            clock = self._lclock = obstrace.stage_clock(obstrace.PATH_WIRE)
+        return clock
+
     def _wire_note(self, key: str, n: int) -> None:
         with self._mu:
             self._wstats[key] = self._wstats.get(key, 0) + n
@@ -205,6 +242,14 @@ class WireListener:
 
     def _flush_wire(self, force: bool = False) -> None:
         now = time.monotonic()
+        if force:
+            # stop(): loop and workers are gone; what their stage clocks
+            # still hold reaches the counters from this thread
+            for clock in [self._lclock] + self._wclocks:
+                if clock is not None:
+                    clock.flush()
+        elif self._lclock is not None:
+            self._lclock.flush_due(time.perf_counter())  # the tick hook
         with self._mu:
             if not self._wstats and not self._wrecs:
                 return
@@ -247,7 +292,7 @@ class WireListener:
 
     def _submit(self, conn: _DoorConn, records: list) -> None:
         try:
-            self._q.put_nowait((conn, records))
+            self._q.put_nowait((conn, records, time.perf_counter()))
         except queue.Full:
             # bounded handoff: shed the WHOLE chunk with explicit
             # overload verdicts — the same 200-wrapped 429 shape the
@@ -289,14 +334,22 @@ class WireListener:
     # ---- worker side -----------------------------------------------------
 
     def _worker(self) -> None:
+        # this worker's wire clock runs per chunk, dequeue -> response
+        # frame handed to the loop: decode (json.loads), prepare, wait,
+        # finalize, encode.  Blocked on the chunk queue it is stopped.
+        clock = obstrace.stage_clock(obstrace.PATH_WIRE)
+        self._wclocks.append(clock)
         while True:
             item = self._q.get()
             if item is None or self._stop.is_set():
                 return
-            conn, records = item
+            conn, records, t_put = item
+            # `queued`: the chunk's wait for a free worker, booked beside
+            # the stages (it is no thread's time)
+            clock.add(obstrace.QUEUED, clock.mark("decode") - t_put)
             try:
                 data = wireproto.encode_response_chunk(
-                    self._process(records))
+                    self._process(records, clock))
             except Exception:
                 # chunk processing or framing failed (e.g. amplified
                 # deny messages pushed the response payload over
@@ -319,7 +372,8 @@ class WireListener:
                         lambda c=conn: c.close(None))
                 else:
                     loop.call_soon_threadsafe(lambda c=conn, d=data:
-                                              c.write(d))
+                                              c.send_frame(d))
+            clock.flush_due(clock.stop())
 
     def _failure_chunk(self, records: list) -> Optional[bytes]:
         """Best-effort per-record 500s when whole-chunk processing
@@ -342,7 +396,10 @@ class WireListener:
             log.exception("wire failure-chunk fallback failed")
             return None
 
-    def _process(self, records: list) -> List[wireproto.ResponseRecord]:
+    def _process(self, records: list,
+                 clock=obstrace.NOOP_CLOCK) -> List[wireproto.ResponseRecord]:
+        """One request chunk -> its response records.  ``clock`` is the
+        calling worker's wire clock, open in ``decode``."""
         out: List[Optional[wireproto.ResponseRecord]] = [None] * len(records)
         server = self.server
         stopping = bool(server is not None
@@ -353,8 +410,9 @@ class WireListener:
             self._deadline_budget_s if server is None
             else getattr(server, "deadline_budget_s", None)
         )
-        batch: List[tuple] = []   # (pos, req, deadline, span)
-        roots: dict = {}          # pos -> (rootctx, req)
+        # decode: every record's AdmissionReview parsed (the one
+        # json.loads of the whole wire path), refusals answered
+        parsed: List[tuple] = []  # (pos, rec, req)
         for pos, rec in enumerate(records):
             if stopping:
                 out[pos] = wireproto.ResponseRecord(
@@ -384,6 +442,14 @@ class WireListener:
                 out[pos] = wireproto.ResponseRecord(
                     rec.req_id, 200, _envelope(resp.to_dict(uid="")))
                 continue
+            parsed.append((pos, rec, req))
+        # prepare: budgets and root spans here, then handle_many's
+        # checks and review augmentation up to the batcher enqueue
+        # (handle_many marks wait / finalize on this thread's clock)
+        clock.mark("prepare")
+        batch: List[tuple] = []   # (pos, req, deadline, span)
+        roots: dict = {}          # pos -> (rootctx, req)
+        for pos, rec, req in parsed:
             budget = _deadline.effective_budget_s(
                 budget_default,
                 _deadline.parse_timeout_seconds(req),
@@ -416,11 +482,14 @@ class WireListener:
 
                 resps = [AdmissionResponse(False, str(e), 500)
                          for _ in batch]
+            clock.mark("encode")
             for (pos, req, _dl, span), resp in zip(batch, resps):
                 span.set_attrs(allowed=resp.allowed, code=resp.code)
                 out[pos] = wireproto.ResponseRecord(
                     records[pos].req_id, 200,
                     _envelope(resp.to_dict(uid=req.get("uid", ""))))
+        else:
+            clock.mark("encode")
         for span, _req in roots.values():
             span.end()
         return out  # type: ignore[return-value]

@@ -524,7 +524,10 @@ class App:
             # must not latch the degraded marker forever
             audit_expected=self.operations.is_assigned(ops_mod.AUDIT),
         )
-        self._collect_hooks = [obscosts.collect_hook, obsslo.collect_hook]
+        from .obs import trace as obstrace
+
+        self._collect_hooks = [obscosts.collect_hook, obsslo.collect_hook,
+                               obstrace.collect_hook]
 
         if getattr(args, "fault_plane_seed", None) is not None:
             from . import faults

@@ -23,6 +23,7 @@ from .views import (
     AGG_COUNT,
     AGG_DISTRIBUTION,
     AGG_LAST_VALUE,
+    AGG_SUM,
     Measure,
     Registry,
     View,
@@ -518,6 +519,51 @@ WIRE_BACKLOG_STALL_M = Measure(
     unit="s",
 )
 
+# ---- host stage clock (ISSUE 27, obs/trace.py StageClock) ------------------
+# Contiguous per-thread stage stopwatches on the replica's hot threads
+# and the sweeping thread: paths wire / batch / audit, stable stage
+# names (docs/tracing.md "Stage clock").  Accumulated on the clock and
+# flushed per sweep, or at most every 0.25 s by a loop.  The collector's pauses
+# come from the one gc.callbacks hook obs installs with the first clock.
+HOST_STAGE_SECONDS_M = Measure(
+    "host_stage_seconds",
+    "Host seconds one thread spent in one stage of its contiguous stage "
+    "clock, by path (wire, batch, audit) and stage; a thread's stages "
+    "are adjacent and sum to its wall time, a stage called wait is idle",
+    unit="s",
+)
+HOST_STAGE_CALLS_M = Measure(
+    "host_stage_calls",
+    "Closed intervals of one stage of the host stage clock, by path and "
+    "stage (seconds over calls = the mean stage)",
+)
+HOST_STAGE_GC_M = Measure(
+    "host_stage_gc_seconds",
+    "Garbage-collector pause seconds that fell inside one stage of the "
+    "host stage clock on the thread that collected (path gc, stage "
+    "background: a thread with no clock, e.g. the replica's webhook-gc "
+    "sweep), so a stage can be read net of the collector",
+    unit="s",
+)
+GC_PAUSE_M = Measure(
+    "gc_pause_seconds",
+    "Seconds the interpreter's garbage collector held the process, by "
+    "generation (gc.callbacks start -> stop)",
+    unit="s",
+)
+GC_COLLECTIONS_M = Measure(
+    "gc_collections",
+    "Garbage collections run, by generation",
+)
+PROCESS_CPU_M = Measure(
+    "process_cpu_seconds",
+    "CPU seconds (user + system, all threads) this process has used "
+    "(time.process_time): against wall time per review it shows whether "
+    "the interpreter lock or the chip bounds the replica",
+    unit="s",
+)
+
+
 # bucket boundaries copied from the reference's view.Distribution calls
 _INGEST_BUCKETS = (
     0.01, 0.02, 0.03, 0.04, 0.05, 0.06, 0.07, 0.08, 0.09, 0.1,
@@ -707,6 +753,17 @@ def catalog_views():
         View("wire_backlog_stall_seconds", WIRE_BACKLOG_STALL_M,
              AGG_DISTRIBUTION, tag_keys=("backend",),
              buckets=_STAGE_BUCKETS),
+        View("host_stage_seconds_total", HOST_STAGE_SECONDS_M, AGG_SUM,
+             tag_keys=("path", "stage")),
+        View("host_stage_calls_total", HOST_STAGE_CALLS_M, AGG_SUM,
+             tag_keys=("path", "stage")),
+        View("host_stage_gc_seconds_total", HOST_STAGE_GC_M, AGG_SUM,
+             tag_keys=("path", "stage")),
+        View("gc_pause_seconds_total", GC_PAUSE_M, AGG_SUM,
+             tag_keys=("generation",)),
+        View("gc_collections_total", GC_COLLECTIONS_M, AGG_SUM,
+             tag_keys=("generation",)),
+        View("process_cpu_seconds_total", PROCESS_CPU_M, AGG_SUM),
     ]
 
 
@@ -1459,3 +1516,45 @@ def record_wire_backlog_stall(backend: str, seconds: float):
                          {"backend": backend})
     except Exception:  # telemetry never blocks the wire path
         record_dropped("record_wire_backlog_stall")
+
+
+def record_host_stages(path: str, rows: Dict[str, tuple]):
+    """One flush of a host stage clock (obs/trace.py StageClock.flush):
+    ``rows`` is {stage: (seconds, calls, collector seconds)} closed
+    since the clock's previous flush.  Three lock holds per flush, never
+    one per mark.  Guarded like record_stage."""
+    try:
+        reg = _global()
+        tags = {stage: {"path": path, "stage": stage} for stage in rows}
+        reg.record_many(HOST_STAGE_SECONDS_M,
+                        [(r[0], tags[s]) for s, r in rows.items()])
+        reg.record_many(HOST_STAGE_CALLS_M,
+                        [(float(r[1]), tags[s]) for s, r in rows.items()
+                         if r[1]])
+        gc_rows = [(r[2], tags[s]) for s, r in rows.items() if r[2]]
+        if gc_rows:
+            reg.record_many(HOST_STAGE_GC_M, gc_rows)
+    except Exception:  # telemetry never blocks the hot threads
+        record_dropped("record_host_stages")
+
+
+def record_process_counters(gc_pause_s, gc_runs, gc_background_s: float,
+                            cpu_s: float):
+    """Scrape-time growth of the process counters (obs/trace.py
+    collect_hook): collector pause seconds and collections per
+    generation, the pauses on threads with no stage clock, CPU seconds.
+    Guarded like record_stage."""
+    try:
+        reg = _global()
+        for gen in range(3):
+            if gc_runs[gen]:
+                tags = {"generation": str(gen)}
+                reg.record(GC_PAUSE_M, gc_pause_s[gen], tags)
+                reg.record(GC_COLLECTIONS_M, float(gc_runs[gen]), tags)
+        if gc_background_s:
+            reg.record(HOST_STAGE_GC_M, gc_background_s,
+                       {"path": "gc", "stage": "background"})
+        if cpu_s:
+            reg.record(PROCESS_CPU_M, cpu_s)
+    except Exception:  # telemetry never blocks the scrape
+        record_dropped("record_process_counters")

@@ -37,6 +37,7 @@ import json
 import logging
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -157,6 +158,31 @@ class Trace:
         }
 
 
+def _finished_record(name: str, trace: "Trace", span_id: str,
+                     parent_id: Optional[str], start: float, stop: float,
+                     attrs: dict) -> dict:
+    """The one shape of a finished span's record (keys, rounding): what
+    Span.record and the stage clock's ring sink both file."""
+    rec = {
+        "name": name,
+        "trace_id": trace.trace_id,
+        "span_id": span_id,
+        "parent_id": parent_id,
+        "start": start,
+        "duration_ms": round((stop - start) * 1e3, 4),
+    }
+    if attrs:
+        rec["attrs"] = attrs
+    return rec
+
+
+def _file_record(trace: "Trace", rec: dict) -> None:
+    """A finished record into its trace and the trace's mirrors."""
+    trace.spans.append(rec)
+    for m in trace.mirrors:
+        m.spans.append(rec)
+
+
 class Span:
     """One timed operation.  Finish with ``end()`` (or use the tracer's
     context managers); a finished span becomes an immutable dict record
@@ -194,17 +220,9 @@ class Span:
         self.links.append((trace_id, span_id))
 
     def record(self) -> dict:
-        rec = {
-            "name": self.name,
-            "trace_id": self.trace.trace_id,
-            "span_id": self.span_id,
-            "parent_id": self.parent_id,
-            "start": self.start,
-            "duration_ms": round(((self.stop or self.start) - self.start)
-                                 * 1e3, 4),
-        }
-        if self.attrs:
-            rec["attrs"] = dict(self.attrs)
+        rec = _finished_record(
+            self.name, self.trace, self.span_id, self.parent_id,
+            self.start, self.stop or self.start, dict(self.attrs))
         if self.events:
             rec["events"] = list(self.events)
         if self.links:
@@ -219,9 +237,7 @@ class Span:
         self.stop = time.perf_counter() if stop is None else stop
         rec = self.record()
         tr = self.trace
-        tr.spans.append(rec)
-        for m in tr.mirrors:
-            m.spans.append(rec)
+        _file_record(tr, rec)
         if tr.root is self:
             tr.root_record = rec
             _TRACER.complete(tr)
@@ -599,11 +615,329 @@ def record_span(name: str, start: float, stop: float,
     return sp
 
 
+# ---- stage clock -------------------------------------------------------------
+# One contiguous stopwatch per (thread, path): ``mark(stage)`` closes the
+# open interval and opens the next, so a thread's stages are adjacent and
+# sum to first-mark -> stop exactly.  Every closed interval goes to three
+# sinks from the one call: always-on counters (host_stage_*_total,
+# accumulated on the clock and flushed per sweep or, by the loops, at
+# most every FLUSH_S), the profiler's own clock (a jax.profiler.TraceAnnotation held
+# open for the stage, so it lands on the host plane of the same
+# .xplane.pb as the device ops), and the ring (record_span under
+# CURRENT).  Paths and stages are stable strings; a stage called
+# ``wait`` is idle with the GIL released, every other one is busy
+# (busy is wall time: under contention it holds the wait for the GIL).
+# docs/tracing.md ("Stage clock") is the contract.
+
+PATH_WIRE = "wire"
+PATH_BATCH = "batch"
+PATH_AUDIT = "audit"
+WAIT = "wait"
+QUEUED = "queued"   # wire only: a chunk's delay in the worker hand-off
+
+# thread ident -> the innermost running clock: where the collector's
+# hook books a pause (GIL-atomic dict ops, like _ACTIVE_BY_THREAD)
+_RUNNING_CLOCKS: Dict[int, "StageClock"] = {}
+_CLOCKS = threading.local()  # path -> this thread's StageClock
+
+# the annotation sink: jax.profiler.TraceAnnotation when jax is ALREADY
+# imported in the process, else absent.  Never imported for this: the
+# door and the benchmark's parent stay jax-free.
+_ANNOTATION = None
+_STAGE_NAMES: Dict[tuple, str] = {}   # (prefix, path, stage) -> span name
+
+
+def _bind_annotation() -> None:
+    global _ANNOTATION
+    _ANNOTATION = getattr(sys.modules.get("jax.profiler"),
+                          "TraceAnnotation", None)
+
+
+class StageClock:
+    """Contiguous stage stopwatch of one thread on one path (see the
+    section comment above).  ``mark(stage)`` names the interval it
+    OPENS; ``lap(stage)`` names the interval it CLOSES (for code that
+    learns what an interval was only at its end — the front door)."""
+
+    __slots__ = ("path", "stage", "t", "totals", "gc_full_s", "_ann",
+                 "_outer", "_flushed", "_lapped", "_gc_full_lapped",
+                 "flushed_at")
+
+    SPAN_PREFIX = "gk."   # ring + annotation names: gk.<path>.<stage>
+    STAGE_ATTR = False    # True: ring spans carry the `stage` attribute
+
+    def __init__(self, path: str, start: Optional[float] = None):
+        self.path = path
+        self.stage: Optional[str] = None   # open stage; None = stopped
+        self.t = time.perf_counter() if start is None else start
+        # stage -> [seconds, calls, collector seconds], cumulative
+        self.totals: Dict[str, list] = {}
+        self.gc_full_s = 0.0   # generation-2 pauses inside open stages
+        self._ann = None
+        self._outer: Optional["StageClock"] = None
+        self._flushed: Optional[Dict[str, tuple]] = None
+        self._lapped: Optional[Dict[str, tuple]] = None
+        self._gc_full_lapped = 0.0
+        self.flushed_at = self.t   # perf_counter of the last flush
+        if _ANNOTATION is None and "jax.profiler" in sys.modules:
+            _bind_annotation()
+        if not _GC_HOOKED:
+            _install_gc_hook()
+
+    def _name(self, stage: str) -> str:
+        key = (self.SPAN_PREFIX, self.path, stage)
+        name = _STAGE_NAMES.get(key)
+        if name is None:
+            name = _STAGE_NAMES[key] = (
+                f"{self.SPAN_PREFIX}{self.path}.{stage}")
+        return name
+
+    def _account(self, stage: str, seconds: float) -> None:
+        acc = self.totals.get(stage)
+        if acc is None:
+            acc = self.totals[stage] = [0.0, 0, 0.0]
+        acc[0] += seconds
+        acc[1] += 1
+
+    def _close(self, stage: str, now: float, attrs: dict) -> None:
+        self._account(stage, now - self.t)
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+        cur = CURRENT.get()
+        if cur is not None:
+            # the ring sink: the finished record filed as it is (no
+            # Span object on the hot threads)
+            if self.STAGE_ATTR:
+                attrs["stage"] = stage
+            _file_record(cur.trace, _finished_record(
+                self._name(stage), cur.trace, _new_span_id(), cur.span_id,
+                self.t, now, attrs))
+
+    def mark(self, stage: str) -> float:
+        """Close the open stage (if any) and open ``stage`` at *now*."""
+        now = time.perf_counter()
+        opened = self.stage
+        if opened is not None:
+            self._close(opened, now, {})
+        self.stage = stage
+        self.t = now
+        if opened is None:
+            # onto the collector's map only with a stage open
+            ident = threading.get_ident()
+            self._outer = _RUNNING_CLOCKS.get(ident)
+            _RUNNING_CLOCKS[ident] = self
+        ann = _ANNOTATION
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self._name(stage))
+        return now
+
+    def add(self, stage: str, seconds: float) -> None:
+        """Book an interval measured elsewhere (a hand-off queue's delay
+        before this thread took the work) under ``stage``: counters
+        only, and no part of the thread's own contiguous time."""
+        self._account(stage, seconds)
+
+    def lap(self, stage: str, **attrs) -> float:
+        """Close the interval since the last boundary AS ``stage``; the
+        clock keeps running with the next interval yet unnamed."""
+        now = time.perf_counter()
+        self._close(stage, now, attrs)
+        self.t = now
+        return now
+
+    def stop(self) -> float:
+        """Close the open stage; the clock is stopped until the next
+        mark (time until then belongs to no stage)."""
+        now = time.perf_counter()
+        if self.stage is not None:
+            self._close(self.stage, now, {})
+            # off the collector's map first: a collection between the
+            # two lines must not find a clock with no open stage
+            ident = threading.get_ident()
+            if self._outer is not None:
+                _RUNNING_CLOCKS[ident] = self._outer
+                self._outer = None
+            else:
+                _RUNNING_CLOCKS.pop(ident, None)
+            self.stage = None
+        return now
+
+    def _since(self, seen: Dict[str, tuple]) -> Dict[str, tuple]:
+        out = {}
+        for stage, acc in self.totals.items():
+            cur = (acc[0], acc[1], acc[2])
+            old = seen.get(stage)
+            if old != cur:
+                o = old or (0.0, 0, 0.0)
+                out[stage] = (cur[0] - o[0], cur[1] - o[1], cur[2] - o[2])
+                seen[stage] = cur
+        return out
+
+    def lapse(self) -> Tuple[Dict[str, tuple], float]:
+        """({stage: (seconds, calls, collector seconds)}, generation-2
+        pause seconds) closed since the previous lapse() — one sweep's
+        share of the clock."""
+        if self._lapped is None:
+            self._lapped = {}
+        gc_full = self.gc_full_s - self._gc_full_lapped
+        self._gc_full_lapped = self.gc_full_s
+        return self._since(self._lapped), gc_full
+
+    FLUSH_S = 0.25   # flush_due's gate (the wire telemetry's cadence)
+
+    def flush_due(self, now: float) -> None:
+        """flush(), at most every FLUSH_S: for the loops that turn
+        hundreds of times a second (batcher, wire workers, add_data)."""
+        if now - self.flushed_at >= self.FLUSH_S:
+            self.flush()
+
+    def flush(self) -> None:
+        """Push what closed since the last flush to the counters
+        (host_stage_seconds_total / _calls_total / _gc_seconds_total):
+        per sweep, or per loop turn / chunk through flush_due — never
+        per mark."""
+        if self._flushed is None:
+            self._flushed = {}
+        self.flushed_at = time.perf_counter()
+        rows = self._since(self._flushed)
+        if rows:
+            from ..metrics.catalog import record_host_stages
+
+            record_host_stages(self.path, rows)
+
+
+class _NoopClock:
+    """The clock of a thread nobody is timing: marks cost one
+    perf_counter read (callers use the returned instants)."""
+
+    __slots__ = ()
+    path = ""
+    stage = None
+
+    def mark(self, stage: str) -> float:
+        return time.perf_counter()
+
+    def stop(self) -> float:
+        return time.perf_counter()
+
+    def add(self, stage: str, seconds: float) -> None:
+        pass
+
+    def lapse(self) -> tuple:
+        return {}, 0.0
+
+    def flush(self) -> None:
+        pass
+
+    def flush_due(self, now: float) -> None:
+        pass
+
+
+NOOP_CLOCK = _NoopClock()
+
+
+def stage_clock(path: str) -> StageClock:
+    """This thread's clock for ``path`` (made on first use)."""
+    clock = _CLOCKS.__dict__.get(path)
+    if clock is None:
+        clock = _CLOCKS.__dict__[path] = StageClock(path)
+    return clock
+
+
+def running_clock(path: str):
+    """This thread's clock for ``path`` if a stage is open on it, else
+    the no-op clock: the driver marks stages on whatever clock its
+    caller (batcher loop, wire worker, Client) started, and on nothing
+    when called bare."""
+    clock = _CLOCKS.__dict__.get(path)
+    if clock is None or clock.stage is None:
+        return NOOP_CLOCK
+    return clock
+
+
+# ---- the collector -----------------------------------------------------------
+# One gc.callbacks hook, installed the first time a StageClock is made in
+# the process.  A pause is booked per generation, and to the stage open
+# on the thread that collected (or to path "gc", stage "background" —
+# the replica's webhook-gc thread); plain adds under the GIL, pushed to
+# the registry by collect_hook at scrape time.
+
+GC_PATH = "gc"
+GC_BACKGROUND = "background"
+_GC_HOOKED = False
+_GC_PAUSE_S = [0.0, 0.0, 0.0]
+_GC_RUNS = [0, 0, 0]
+_GC_BACKGROUND_S = [0.0]
+_GC_OPEN: list = []   # (start, annotation) of the collection in progress
+_GC_PUSHED = {"pause": [0.0, 0.0, 0.0], "runs": [0, 0, 0], "bg": 0.0,
+              "cpu": 0.0}
+_GC_PUSH_LOCK = threading.Lock()   # two scrapes must not push one growth
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    gen = info.get("generation", 2)
+    if phase == "start":
+        ann = None
+        cls = _ANNOTATION
+        if gen == 2 and cls is not None and cls.is_enabled():
+            ann = cls("gk.gc.gen2")
+        _GC_OPEN.append((time.perf_counter(), ann))
+        return
+    if not _GC_OPEN:
+        return
+    t0, ann = _GC_OPEN.pop()
+    pause = time.perf_counter() - t0
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    _GC_PAUSE_S[gen] += pause
+    _GC_RUNS[gen] += 1
+    clock = _RUNNING_CLOCKS.get(threading.get_ident())
+    if clock is None:
+        _GC_BACKGROUND_S[0] += pause
+        return
+    acc = clock.totals.get(clock.stage)
+    if acc is None:
+        acc = clock.totals[clock.stage] = [0.0, 0, 0.0]
+    acc[2] += pause
+    if gen == 2:
+        clock.gc_full_s += pause
+
+
+def _install_gc_hook() -> None:
+    global _GC_HOOKED
+    if not _GC_HOOKED:
+        _GC_HOOKED = True
+        import gc
+
+        gc.callbacks.append(_gc_callback)
+
+
+def collect_hook(registry=None) -> None:
+    """Scrape-time push (MetricsExporter collect hook): the collector's
+    pauses and collections per generation, the background share, and
+    process_cpu_seconds_total — read when asked, no refresher thread."""
+    from ..metrics.catalog import record_process_counters
+
+    pushed = _GC_PUSHED
+    with _GC_PUSH_LOCK:
+        pause = [_GC_PAUSE_S[g] - pushed["pause"][g] for g in range(3)]
+        runs = [_GC_RUNS[g] - pushed["runs"][g] for g in range(3)]
+        bg = _GC_BACKGROUND_S[0] - pushed["bg"]
+        cpu = time.process_time()
+        record_process_counters(pause, runs, bg, cpu - pushed["cpu"])
+        for g in range(3):
+            pushed["pause"][g] += pause[g]
+            pushed["runs"][g] += runs[g]
+        pushed["bg"] += bg
+        pushed["cpu"] = cpu
+
+
 def dump_stacks() -> dict:
     """Thread-stack snapshot for /debug/stacks: every live thread's name,
     ident, daemon flag, and current frames — the hang-diagnosis view the
     fault plane's hang mode needs."""
-    import sys
     import traceback
 
     frames = sys._current_frames()

@@ -469,6 +469,11 @@ class TpuDriver(InterpDriver):
                 # nothing to evaluate: the compile seam is the best probe
                 self._fused_fn()
                 return
+            # gklint: disable=blocking-under-lock -- compute_masks has
+            # always fetched its result under the driver lock (the lock
+            # is what keeps the packed inputs and the epoch they were
+            # packed for together); the wait is explicit since the stage
+            # clock split it from the fetch
             self.compute_masks([copy.deepcopy(self._PROBE_REVIEW)])
 
     def _on_breaker_transition(self, old: str, new: str):
@@ -1512,20 +1517,29 @@ class TpuDriver(InterpDriver):
         mesh multiple and committed sharded (input placement drives the
         SPMD compile of the SAME fused jit); results come back trimmed so
         callers see identical shapes on 1 or N devices."""
-        import time as _time
-
-        t0 = _time.perf_counter()
+        # stages on the caller's batch clock (obs/trace.py StageClock;
+        # the no-op clock when nobody is timing this thread): the old
+        # tpu.dispatch interval split where the work changes hands
+        clock = obstrace.running_clock(obstrace.PATH_BATCH)
+        t0 = clock.mark("pack")
         fn, ordered, rp, cp, cols, group_params, crow = self._device_inputs(
             reviews
         )
         rows = len(rp.arrays["valid"])
-        t1 = _time.perf_counter()
+        t1 = clock.mark("enqueue")  # implicit upload + launch
         packed = self._dispatch(
             self._packed_variant(fn), rp.arrays, cp.arrays, cols,
             group_params, rows,
         )
+        # the fetch is requested behind the compute before the host waits
+        # for either: block_until_ready alone would put a host round trip
+        # between the two (+0.18 ms per dispatch, measured: PERF.md PR 27)
+        packed.copy_to_host_async()
+        clock.mark("device_wait")
+        packed = jax.block_until_ready(packed)
+        clock.mark("fetch")
         both = np.unpackbits(np.asarray(packed), axis=1)
-        t2 = _time.perf_counter()
+        t2 = clock.mark("account")
         # stage telemetry: spans mirror into every request trace this
         # batch serves; the histograms double-record the same intervals
         obstrace.record_span("tpu.pack", t0, t1, stage=obstrace.PACK,
@@ -2117,6 +2131,9 @@ class TpuDriver(InterpDriver):
                 reviews = [cal_review() for _ in range(batch)]
                 with self._lock:
                     t0 = _time.perf_counter()
+                    # gklint: disable=blocking-under-lock -- as in
+                    # _breaker_probe: the fetch under the lock is the
+                    # dispatch being priced
                     self.compute_masks(reviews)
                     ts.append(_time.perf_counter() - t0)
             # median, deliberately asymmetric with the host paths' min:
@@ -2390,7 +2407,7 @@ class TpuDriver(InterpDriver):
         # real Pod — pure waste twice per unique admission).
         import time as _time
 
-        t0 = _time.perf_counter()
+        t0 = obstrace.running_clock(obstrace.PATH_BATCH).mark("route")
         probed = [self._request_memo_hit(r) for r in reviews]
         served: List = [p[0] for p in probed]
         misses = [i for i, s in enumerate(served) if s is None]
@@ -2441,6 +2458,9 @@ class TpuDriver(InterpDriver):
                            memo_reviews: Optional[list] = None):
         """Route and evaluate (no memo probe: review_batch already served
         the hits)."""
+        clock = obstrace.running_clock(obstrace.PATH_BATCH)
+        if clock.stage != "route":   # review_batch's memo probe opened it
+            clock.mark("route")
         n_constraints = self._n_constraints_total()
         cells = len(reviews) * max(n_constraints, 1)
         route, reason, lam, priced = self._route_decision(
@@ -2495,6 +2515,9 @@ class TpuDriver(InterpDriver):
         _record("device")
         with self._lock:
             try:
+                # gklint: disable=blocking-under-lock -- as in
+                # _breaker_probe: the device tier evaluates and fetches
+                # under the driver lock by design
                 ordered, mask, autoreject = self.compute_masks(reviews)
             except Exception as e:
                 # backend failure: feed the breaker and degrade THIS batch
@@ -2522,12 +2545,14 @@ class TpuDriver(InterpDriver):
                     return self._review_batch_traced(
                         reviews, ordered, mask_np, rej_np, inventory
                     )
+                clock.mark("render")
                 with obstrace.span("render", stage=obstrace.RENDER,
                                    tier="tpu"):
                     out = self._render_masked(
                         reviews, ordered, mask_np, rej_np, inventory,
                         memo_keys=memo_reviews,
                     )
+                clock.mark("account")  # request-memo stores
                 # admission-sized batches feed the request memo from the
                 # device path too, so repeat content (replica/retry
                 # storms — including repeat ALLOWS, the common case)
@@ -2584,6 +2609,7 @@ class TpuDriver(InterpDriver):
         """Interpreter-tier serving with the stage span every evaluation
         path emits: tier + breaker state make degraded traffic (breaker
         open, compile in flight) attributable in the trace."""
+        obstrace.running_clock(obstrace.PATH_BATCH).mark("render")
         with obstrace.span("eval.interp", stage=obstrace.RENDER,
                            tier="interp", breaker=self.breaker.state):
             return [
@@ -2806,12 +2832,16 @@ class TpuDriver(InterpDriver):
             return None
         import time as _time
 
+        # host tiers on the batch clock: pack = the side's sync, render =
+        # the host evaluation and the render; no enqueue / device_wait /
+        # fetch stage exists for them
+        clock = obstrace.running_clock(obstrace.PATH_BATCH)
         t_enter = _time.perf_counter()
         with self._lock:
-            t_locked = _time.perf_counter()
+            t_locked = clock.mark("pack")
             ns = self._np_side
             ns.sync(self)
-            t_synced = _time.perf_counter()
+            t_synced = clock.mark("render")
             got = ns.serve(self, reviews)
             if got is None:
                 return None
@@ -2840,6 +2870,7 @@ class TpuDriver(InterpDriver):
                     reviews, ordered, mask, rej, inventory,
                     memo_keys=memo_reviews,
                 )
+            clock.mark("account")  # request-memo stores
             if (
                 len(reviews) <= self.REQUEST_MEMO_BATCH_MAX
                 and self._memoable_synced()
@@ -3278,7 +3309,8 @@ class TpuDriver(InterpDriver):
 
         if faults.ENABLED:
             faults.fire(faults.TPU_DISPATCH, path="audit")
-        t0 = _time.perf_counter()
+        clock = obstrace.running_clock(obstrace.PATH_AUDIT)
+        t0 = self._audit_clock_pack()
         fn, ordered, cp, group_params, crow = self._audit_inputs(K)
         ap = self._audit_pack
         if ap.n_rows == 0:
@@ -3290,7 +3322,7 @@ class TpuDriver(InterpDriver):
         self._ensure_join_state()
         jargs = self._join_trace_args()
         mesh = self._mesh()
-        t1 = _time.perf_counter()
+        t1 = clock.mark("enqueue")  # dirty-row scatter, placement, launch
         if mesh is None:
             rv_d, cols_d = self._audit_device_inputs()
             cs_d, gp_d = self._constraint_device_side(
@@ -3352,8 +3384,9 @@ class TpuDriver(InterpDriver):
             # rides the background thread) so the first O(churn) delta
             # sweep under the mesh pays a dispatch, not an SPMD compile
             self._warm_delta_async(mask_src, cs_p, gp_p, mesh)
+        t_wait = clock.mark("device_wait")
         packed_dev.block_until_ready()
-        t2 = _time.perf_counter()
+        t2 = clock.mark("fetch")
         # the ONE small fetch per sweep; crow folds the group-major pad
         # rows out so all host-side state is per ordered constraint
         if mesh is None:
@@ -3363,7 +3396,7 @@ class TpuDriver(InterpDriver):
             # produces (per-shard lists may be narrower when a shard's
             # row slab is smaller than K)
             packed = _merge_sharded_packed(np.asarray(packed_dev), K)[crow]
-        t3 = _time.perf_counter()
+        t3 = clock.mark("apply")  # the incremental state rebased
         counts = packed[:, 0].astype(np.int64)
         sweep = (ap.reviews, ordered, mask_src, counts, packed[:, 1:])
         # re-read the epochs: packing may have interned new strings and
@@ -3389,6 +3422,10 @@ class TpuDriver(InterpDriver):
             "pack_ms": (t1 - t0) * 1e3,
             "device_ms": (t2 - t1) * 1e3,
             "fetch_ms": (t3 - t2) * 1e3,
+            "slice_ms": 0.0,  # a full sweep gathers no dirty-row slice
+            "enqueue_ms": (t_wait - t1) * 1e3,
+            "device_wait_ms": (t2 - t_wait) * 1e3,
+            "apply_ms": (_time.perf_counter() - t3) * 1e3,
             "fetch_bytes": float(packed.nbytes),
             "rows": float(ap.n_rows),
             "cells": float(len(ordered) * ap.n_rows),
@@ -3533,6 +3570,7 @@ class TpuDriver(InterpDriver):
             reviews, ordered, mask = self._audit_masks()
             if not reviews:
                 return [], ("" if tracing else None)
+            obstrace.running_clock(obstrace.PATH_AUDIT).mark("render")
             inventory = self._inventory_for_render()
             results: List[Result] = []
             trace: List[str] = [] if tracing else None
@@ -3769,6 +3807,17 @@ class TpuDriver(InterpDriver):
         self._delta_jit_key = self._fused_gen
         return self._delta_jit
 
+    @staticmethod
+    def _audit_clock_pack() -> float:
+        """Open `pack` on the sweeping thread's audit clock (the Client
+        opened it already when it owns the clock) -> the instant."""
+        clock = obstrace.running_clock(obstrace.PATH_AUDIT)
+        if clock.stage == "pack":
+            import time as _time
+
+            return _time.perf_counter()
+        return clock.mark("pack")
+
     def _try_delta(self, K: int):
         """Bring the incremental sweep state current with an O(dirty-rows)
         device evaluation (ops/deltasweep.py).  Returns
@@ -3795,7 +3844,7 @@ class TpuDriver(InterpDriver):
             return None
         import time as _time
 
-        t0 = _time.perf_counter()
+        t0 = self._audit_clock_pack()
         side = self._constraint_side()
         self._audit_pack.sync(self, side[3])
         if self.interner.snapshot_size() > self._cs_cache[0][1]:
@@ -3892,7 +3941,12 @@ class TpuDriver(InterpDriver):
     def _apply_delta(self, st, ap, rows, ordered, cp, groups, t0,
                      join_rows: int = 0):
         import time as _time
-        t1 = _time.perf_counter()
+
+        # the audit clock's stages (obs/trace.py): slice (host gather of
+        # the dirty rows from every column), enqueue (implicit upload +
+        # launch), device_wait, fetch, apply
+        clock = obstrace.running_clock(obstrace.PATH_AUDIT)
+        t1 = clock.mark("slice")
         # ONE dispatch: the fused evaluation on the dirty-row slice AND the
         # gather of the same rows' before-columns from the resident
         # full-sweep mask; one [C, 2d] int8 fetch
@@ -3915,6 +3969,7 @@ class TpuDriver(InterpDriver):
         # UPDATED global aggregate (ops/joinkernel.py 'tables' mode)
         jt = self._join_delta_tables()
         jtail = (jt,) if jt is not None else ()
+        t_enq = clock.mark("enqueue")
         # [C_total, 2d] from the device; crow folds pad rows out so the
         # incremental state stays per ordered constraint
         if mesh is not None:
@@ -3933,10 +3988,14 @@ class TpuDriver(InterpDriver):
                 st.mask_src.get(), rows_pad, rv_slice, cs_d, cols_slice,
                 gp_d, *jtail
             )
+        both_dev.copy_to_host_async()  # see compute_masks
+        t_wait = clock.mark("device_wait")
+        both_dev = jax.block_until_ready(both_dev)
+        t_fetch = clock.mark("fetch")
         both = np.asarray(both_dev).astype(bool)[st.crow]
         fetch_bytes = both.nbytes
         base_old, dmask = both[:, :width], both[:, width:]
-        t2 = _time.perf_counter()
+        t2 = clock.mark("apply")
         for j, r in enumerate(rows):
             # rows dirtied since the base sweep carry their current column
             # in the state cache; the device gather serves the rest
@@ -3945,10 +4004,16 @@ class TpuDriver(InterpDriver):
                 old = base_old[:, j]
             st.apply_row(r, old, dmask[:, j])
         st.store_epoch = self.store.epoch
+        # device_ms + fetch_ms is the interval slice -> fetched (what
+        # device_ms alone was before the fetch had a reading of its own)
         self.last_sweep_stats = {
             "pack_ms": (t1 - t0) * 1e3,
-            "device_ms": (t2 - t1) * 1e3,
-            "fetch_ms": 0.0,
+            "device_ms": (t_fetch - t1) * 1e3,
+            "fetch_ms": (t2 - t_fetch) * 1e3,
+            "slice_ms": (t_enq - t1) * 1e3,
+            "enqueue_ms": (t_wait - t_enq) * 1e3,
+            "device_wait_ms": (t_fetch - t_wait) * 1e3,
+            "apply_ms": (_time.perf_counter() - t2) * 1e3,
             "fetch_bytes": float(fetch_bytes),
             "delta_rows": float(len(rows)),
             "rows": float(ap.n_rows),
@@ -4029,6 +4094,9 @@ class TpuDriver(InterpDriver):
             K = self._audit_topk(cap)
             trace: List[str] = [] if tracing else None
             for _attempt in (0, 1):
+                # gklint: disable=blocking-under-lock -- same audit
+                # exclusive-device-ownership contract as _audit_device
+                # above; the delta dispatch always fetched under the lock
                 got = self._try_delta(K)
                 if got is None:
                     # gklint: disable=blocking-under-lock -- same audit
@@ -4073,7 +4141,8 @@ class TpuDriver(InterpDriver):
 
         import time as _time
 
-        t0 = _time.perf_counter()
+        clock = obstrace.running_clock(obstrace.PATH_AUDIT)
+        t0 = clock.mark("render")
         ap = self._audit_pack
         if self._render_memo_epoch != self._cs_epoch:
             self._render_memo.clear()
@@ -4273,6 +4342,9 @@ class TpuDriver(InterpDriver):
             fallback_bytes=float(fallback_bytes),
             results=float(len(results)),
         )
+        # cap: the capped answer assembled — cost entries here, then the
+        # Client's resource rebuild and Responses
+        clock.mark("cap")
         if cost_on and cost_entries:
             obscosts.record_render(
                 cost_entries, _time.perf_counter() - t0, 0.0

@@ -275,6 +275,9 @@ class ValidationHandler:
         so the whole chunk costs one producer-lock round.  Traced
         requests and clients without submit_many fall back to the
         per-request review path."""
+        # the calling wire worker's stage clock (open in `prepare`), or
+        # the no-op clock for any other caller
+        clock = obstrace.running_clock(obstrace.PATH_WIRE)
         n = len(items)
         out: List[Optional[AdmissionResponse]] = [None] * n
         meta = [None] * n        # (req, t0, budget_s, deadline, span)
@@ -367,10 +370,13 @@ class ValidationHandler:
             for (idx, review), p in zip(batchable, pendings):
                 req, t0, budget_s, deadline, span = meta[idx]
                 results = None
+                clock.mark(obstrace.WAIT)
                 try:
                     resp_obj = waiter(p)
+                    clock.mark("finalize")
                     results = resp_obj.results()
                 except Exception as e:
+                    clock.mark("finalize")
                     out[idx] = self._finalize_failure(
                         req, e, t0, budget_s, span)
                     continue

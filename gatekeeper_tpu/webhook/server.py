@@ -495,12 +495,21 @@ class MicroBatcher:
 
         last_batch_size = 0
         last_dispatch_end = 0.0
+        # this thread's contiguous stage clock (obs/trace.py, path
+        # `batch`): wait -> collect -> [the driver's route, pack,
+        # enqueue, device_wait, fetch, render, account] -> release,
+        # one flush to the counters per turn
+        clock = obstrace.stage_clock(obstrace.PATH_BATCH)
         while True:
+            clock.mark(obstrace.WAIT)
             with self._cv:
                 while not self._pending and not self._stop:
                     self._cv.wait(timeout=0.1)
                 if self._stop and not self._pending:
+                    clock.stop()
+                    clock.flush()
                     return
+            clock.mark("collect")
             # adapt OUTSIDE the cv: the service model takes the driver
             # lock (predicted_batch_ms -> _n_constraints_total), and a
             # long driver hold (audit sweep, snapshot capture) must not
@@ -515,6 +524,7 @@ class MicroBatcher:
                 # cv, so a filled target dispatches immediately)
                 goal = min(target, self.max_batch)
                 if target > 1 and len(self._pending) < goal:
+                    clock.mark(obstrace.WAIT)
                     t_end = _time.monotonic() + deadline
                     while (
                         not self._stop and len(self._pending) < goal
@@ -553,7 +563,10 @@ class MicroBatcher:
                         last_batch_size > 1 and recent
                     )
                     if concurrent and len(self._pending) < self.max_batch:
+                        clock.mark(obstrace.WAIT)
                         self._cv.wait(timeout=self.window_s)
+                if clock.stage == obstrace.WAIT:
+                    clock.mark("collect")
                 batch = self._pending[: self.max_batch]
                 self._pending = self._pending[self.max_batch:]
                 if self._pending_dryruns:
@@ -615,11 +628,13 @@ class MicroBatcher:
                     responses = self._client.review_batch(
                         [p.obj for p in batch]
                     )
+                    clock.mark("account")  # the batch span's mirroring
                     if bsp is not None:
                         obstrace.deactivate(btoken)
                         btoken = None
                         bsp.end()
                         bsp = None
+                    clock.mark("release")
                     for p, resp in zip(batch, responses):
                         p.result = resp
                         p.event.set()
@@ -666,6 +681,7 @@ class MicroBatcher:
                     bsp.end()  # idempotent on the success path
                 self._busy = False
                 last_dispatch_end = _time.monotonic()
+                clock.flush_due(_time.perf_counter())
 
     def drain(self, deadline_s: float) -> dict:
         """Flush the queue for a graceful shutdown (docs/fleet.md drain
