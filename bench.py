@@ -978,11 +978,11 @@ else:
     from gatekeeper_tpu.ops.auditpack import AuditPackCache
     from gatekeeper_tpu.snapshot import SnapshotLoader
     packs = {"n": 0}
-    orig = AuditPackCache._pack_row
-    def counting(self, *a, **k):
-        packs["n"] += 1
-        return orig(self, *a, **k)
-    AuditPackCache._pack_row = counting
+    orig = AuditPackCache._pack_rows
+    def counting(self, drv, rows, *a, **k):
+        packs["n"] += len(rows)
+        return orig(self, drv, rows, *a, **k)
+    AuditPackCache._pack_rows = counting
     loader = SnapshotLoader(SNAP)
     outcome = loader.restore(client, kube)
     t_restored = time.time()

@@ -8,12 +8,15 @@ resident and applies only the store's change log per sweep:
 
   - one row per cached object, stable across sweeps (tombstoned on delete,
     reused from a free list)
-  - per-row re-pack on object change (pack_reviews/extract_columns on a
-    single review, written into the row slot with width growth as needed)
-  - Namespace objects re-pack every row in that namespace: packed rows bake
-    in namespaceSelector label resolution + autoreject against the cached
-    Namespace (ops/pack.py ns_mode), and a stale row could UNDER-approximate
-    the device mask, which the exactness filter cannot repair
+  - one batched re-pack per sync: every row the change log touched goes
+    through ONE pack_reviews and ONE extract_columns call, and each
+    resident leaf takes the batch in one fancy-indexed write (widths grow
+    once per leaf, to the batch's widest row)
+  - Namespace objects re-pack every row in that namespace, in that same
+    batch: packed rows bake in namespaceSelector label resolution +
+    autoreject against the cached Namespace (ops/pack.py ns_mode), and a
+    stale row could UNDER-approximate the device mask, which the exactness
+    filter cannot repair
   - wipes, subtree deletions, layout changes (new column specs) and
     change-log overruns fall back to a full rebuild
 
@@ -82,8 +85,13 @@ class AuditPackCache:
     """Resident packed audit inputs, synced to an InventoryStore's change
     log.  All access happens under the owning driver's lock."""
 
-    # beyond this many pending changes a batch rebuild is cheaper than
-    # per-row packing (native batch pack is ~15us/row vs ~200us/row here)
+    # past max(1024, n_rows / REBUILD_FRACTION) changed paths sync rebuilds
+    # instead of patching.  The value dates from per-row packing (~330us a
+    # row on the chip host: ledger, PR 27) and was not retuned when the
+    # patch became one batch (~22us a row there, the whole pack stage over
+    # its 200 rows: chip run, PR 28; PERF.md section 5).  A rebuild also
+    # resets every row generation (the render caches start over) and forces
+    # a re-upload, so the break-even is not the packers' alone.
     REBUILD_FRACTION = 8
 
     def __init__(self):
@@ -121,6 +129,9 @@ class AuditPackCache:
         # above, so neither consumer starves the other and the delta path
         # never rescans cumulative churn (advisor r3)
         self.delta_dirty: set = set()
+        # rows packed (batch re-packs and rebuilds) since the last
+        # take_packed_rows(): the driver publishes it as `pack_rows`
+        self.packed_rows = 0
 
     # ---- snapshot restore (gatekeeper_tpu/snapshot/) ----------------------
 
@@ -128,7 +139,7 @@ class AuditPackCache:
                        row_gen, free, n_rows, synced_epoch):
         """Install state deserialized from a snapshot (under the owning
         driver's lock).  Arrays arrive writable and exactly as a previous
-        process's _rebuild/_pack_row left them; reviews and row
+        process's _rebuild/_pack_rows left them; reviews and row
         generations are restored verbatim (generations key the render
         caches, so preserving them is what lets an unchanged constraint
         reuse its persisted rendered results).  layout_gen bumps so
@@ -177,6 +188,11 @@ class AuditPackCache:
         self.delta_dirty = set()
         return d
 
+    def take_packed_rows(self) -> int:
+        n = self.packed_rows
+        self.packed_rows = 0
+        return n
+
     # ---- public -----------------------------------------------------------
 
     def sync(self, driver, col_specs) -> bool:
@@ -211,16 +227,27 @@ class AuditPackCache:
         ):
             self._rebuild(driver, col_specs)
             return True
+        # pass 1, bookkeeping per path in log order: tombstones land now
+        # (a freed row may be re-used further down this same log), upserts
+        # only claim their row and queue it
+        rows: List[int] = []
         ns_repack: set = set()
         for seg in reversed(ordered_changes):
-            self._apply(driver, seg, col_specs)
+            row = self._apply(driver, seg)
+            if row is not None:
+                rows.append(row)
             if seg[:3] == _NS_PATH_PREFIX:
                 ns_repack.add(seg[3])
-        for ns in ns_repack:
-            for r in list(self.ns_rows.get(ns, ())):
-                review = self.reviews[r]
-                if review is not None:
-                    self._pack_row(driver, r, review, col_specs)
+        if ns_repack:
+            queued = set(rows)
+            for ns in sorted(ns_repack):
+                for r in sorted(self.ns_rows.get(ns, ())):
+                    if r not in queued and self.reviews[r] is not None:
+                        queued.add(r)
+                        rows.append(r)
+        # pass 2: one batch for everything queued
+        if rows:
+            self._pack_rows(driver, rows, col_specs)
         self.synced_epoch = store.epoch
         return True
 
@@ -264,10 +291,15 @@ class AuditPackCache:
         self.delta_dirty = set()
         self.layout_gen += 1
         self.rebuild_gen += 1
+        self.packed_rows += len(reviews)
 
     # ---- incremental ------------------------------------------------------
 
-    def _apply(self, driver, seg: Tuple[str, ...], col_specs):
+    def _apply(self, driver, seg: Tuple[str, ...]) -> Optional[int]:
+        """Row bookkeeping for one changed path.  A deletion tombstones
+        its row at once; an upsert installs the new review in its row
+        (allocating one if the path is new) and returns the row for the
+        sync's batch pack."""
         from ..engine.value import thaw
 
         api, kind, name, ns = _path_identity(seg)
@@ -276,7 +308,7 @@ class AuditPackCache:
         if obj is None:
             if row is not None:
                 self._tombstone(row, seg)
-            return
+            return None
         review = driver.target.make_audit_review(thaw(obj), api, kind, name, ns)
         if row is None:
             row = self._alloc_row()
@@ -289,7 +321,7 @@ class AuditPackCache:
         self.row_ns[row] = ns
         if ns:
             self.ns_rows.setdefault(ns, set()).add(row)
-        self._pack_row(driver, row, review, col_specs)
+        return row
 
     def _tombstone(self, row: int, seg: Tuple[str, ...]):
         self.reviews[row] = None
@@ -333,46 +365,57 @@ class AuditPackCache:
         self.capacity = new_capacity
         self.layout_gen += 1
 
-    def _write_leaf(self, holder: dict, key, row: int, src: np.ndarray, fill):
-        """Write one packed row into its slot, growing trailing (width)
-        dims when this row exceeds them.  Rows are reset to the fill value
-        first so narrower rows leave no stale tail."""
-        dst = holder[key]
-        if src.shape != dst.shape[1:]:
-            target = tuple(
-                max(a, b) for a, b in zip(dst.shape[1:], src.shape)
+    def _write_rows(self, holder: dict, key, idx: np.ndarray,
+                    src: np.ndarray, fill):
+        """Write a packed batch src[i] -> row idx[i] of one resident leaf.
+        The leaf's trailing (width) dims grow once, to the batch's, when
+        the batch is wider; a narrower batch resets its rows to the fill
+        value first so no row keeps a stale tail."""
+        dst = holder.get(key)
+        width = src.shape[1:]
+        if dst is None:
+            dst = holder[key] = np.full(
+                (self.capacity,) + width, fill, dtype=src.dtype
             )
-            if target != dst.shape[1:]:
-                grown = np.full((dst.shape[0],) + target, fill, dtype=dst.dtype)
-                grown[tuple(slice(0, s) for s in dst.shape)] = dst
-                holder[key] = grown
-                dst = grown
-                self.layout_gen += 1  # shape changed: device copy is stale
-        dst[row] = fill
-        if src.ndim:
-            dst[(row,) + tuple(slice(0, s) for s in src.shape)] = src
+            self.layout_gen += 1  # new leaf: device tree is stale
         else:
-            dst[row] = src
+            target = tuple(max(d, s) for d, s in zip(dst.shape[1:], width))
+            if target != dst.shape[1:]:
+                grown = np.full(
+                    (dst.shape[0],) + target, fill, dtype=dst.dtype
+                )
+                grown[tuple(slice(0, d) for d in dst.shape)] = dst
+                dst = holder[key] = grown
+                self.layout_gen += 1  # shape changed: device copy is stale
+        if width == dst.shape[1:]:
+            dst[idx] = src
+        else:
+            dst[idx] = fill
+            dst[(idx,) + tuple(slice(0, s) for s in width)] = src
 
-    def _pack_row(self, driver, row: int, review: dict, col_specs):
-        rp1 = pack_reviews(
-            [review], driver.interner, driver.store.cached_namespace,
+    def _pack_rows(self, driver, rows: List[int], col_specs):
+        """Re-pack `rows` (distinct, each holding its current review) as
+        one batch: the same packers _rebuild uses, one call each, and one
+        write per leaf.  Every row gets a generation of its own (the
+        render caches key on it)."""
+        reviews = [self.reviews[r] for r in rows]
+        idx = np.asarray(rows, dtype=np.intp)
+        rp = pack_reviews(
+            reviews, driver.interner, driver.store.cached_namespace,
             bucket_rows=False,
         )
-        for key, arr in rp1.arrays.items():
-            self._write_leaf(self.rp, key, row, arr[0], _RP_FILL[key])
-        cols1 = extract_columns([review], col_specs, driver.interner, 1)
-        for ckey, leaves in cols1.items():
+        for key, src in rp.arrays.items():
+            self._write_rows(self.rp, key, idx, src, _RP_FILL[key])
+        cols = extract_columns(
+            reviews, col_specs, driver.interner, len(reviews)
+        )
+        for ckey, leaves in cols.items():
             holder = self.cols.setdefault(ckey, {})
-            for leaf, arr in leaves.items():
-                if leaf not in holder:
-                    holder[leaf] = np.full(
-                        (self.capacity,) + arr.shape[1:],
-                        _COL_FILL[leaf], dtype=arr.dtype,
-                    )
-                    self.layout_gen += 1  # new leaf: device tree is stale
-                self._write_leaf(holder, leaf, row, arr[0], _COL_FILL[leaf])
-        self._gen += 1
-        self.row_gen[row] = self._gen
-        self.dirty.add(row)
-        self.delta_dirty.add(row)
+            for leaf, src in leaves.items():
+                self._write_rows(holder, leaf, idx, src, _COL_FILL[leaf])
+        for r in rows:
+            self._gen += 1
+            self.row_gen[r] = self._gen
+        self.dirty.update(rows)
+        self.delta_dirty.update(rows)
+        self.packed_rows += len(rows)

@@ -3301,8 +3301,8 @@ class TpuDriver(InterpDriver):
             ckey = self._audit_cache[0]
             if ckey == key or (reuse_any_k and ckey[:2] == key[:2]):
                 self.last_sweep_stats = {
-                    "pack_ms": 0.0, "device_ms": 0.0, "fetch_ms": 0.0,
-                    "fetch_bytes": 0.0, "cached": 1.0,
+                    "pack_ms": 0.0, "pack_rows": 0.0, "device_ms": 0.0,
+                    "fetch_ms": 0.0, "fetch_bytes": 0.0, "cached": 1.0,
                 }
                 return self._audit_cache[1]
         import time as _time
@@ -3420,6 +3420,7 @@ class TpuDriver(InterpDriver):
         ap.delta_dirty.clear()
         self.last_sweep_stats = {
             "pack_ms": (t1 - t0) * 1e3,
+            "pack_rows": float(ap.take_packed_rows()),
             "device_ms": (t2 - t1) * 1e3,
             "fetch_ms": (t3 - t2) * 1e3,
             "slice_ms": 0.0,  # a full sweep gathers no dirty-row slice
@@ -3859,6 +3860,7 @@ class TpuDriver(InterpDriver):
             st.store_epoch = self.store.epoch
             self.last_sweep_stats = {
                 "pack_ms": (_time.perf_counter() - t0) * 1e3,
+                "pack_rows": float(ap.take_packed_rows()),
                 "device_ms": 0.0, "fetch_ms": 0.0, "fetch_bytes": 0.0,
                 "cached": 1.0,
             }
@@ -4008,6 +4010,7 @@ class TpuDriver(InterpDriver):
         # device_ms alone was before the fetch had a reading of its own)
         self.last_sweep_stats = {
             "pack_ms": (t1 - t0) * 1e3,
+            "pack_rows": float(ap.take_packed_rows()),
             "device_ms": (t_fetch - t1) * 1e3,
             "fetch_ms": (t2 - t_fetch) * 1e3,
             "slice_ms": (t_enq - t1) * 1e3,

@@ -287,30 +287,30 @@ def _instrument(driver):
     audit pack (class-level methods wrapped per-instance)."""
     state = {"packs": 0, "rebuilds": 0}
     ap = driver._audit_pack
-    orig_pack = AuditPackCache._pack_row
+    orig_pack = AuditPackCache._pack_rows
     orig_rebuild = AuditPackCache._rebuild
 
-    def pack_row(self, *a, **k):
+    def pack_rows(self, drv, rows, *a, **k):
         if self is driver._audit_pack:
-            state["packs"] += 1
-        return orig_pack(self, *a, **k)
+            state["packs"] += len(rows)
+        return orig_pack(self, drv, rows, *a, **k)
 
     def rebuild(self, *a, **k):
         if self is driver._audit_pack:
             state["rebuilds"] += 1
         return orig_rebuild(self, *a, **k)
 
-    ap.__class__._pack_row = pack_row
+    ap.__class__._pack_rows = pack_rows
     ap.__class__._rebuild = rebuild
     return (lambda: state["packs"]), (lambda: state["rebuilds"])
 
 
 @pytest.fixture(autouse=True)
 def _restore_auditpack_methods():
-    orig_pack = AuditPackCache._pack_row
+    orig_pack = AuditPackCache._pack_rows
     orig_rebuild = AuditPackCache._rebuild
     yield
-    AuditPackCache._pack_row = orig_pack
+    AuditPackCache._pack_rows = orig_pack
     AuditPackCache._rebuild = orig_rebuild
 
 
