@@ -91,10 +91,15 @@ class ColumnSpec:
     iter_paths: Tuple[Path, ...]  # slot/keyset entity sources ([] allowed)
     rel_path: Path = ()  # []-free value path (scalar: the full path)
     exclude: Tuple[str, ...] = ()  # keyset: excluded key literals
+    # joinkey: how the value at the path becomes the key.  () is the value
+    # itself; ("joinpairs", kv_sep, item_sep) is a key COMPUTED from a map
+    # (joinkernel.join_pairs — upstream's flatten_selector)
+    form: Tuple[str, ...] = ()
 
     @property
     def key(self):
-        return (self.kind, self.iter_paths, self.rel_path, self.exclude)
+        base = (self.kind, self.iter_paths, self.rel_path, self.exclude)
+        return base + (self.form,) if self.form else base
 
     @property
     def iter_key(self):
@@ -148,16 +153,25 @@ def _extract_joinkey(
     {"sid" [R]}; slot keys (iteration paths) -> {"sid", "mask"} [R, S]
     with the slot width bucketed exactly like slot columns over the same
     iteration group (shared axes stay aligned)."""
-    from .joinkernel import UNKNOWN_KEY, intern_join_key
+    from .joinkernel import UNKNOWN_KEY, intern_join_key, join_pairs
 
     if not spec.iter_paths:  # scalar key
         sid = np.full(rows, Interner.MISSING, np.int32)
         for i, r in enumerate(resources):
             hits: List[Any] = []
             _walk(r, spec.rel_path, 0, hits)
-            if hits:
+            if spec.form:
+                # a computed key is defined on every row (an absent map
+                # flattens to the empty string, as the Rego's does)
+                sid[i] = intern_join_key(
+                    join_pairs(hits[0] if hits else None, *spec.form[1:]),
+                    interner,
+                )
+            elif hits:
                 sid[i] = intern_join_key(hits[0], interner)
         return {"sid": sid}
+    if spec.form:
+        raise ValueError("computed join keys are scalar")
     ents: List[List[Any]] = []
     for r in resources:
         hits: List[Any] = []

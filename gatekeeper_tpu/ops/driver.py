@@ -3319,10 +3319,13 @@ class TpuDriver(InterpDriver):
         # (diff-bumps reader row generations for changed key groups) and
         # build the trace-mode runtime args the join-bearing executables
         # take (ops/joinkernel.py)
+        t_join = clock.mark("join_commit") \
+            if self._active_join_plans() else None
         self._ensure_join_state()
         jargs = self._join_trace_args()
         mesh = self._mesh()
         t1 = clock.mark("enqueue")  # dirty-row scatter, placement, launch
+        t_packed = t1 if t_join is None else t_join
         if mesh is None:
             rv_d, cols_d = self._audit_device_inputs()
             cs_d, gp_d = self._constraint_device_side(
@@ -3419,7 +3422,8 @@ class TpuDriver(InterpDriver):
         # drop the delta channel so those rows aren't re-applied
         ap.delta_dirty.clear()
         self.last_sweep_stats = {
-            "pack_ms": (t1 - t0) * 1e3,
+            "full": 1.0,
+            "pack_ms": (t_packed - t0) * 1e3,
             "pack_rows": float(ap.take_packed_rows()),
             "device_ms": (t2 - t1) * 1e3,
             "fetch_ms": (t3 - t2) * 1e3,
@@ -3446,6 +3450,10 @@ class TpuDriver(InterpDriver):
             # explicit reason the dispatch would read as an ordinary
             # row-local device sweep in route_decisions_total/routez
             self.last_sweep_stats["join_plans"] = float(len(jargs))
+            # the join index rebuilt and diffed against the previous one
+            # (JoinState.rebuild); a full sweep previews no delta
+            self.last_sweep_stats["join_affected_ms"] = 0.0
+            self.last_sweep_stats["join_commit_ms"] = (t1 - t_join) * 1e3
             # tier "device" (the documented taxonomy), flip-exempt: an
             # audit-class dispatch interleaved with np/interp review
             # traffic is not a serving-tier change
@@ -3873,6 +3881,9 @@ class TpuDriver(InterpDriver):
         # current join index the aggregate cannot be maintained
         # incrementally, so rebase via a full sweep.
         js = None
+        clock = obstrace.running_clock(obstrace.PATH_AUDIT)
+        join_affected_s = 0.0
+        t_commit = None
         if self._active_join_plans():
             js = self._join_state
             if (
@@ -3883,7 +3894,9 @@ class TpuDriver(InterpDriver):
                 or js.rebuild_gen != ap.rebuild_gen
             ):
                 return None
+            t_aff = clock.mark("join_affected")
             affected = js.affected(ap, self.interner, ap.delta_dirty)
+            join_affected_s = clock.mark("pack") - t_aff
             if len(ap.delta_dirty) + len(affected) > self.DELTA_MAX_ROWS:
                 return None
         from .deltasweep import MaskSource
@@ -3919,7 +3932,9 @@ class TpuDriver(InterpDriver):
         if js is not None:
             # commit the churn to the join index: updates provider/reader
             # maps, bumps affected readers' row generations (stale render
-            # reuse), and returns the key-group rows to co-dispatch
+            # reuse), and returns the key-group rows to co-dispatch;
+            # the stage stays open over _apply_delta's delta_tables
+            t_commit = clock.mark("join_commit")
             extra = js.commit(ap, self.interner, rows)
             if extra:
                 join_rows = len(extra)
@@ -3929,7 +3944,9 @@ class TpuDriver(InterpDriver):
                 record_join_affected(join_rows)
         try:
             return self._apply_delta(st, ap, rows, ordered, cp, groups, t0,
-                                     join_rows=join_rows)
+                                     join_rows=join_rows,
+                                     join_affected_s=join_affected_s,
+                                     t_commit=t_commit)
         except Exception:
             import logging
 
@@ -3941,14 +3958,22 @@ class TpuDriver(InterpDriver):
             return None
 
     def _apply_delta(self, st, ap, rows, ordered, cp, groups, t0,
-                     join_rows: int = 0):
+                     join_rows: int = 0, join_affected_s: float = 0.0,
+                     t_commit: Optional[float] = None):
         import time as _time
 
+        # post-commit join tables: the [C, d] dispatch evaluates the
+        # churned rows AND the affected key-group readers against the
+        # UPDATED global aggregate (ops/joinkernel.py 'tables' mode);
+        # built inside the `join_commit` stage _try_delta opened
+        jt = self._join_delta_tables()
+        jtail = (jt,) if jt is not None else ()
         # the audit clock's stages (obs/trace.py): slice (host gather of
         # the dirty rows from every column), enqueue (implicit upload +
         # launch), device_wait, fetch, apply
         clock = obstrace.running_clock(obstrace.PATH_AUDIT)
         t1 = clock.mark("slice")
+        join_commit_s = 0.0 if t_commit is None else t1 - t_commit
         # ONE dispatch: the fused evaluation on the dirty-row slice AND the
         # gather of the same rows' before-columns from the resident
         # full-sweep mask; one [C, 2d] int8 fetch
@@ -3966,11 +3991,6 @@ class TpuDriver(InterpDriver):
         cs_d, gp_d = self._constraint_device_side(
             cp.arrays, group_params, None, mesh
         )
-        # post-commit join tables: the [C, d] dispatch evaluates the
-        # churned rows AND the affected key-group readers against the
-        # UPDATED global aggregate (ops/joinkernel.py 'tables' mode)
-        jt = self._join_delta_tables()
-        jtail = (jt,) if jt is not None else ()
         t_enq = clock.mark("enqueue")
         # [C_total, 2d] from the device; crow folds pad rows out so the
         # incremental state stays per ordered constraint
@@ -4009,7 +4029,9 @@ class TpuDriver(InterpDriver):
         # device_ms + fetch_ms is the interval slice -> fetched (what
         # device_ms alone was before the fetch had a reading of its own)
         self.last_sweep_stats = {
-            "pack_ms": (t1 - t0) * 1e3,
+            "full": 0.0,
+            # the join stages are read apart (below): not pack's
+            "pack_ms": (t1 - t0 - join_affected_s - join_commit_s) * 1e3,
             "pack_rows": float(ap.take_packed_rows()),
             "device_ms": (t_fetch - t1) * 1e3,
             "fetch_ms": (t2 - t_fetch) * 1e3,
@@ -4028,6 +4050,9 @@ class TpuDriver(InterpDriver):
             # affected readers rather than content churn (the quantity
             # tools/check_join_parity.py pins to the exact group size)
             self.last_sweep_stats["join_affected_rows"] = float(join_rows)
+            self.last_sweep_stats["join_plans"] = float(len(jt))
+            self.last_sweep_stats["join_affected_ms"] = join_affected_s * 1e3
+            self.last_sweep_stats["join_commit_ms"] = join_commit_s * 1e3
         if mesh is not None:
             # churn locality: the dirty rows' slabs are the only shards
             # whose resident state the next full placement must touch
@@ -4210,22 +4235,20 @@ class TpuDriver(InterpDriver):
             for ri in full[len(lst):]:
                 yield ri
 
-        def _join_complete(ci):
-            # complete candidate knowledge: the union below must cover
-            # the constraint's readers, and candidates() never extends
-            # st.cand past this exact condition
-            return (st.horizon[ci] is None
-                    or int(st.counts[ci]) <= len(st.cand[ci]))
-
         # ONE pruned join inventory per kind, shared by its constraints
         # (the full-sweep path's _inv_for argument: a provider SUPERSET
-        # is equivalence-safe, so the union of the kind's candidate
-        # rows serves every constraint) — K same-kind constraints
-        # missing the memo in one sweep build one tree, not K
+        # is equivalence-safe, so the union of the kind's KNOWN
+        # candidate rows serves every constraint) — K same-kind
+        # constraints missing the memo in one sweep build one tree, not
+        # K.  A cell renders against it only if its row is in the union
+        # (render() below): the rows a horizon fetch adds past the known
+        # candidates keep the full tree.  Candidate knowledge need not
+        # be complete: a capped walk that ends inside the known
+        # candidates (the usual case past the cap) never leaves the
+        # union, and one cell against the full tree is O(inventory).
         join_union: Dict[str, set] = {}
         for ci, (kind, _name, _c) in enumerate(ordered):
-            if (int(st.counts[ci]) == 0 or not self._join_safe(kind)
-                    or not _join_complete(ci)):
+            if int(st.counts[ci]) == 0 or not self._join_safe(kind):
                 continue
             tmpl = self.templates.get(kind)
             if tmpl is None or getattr(tmpl.policy, "uses_inventory",
@@ -4246,6 +4269,7 @@ class TpuDriver(InterpDriver):
             )
             join_strict = False
             join_inv = None
+            join_rows = ()  # the rows join_inv covers
             if uses_inv and self._join_safe(kind):
                 # every inventory read is a classified join plan: the
                 # join index bumps reader row generations when a key
@@ -4253,23 +4277,20 @@ class TpuDriver(InterpDriver):
                 # like inventory-free templates — O(churn) rendering
                 uses_inv = False
                 join_strict = self._join_strict(kind, constraint)
-                if _join_complete(ci):
-                    # grouped interpreter pass (docs/referential.md):
-                    # every flagged cell renders against ONE pruned
-                    # inventory holding the kind's key groups' provider
-                    # rows — the interp's O(R) per-cell inventory walk
-                    # becomes O(group).  LAZY: built on the first
-                    # render MISS, so steady-state memo-hit sweeps
-                    # never pay it.  Candidate knowledge must be
-                    # complete; the horizon-fetch fallback keeps the
-                    # full tree.
-                    join_inv = join_inv_by_kind.get(kind)
-                    if join_inv is None:
-                        join_inv = self._lazy_join_inventory(
-                            kind, sorted(join_union.get(kind, ())),
-                            inventory,
-                        )
-                        join_inv_by_kind[kind] = join_inv
+                # grouped interpreter pass (docs/referential.md): every
+                # flagged cell of a known candidate row renders against
+                # ONE pruned inventory holding the kind's key groups'
+                # provider rows — the interp's O(R) per-cell inventory
+                # walk becomes O(group).  LAZY: built on the first
+                # render MISS, so steady-state memo-hit sweeps never
+                # pay it.
+                join_rows = join_union.get(kind, ())
+                join_inv = join_inv_by_kind.get(kind)
+                if join_inv is None:
+                    join_inv = self._lazy_join_inventory(
+                        kind, sorted(join_rows), inventory,
+                    )
+                    join_inv_by_kind[kind] = join_inv
             lst = st.cand[ci]
             sig = None
             if trace is None and not uses_inv and len(lst) <= 512:
@@ -4302,7 +4323,8 @@ class TpuDriver(InterpDriver):
                 if ri >= R or reviews[ri] is None:
                     continue  # tombstoned row (valid=False on device too)
                 render(ri, kind, name, constraint, uses_inv, action,
-                       join_strict=join_strict, inv=join_inv)
+                       join_strict=join_strict,
+                       inv=join_inv if ri in join_rows else None)
                 rendered_cells += 1
             if not capped:
                 totals[ckey] = (len(results) - start, "exact")

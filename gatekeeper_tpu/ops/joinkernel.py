@@ -14,7 +14,13 @@ This module keeps referential templates inside the vectorized sweep:
   duplicate-key detection (unique ingress host), existence-of-referenced-row
   (required storage class), and count/group-by vs a parameter quota — and
   compiles it to a :class:`JoinPlan` + a ``JoinCmp`` IR node
-  (ops/vexpr.py) instead of bailing to the interpreter.
+  (ops/vexpr.py) instead of bailing to the interpreter.  The
+  duplicate-key family takes the upstream ``K8sUniqueServiceSelector``
+  clause as written: a key COMPUTED from a map (:func:`join_pairs`), an
+  inventory iteration that binds its namespace / name variables, and a
+  message that names the other row — sound under the identity rule
+  (``_check_benign_guards``), which :class:`JoinState` upholds by
+  tracking every provider row's identity.
 - all three families reduce to ONE aggregate: **distinct provider rows per
   interned join key**.  Key values are normalized type-tagged strings
   (:func:`normalize_join_key`) interned into the global vocabulary, so
@@ -136,6 +142,26 @@ def _canon_numbers(v: Any):
     if isinstance(v, dict):
         return {k: _canon_numbers(x) for k, x in v.items()}
     return v
+
+
+def join_pairs(v: Any, kv_sep: str, item_sep: str) -> str:
+    """A key COMPUTED from a map, as the Rego idiom the matcher
+    recognizes (upstream's ``flatten_selector``) evaluates it:
+    ``concat(item_sep, sort([concat(kv_sep, [k, x]) | x = v[k]]))``.
+
+    Always defined.  Only the string-valued entries of an object
+    contribute: ``concat`` refuses any other element, a builtin error is
+    undefined, and that fails ONE iteration of the comprehension, not the
+    comprehension; an absent, scalar or array ``v`` therefore gives the
+    empty string (array indices are numbers).  The string is the key —
+    two maps whose flattened forms coincide collide in the Rego too — and
+    it goes through :func:`normalize_join_key` like every other key."""
+    if not isinstance(v, dict):
+        return ""
+    return item_sep.join(sorted(
+        k + kv_sep + x for k, x in v.items()
+        if isinstance(k, str) and isinstance(x, str)
+    ))
 
 
 def intern_join_key(v: Any, interner: Interner) -> int:
@@ -311,10 +337,14 @@ class JoinBinding:
             if self.mode == "tables":
                 hit = (xp.asarray(arg["uk"]), xp.asarray(arg["uc"]))
             else:
-                hit = provider_key_table(
-                    plan, xp.asarray(arg["kind_id"]), self.rv, env.cols,
-                    xp, axis_name=self.axis_name,
-                )
+                import jax
+
+                # the scope names the build's ops in a device trace
+                with jax.named_scope("gk.join.table"):
+                    hit = provider_key_table(
+                        plan, xp.asarray(arg["kind_id"]), self.rv,
+                        env.cols, xp, axis_name=self.axis_name,
+                    )
             self.cache[plan] = hit
         return hit
 
@@ -387,6 +417,19 @@ def _keys_of_row(plan, colkey, slot, ap, row, part_ok: bool) -> Tuple[int, ...]:
     return (s,) if s >= 0 else ()
 
 
+def _row_identity(ap, row: int) -> Optional[Tuple]:
+    """The identity of the object a pack row holds — the inventory
+    path's (namespace, name) and the object's own metadata.namespace /
+    metadata.name: all a dup-family message may name of a provider (the
+    identity rule, ``_check_benign_guards``)."""
+    rv = ap.reviews[row] if row < len(ap.reviews) else None
+    if rv is None:
+        return None
+    meta = (rv.get("object") or {}).get("metadata") or {}
+    return (rv.get("namespace", ""), rv.get("name", ""),
+            meta.get("namespace"), meta.get("name"))
+
+
 class JoinState:
     """The join-group index: per plan, key -> provider rows (drives the
     aggregate) and key -> reader rows (rows whose verdict/message reads
@@ -412,6 +455,14 @@ class JoinState:
             {} for _ in range(n)
         ]
         self.row_rkeys: List[Dict[int, Tuple[int, ...]]] = [
+            {} for _ in range(n)
+        ]
+        # provider row -> the identity it held when indexed.  Rows are
+        # reused from a free list, so "delete B, create C with B's key"
+        # can leave a key's provider ROW set as it was while a reader's
+        # message ("same selector as <B>") went stale: a provider row
+        # whose identity changed counts as leaving and joining its keys
+        self.row_ident: List[Dict[int, Tuple]] = [
             {} for _ in range(n)
         ]
 
@@ -451,12 +502,21 @@ class JoinState:
                 plan, plan.local_colkey, plan.local_slot, ap, None
             )
             new_read, new_rowr = self._index(read_pairs)
+            new_ident = {r: _row_identity(ap, r) for r in new_rowp}
             if self.built:
                 old_prov, old_read = self.providers[i], self.readers[i]
-                for k in set(old_prov) | set(new_prov):
-                    if old_prov.get(k) != new_prov.get(k):
-                        bump |= old_read.get(k, set())
-                        bump |= new_read.get(k, set())
+                moved = {
+                    k for k in set(old_prov) | set(new_prov)
+                    if old_prov.get(k) != new_prov.get(k)
+                }
+                old_ident, old_rowp = self.row_ident[i], self.row_pkeys[i]
+                for r in set(old_ident) & set(new_ident):
+                    if old_ident[r] != new_ident[r]:
+                        moved.update(old_rowp.get(r, ()), new_rowp[r])
+                for k in moved:
+                    bump |= old_read.get(k, set())
+                    bump |= new_read.get(k, set())
+            self.row_ident[i] = new_ident
             self.providers[i] = new_prov
             self.readers[i] = new_read
             self.row_pkeys[i] = new_rowp
@@ -475,16 +535,25 @@ class JoinState:
             part = _provider_part(plan, ap, interner)
             changed: set = set()
             for r in dirty:
-                old = set(self.row_pkeys[i].get(r, ()))
-                new = set(_keys_of_row(
-                    plan, plan.remote_colkey, plan.remote_slot, ap, r,
-                    bool(part[r]),
-                ))
-                changed |= old ^ new
+                changed |= self._moved_keys(i, plan, ap, r, bool(part[r]))[0]
             readers = self.readers[i]
             for k in changed:
                 out |= readers.get(k, set())
         return out - set(dirty)
+
+    def _moved_keys(self, i: int, plan: JoinPlan, ap, r: int,
+                    part_ok: bool):
+        """(keys whose group dirty row ``r`` leaves or joins, its old
+        provider keys, its new ones, its new identity).  A row that keeps
+        its keys under another identity leaves and joins them all."""
+        old = set(self.row_pkeys[i].get(r, ()))
+        new = set(_keys_of_row(
+            plan, plan.remote_colkey, plan.remote_slot, ap, r, part_ok
+        ))
+        ident = _row_identity(ap, r) if new else None
+        if old and new and self.row_ident[i].get(r) != ident:
+            return old | new, old, new, ident
+        return old ^ new, old, new, ident
 
     def commit(self, ap, interner: Interner, dirty) -> set:
         """Apply a churn batch to the index; returns the affected reader
@@ -498,12 +567,14 @@ class JoinState:
             rowp, rowr = self.row_pkeys[i], self.row_rkeys[i]
             changed: set = set()
             for r in dirty:
-                old = set(rowp.get(r, ()))
-                new = set(_keys_of_row(
-                    plan, plan.remote_colkey, plan.remote_slot, ap, r,
-                    bool(part[r]),
-                ))
-                changed |= old ^ new
+                moved, old, new, ident = self._moved_keys(
+                    i, plan, ap, r, bool(part[r])
+                )
+                changed |= moved
+                if ident is None:
+                    self.row_ident[i].pop(r, None)
+                else:
+                    self.row_ident[i][r] = ident
                 for k in old - new:
                     s = prov.get(k)
                     if s is not None:
@@ -604,6 +675,10 @@ class JoinState:
                 {int(r): list(ks) for r, ks in rr.items()}
                 for rr in self.row_rkeys
             ],
+            "row_ident": [
+                {int(r): list(ident) for r, ident in ri.items()}
+                for ri in self.row_ident
+            ],
         }
 
     @classmethod
@@ -630,6 +705,10 @@ class JoinState:
             st.row_rkeys = [
                 {int(r): tuple(ks) for r, ks in rr.items()}
                 for rr in data["row_rkeys"]
+            ]
+            st.row_ident = [
+                {int(r): tuple(ident) for r, ident in ri.items()}
+                for ri in data["row_ident"]
             ]
         except (KeyError, TypeError, ValueError):
             return None
@@ -777,12 +856,14 @@ def _remote_rel_path(rhs, inv_var: str) -> Optional[Tuple[str, ...]]:
     return tuple(segs)
 
 
-def _remote_colspec(rel: Tuple[str, ...]):
+def _remote_colspec(rel: Tuple[str, ...], form: Tuple[str, ...] = ()):
     """Remote rel path (object-relative) -> joinkey ColumnSpec over the
     packed review rows (which nest the raw object under 'object')."""
     from .columns import ColumnSpec
 
     segs = ("object",) + rel
+    if form:
+        return ColumnSpec("joinkey", (), segs, form=form), False
     if "[]" in segs:
         last = len(segs) - 1 - segs[::-1].index("[]")
         return ColumnSpec(
@@ -867,13 +948,115 @@ class _ClauseScan:
             self.conds.append(stmt)
 
 
+def _pairs_key_helper(vec, name: str):
+    """A key COMPUTED by a helper of the shape of upstream's
+    ``flatten_selector``::
+
+        f(obj) = out {
+          xs := [s | s = concat(KV, [k, v]); v = obj.<path>[k]]
+          out := concat(SEP, sort(xs))
+        }
+
+    -> (path, ("joinpairs", KV, SEP)) — the key form ops/columns.py
+    extracts through :func:`join_pairs` — else None.  Statement order is
+    free (the safety pass reorders)."""
+    from ..rego.ast import ArrayCompr, ArrayTerm, Call, Ref, Var
+
+    rules = vec.cm.rules.get(name) or []
+    if len(rules) != 1:
+        return None
+    r = rules[0]
+    if not (r.is_function and len(r.args or ()) == 1 and r.els is None
+            and isinstance(r.args[0], Var) and isinstance(r.value, Var)
+            and len(r.body) == 2):
+        return None
+
+    def bound(body) -> dict:
+        """{var: term} of a body made of `var := term` statements only
+        (fewer entries than statements otherwise)."""
+        return {
+            stmt.terms[0].name: stmt.terms[1] for stmt in body
+            if stmt.kind in ("assign", "unify") and not stmt.withs
+            and isinstance(stmt.terms[0], Var)
+        }
+
+    def concat_of(t):
+        if isinstance(t, Call) and t.path == ("concat",) \
+                and len(t.args) == 2:
+            return _scalar_str(t.args[0]), t.args[1]
+        return None, None
+
+    obj, out = r.args[0].name, r.value.name
+    got = bound(r.body)
+    if len(got) != 2 or out not in got:
+        return None
+    (xs, compr), = ((n, t) for n, t in got.items() if n != out)
+    item_sep, sorted_xs = concat_of(got[out])
+    if not (item_sep is not None and isinstance(sorted_xs, Call)
+            and sorted_xs.path == ("sort",) and len(sorted_xs.args) == 1
+            and isinstance(sorted_xs.args[0], Var)
+            and sorted_xs.args[0].name == xs):
+        return None
+    if not (isinstance(compr, ArrayCompr) and isinstance(compr.head, Var)
+            and len(compr.body) == 2):
+        return None
+    inner = bound(compr.body)
+    s_var = compr.head.name
+    if len(inner) != 2 or s_var not in inner:
+        return None
+    (v_var, ref), = ((n, t) for n, t in inner.items() if n != s_var)
+    kv_sep, pair = concat_of(inner[s_var])
+    if not (kv_sep is not None and isinstance(pair, ArrayTerm)
+            and len(pair.items) == 2
+            and all(isinstance(x, Var) for x in pair.items)
+            and pair.items[1].name == v_var):
+        return None
+    k_var = pair.items[0].name
+    if not (isinstance(ref, Ref) and isinstance(ref.head, Var)
+            and ref.head.name == obj and len(ref.operands) >= 2
+            and isinstance(ref.operands[-1], Var)
+            and ref.operands[-1].name == k_var
+            and len({obj, out, xs, s_var, v_var, k_var}) == 6):
+        return None
+    path = tuple(_scalar_str(op) for op in ref.operands[:-1])
+    if None in path:
+        return None
+    return path, ("joinpairs", kv_sep, item_sep)
+
+
+def _computed_key(vec, rhs):
+    """``f(<arg>)`` with ``f`` a computed-key helper -> (arg term, path
+    under the argument, form), else None."""
+    from ..rego.ast import Call
+
+    if not (isinstance(rhs, Call) and len(rhs.path) == 1
+            and len(rhs.args) == 1):
+        return None
+    got = _pairs_key_helper(vec, rhs.path[0])
+    if got is None:
+        return None
+    return rhs.args[0], got[0], got[1]
+
+
 def _local_key_operand(vec, rhs, state):
-    """Resolve a local key source (iteration -> slot, or a review-rooted
-    scalar path) and register its joinkey column.  Returns
-    (colkey, slot?)."""
+    """Resolve a local key source (iteration -> slot, a review-rooted
+    scalar path, or a key computed from a review-rooted object) and
+    register its joinkey column.  Returns (colkey, slot?)."""
     from .columns import ColumnSpec
     from .vectorizer import SPath, _Unsupported
 
+    computed = _computed_key(vec, rhs)
+    if computed is not None:
+        arg, path, form = computed
+        try:
+            sym = vec._resolve(arg, {}, state)
+        except _Unsupported:
+            raise _NoMatch()
+        if not (isinstance(sym, SPath) and sym.root == "review"):
+            raise _NoMatch()
+        spec = ColumnSpec("joinkey", (), tuple(sym.segs) + path, form=form)
+        vec.columns[spec.key] = spec
+        return spec.key, False
     try:
         it = vec._try_iteration(rhs, {}, state)
     except _Unsupported:
@@ -893,12 +1076,44 @@ def _local_key_operand(vec, rhs, state):
     raise _NoMatch()
 
 
-def _check_benign_guards(scan, consumed: set, remote_vars: set):
+def _strip_identity(node, inv_var: Optional[str]):
+    """The term with every ``<inv_var>.metadata.name`` /
+    ``.metadata.namespace`` ref replaced by a constant: what is left of
+    the provider row after its IDENTITY is taken out."""
+    from ..rego.ast import ArrayTerm, Call, Ref, Scalar, SetTerm, Var
+
+    if isinstance(node, Ref):
+        if (inv_var is not None and isinstance(node.head, Var)
+                and node.head.name == inv_var
+                and [_scalar_str(op) for op in node.operands]
+                in (["metadata", "name"], ["metadata", "namespace"])):
+            return Scalar("")
+        return node
+    if isinstance(node, Call):
+        return Call(node.path, tuple(
+            _strip_identity(a, inv_var) for a in node.args))
+    if isinstance(node, (ArrayTerm, SetTerm)):
+        return type(node)(tuple(
+            _strip_identity(x, inv_var) for x in node.items))
+    return node
+
+
+def _check_benign_guards(scan, consumed: set, remote_vars: set,
+                         inv_var: Optional[str] = None):
     """Assignments the matcher did not consume must be benign calls
-    (sprintf & friends) referencing no remote entity — a message that
-    embeds the OTHER row's fields depends on group content the delta
-    invalidation cannot see, so such clauses stay on the interpreter
-    tier.  The violation head is checked the same way."""
+    (sprintf & friends).  A message that embeds the OTHER row's fields
+    depends on group content the delta invalidation cannot see, so such
+    clauses stay on the interpreter tier — with ONE exception, the
+    identity rule: the provider's identity (the inventory iteration's own
+    namespace / name variables, which the caller leaves out of
+    ``remote_vars``, and ``<inv_var>.metadata.name`` /
+    ``.metadata.namespace``) may be named.
+    Which identities share a key changes only when a row joins or leaves
+    the key's group or a group's row changes identity, and every such
+    row is a dirty row of that group, whose readers JoinState re-renders
+    (``affected`` / ``commit`` / ``rebuild``).  Any other remote field
+    can change under a reader without its group changing.  The violation
+    head is checked the same way."""
     from ..rego.ast import Call
 
     from .vectorizer import _BENIGN_CALLS
@@ -909,70 +1124,93 @@ def _check_benign_guards(scan, consumed: set, remote_vars: set):
         if not (isinstance(rhs, Call)
                 and ".".join(rhs.path) in _BENIGN_CALLS):
             raise _NoMatch()
-        if _vars_in(rhs) & remote_vars:
+        if _vars_in(_strip_identity(rhs, inv_var)) & remote_vars:
             raise _NoMatch()
-    if scan.rule.key is not None and _vars_in(scan.rule.key) & remote_vars:
+    if scan.rule.key is not None and _vars_in(
+        _strip_identity(scan.rule.key, inv_var)
+    ) & remote_vars:
         raise _NoMatch()
 
 
 def _match_dup(vec, scan: _ClauseScan):
-    """unique-key family: local (slot or scalar) key, an inventory
-    iteration of the same kind, a remote key equal to the local key, and
-    an object-identity self-exclusion helper under ``not``."""
+    """unique-key family: a local key (slot, scalar, or computed from a
+    map), an inventory iteration of the same kind, a remote key of the
+    same form equal to the local key, and an object-identity
+    self-exclusion helper under ``not``.  The iteration may BIND its
+    namespace / name variables when the clause uses them only to name the
+    provider (the identity rule, ``_check_benign_guards``); conditions on
+    the local row alone (``input.review.kind.kind == "Service"``) compile
+    through the vectorizer and AND with the aggregate."""
     from ..rego.ast import BinOp, Call, Ref, Var
 
     state = {"slot": None}
     inv = None
     inv_var = None
+    identity_vars: set = set()
     for name, rhs, _stmt in scan.assigns:
         got = _inventory_iter(rhs)
         if got is not None:
             if inv is not None:
                 raise _NoMatch()
-            # violation-clause inventory vars must be wildcards: a bound
-            # scope var would correlate with the local row (unsupported)
             scope, kind, vs = got
-            for v in (vs["ns"], vs["gv"], vs["name"]):
-                if v is not None and not v.is_wildcard:
-                    raise _NoMatch()
+            if not vs["gv"].is_wildcard:
+                raise _NoMatch()
+            # a bound namespace / name var is the provider's identity;
+            # used anywhere but a message it would correlate with the
+            # local row (checked below: conditions may not mention it)
+            identity_vars = {
+                v.name for v in (vs["ns"], vs["name"])
+                if v is not None and not v.is_wildcard
+            }
             inv, inv_var = (scope, kind), name
     if inv is None:
         raise _NoMatch()
     scope, kind = inv
-    # remote key: either a var assigned from `other.<path>[_]...` or a
-    # direct `other.<path> == key` comparison side
-    remote_key_vars: Dict[str, Tuple[str, ...]] = {}
+    # remote key: a var assigned from `other.<path>[_]...` or from a
+    # computed-key helper applied to `other`, or a direct
+    # `other.<path> == key` comparison side
+    remote_key_vars: Dict[str, Tuple] = {}
     for name, rhs, _stmt in scan.assigns:
         if name == inv_var:
             continue
         rel = _remote_rel_path(rhs, inv_var)
         if rel is not None:
-            remote_key_vars[name] = rel
+            remote_key_vars[name] = (rel, ())
+            continue
+        computed = _computed_key(vec, rhs)
+        if computed is not None and isinstance(computed[0], Var) \
+                and computed[0].name == inv_var:
+            remote_key_vars[name] = (computed[1], computed[2])
+    remote_vars = {inv_var} | set(remote_key_vars) | identity_vars
 
     # the equality condition joining local and remote keys decides which
-    # local var is the key
-    remote_rel = None
+    # local var is the key; every other condition is on the local row
+    remote_key = None
     local_var = None
+    local_conds: List = []
     for stmt in scan.conds:
         t = stmt.terms[0]
+        if not _vars_in(t) & remote_vars:
+            local_conds.append(stmt)
+            continue
         if not (isinstance(t, BinOp) and t.op == "=="):
             raise _NoMatch()
         for a, b in ((t.lhs, t.rhs), (t.rhs, t.lhs)):
-            if not isinstance(a, Var) or a.name in remote_key_vars \
-                    or a.name == inv_var:
+            if not isinstance(a, Var) or a.name in remote_vars:
                 continue
-            rel = (
-                remote_key_vars.get(b.name)
-                if isinstance(b, Var) else _remote_rel_path(b, inv_var)
-            )
-            if rel is not None:
-                if remote_rel is not None:
+            if isinstance(b, Var):
+                rk = remote_key_vars.get(b.name)
+            else:
+                rel = _remote_rel_path(b, inv_var)
+                rk = None if rel is None else (rel, ())
+            if rk is not None:
+                if remote_key is not None:
                     raise _NoMatch()  # one join equality per clause
-                remote_rel, local_var = rel, a.name
+                remote_key, local_var = rk, a.name
                 break
         else:
             raise _NoMatch()
-    if remote_rel is None or local_var is None:
+    if remote_key is None or local_var is None:
         raise _NoMatch()
     local_key = None
     for name, rhs, _stmt in scan.assigns:
@@ -997,18 +1235,28 @@ def _match_dup(vec, scan: _ClauseScan):
         raise _NoMatch()
     _check_identity_helper(vec, t.path[0], scope)
 
-    remote_vars = {inv_var} | set(remote_key_vars)
-    _check_benign_guards(scan, {local_var, inv_var} | set(remote_key_vars),
-                         remote_vars)
+    _check_benign_guards(
+        scan, {local_var, inv_var} | set(remote_key_vars),
+        remote_vars - identity_vars, inv_var=inv_var,
+    )
 
+    from .vectorizer import _Unsupported
     from .vexpr import Clause, JoinCmp, Lit
 
-    rspec, rslot = _remote_colspec(remote_rel)
+    rspec, rslot = _remote_colspec(*remote_key)
     if (rspec.key, rslot) != (local_key[0], local_key[1]):
         # self-exclusion (counts - own contribution) is only exact when
         # the local key IS the row's provider key — different local and
-        # remote paths stay on the interpreter tier
+        # remote paths or forms stay on the interpreter tier
         raise _NoMatch()
+    conds: List = []
+    for stmt in local_conds:
+        try:
+            vec._compile_stmt(stmt, {}, conds, state, exact_required=True)
+        except _Unsupported:
+            raise _NoMatch()
+    if state["slot"] is not None and not local_key[1]:
+        raise _NoMatch()  # a local condition opened a slot axis
     vec.columns[rspec.key] = rspec
     plan = JoinPlan(
         agg="dup", local_colkey=local_key[0], local_slot=local_key[1],
@@ -1020,15 +1268,83 @@ def _match_dup(vec, scan: _ClauseScan):
     # key, minus this row's own contribution, >= 1
     node = JoinCmp(pid, ">=", Lit(1), slot=local_key[1],
                    exclude_self=True)
-    return Clause(conds=(node,), slot_iter=state["slot"])
+    return Clause(conds=tuple(conds) + (node,), slot_iter=state["slot"])
+
+
+def _is_apiversion_helper(vec, name: str) -> bool:
+    """``make_apiversion(kind)``: "<group>/<version>", or the bare version
+    for the core group — the apiVersion of the object a review is of, so
+    ``other.apiVersion == make_apiversion(review.kind)`` holds when
+    ``other`` is the reviewed row itself."""
+    from ..rego.ast import ArrayTerm, BinOp, Call, Ref, Var
+
+    rules = vec.cm.rules.get(name) or []
+    forms = set()
+    for r in rules:
+        if not (r.is_function and len(r.args or ()) == 1
+                and r.els is None and isinstance(r.args[0], Var)
+                and isinstance(r.value, Var)):
+            return False
+        k, out = r.args[0].name, r.value.name
+        alias: Dict[str, str] = {}
+
+        def field(t):
+            if isinstance(t, Var):
+                return alias.get(t.name)
+            if (isinstance(t, Ref) and isinstance(t.head, Var)
+                    and t.head.name == k and len(t.operands) == 1):
+                f = _scalar_str(t.operands[0])
+                return f if f in ("group", "version") else None
+            return None
+
+        group_empty = None
+        form = None
+        for stmt in r.body:
+            t0 = stmt.terms[0]
+            if stmt.kind in ("assign", "unify") and isinstance(t0, Var):
+                rhs = stmt.terms[1]
+                if t0.name != out:
+                    if field(rhs) is None:
+                        return False
+                    alias[t0.name] = field(rhs)
+                elif field(rhs) == "version":
+                    form = "v"
+                elif (isinstance(rhs, Call) and rhs.path == ("sprintf",)
+                        and len(rhs.args) == 2
+                        and _scalar_str(rhs.args[0]) == "%v/%v"
+                        and isinstance(rhs.args[1], ArrayTerm)
+                        and [field(x) for x in rhs.args[1].items]
+                        == ["group", "version"]):
+                    form = "gv"
+                else:
+                    return False
+            elif (stmt.kind == "term" and isinstance(t0, BinOp)
+                    and t0.op in ("==", "!=")):
+                sides = {field(t0.lhs), field(t0.rhs)}
+                lits = {_scalar_str(t0.lhs), _scalar_str(t0.rhs)}
+                if sides != {"group", None} or "" not in lits:
+                    return False
+                group_empty = t0.op == "=="
+            else:
+                return False
+        if (form, group_empty) not in (("v", True), ("gv", False)):
+            return False
+        forms.add(form)
+    return forms == {"v", "gv"}
 
 
 def _check_identity_helper(vec, name: str, scope: str):
     """The self-exclusion helper must compare exactly the fields that
     identify an object in the plan's scope: metadata.name (+ namespace
-    when namespace-scoped).  Anything else narrows or widens identity in
-    ways the distinct-row aggregate cannot express."""
-    from ..rego.ast import BinOp, Ref, Var
+    when namespace-scoped), against the review's object or the review's
+    own name / namespace.  It may also hold the provider to the review's
+    kind (``o.kind == review.kind.kind``) and apiVersion
+    (``o.apiVersion == make_apiversion(review.kind)``): both hold when
+    ``o`` is the reviewed row itself, so the row is still its own only
+    exclusion; the apiVersion check makes groupVersion twins two
+    objects, as the distinct-row aggregate counts them.  Anything else
+    narrows or widens identity in ways the aggregate cannot express."""
+    from ..rego.ast import BinOp, Call, Ref, Var
 
     rules = vec.cm.rules.get(name) or []
     if len(rules) != 1:
@@ -1044,6 +1360,25 @@ def _check_identity_helper(vec, name: str, scope: str):
     o_var, rv_var = r.args
     if not (isinstance(o_var, Var) and isinstance(rv_var, Var)):
         raise _NoMatch()
+
+    def segs_of(t, head):
+        if not (isinstance(t, Ref) and isinstance(t.head, Var)
+                and t.head.name == head):
+            return None
+        segs = [_scalar_str(op) for op in t.operands]
+        return None if None in segs else segs
+
+    def same_kind_or_version(a, b2) -> bool:
+        o = segs_of(a, o_var.name)
+        if o == ["kind"]:
+            return segs_of(b2, rv_var.name) == ["kind", "kind"]
+        if o == ["apiVersion"]:
+            return (isinstance(b2, Call) and len(b2.path) == 1
+                    and len(b2.args) == 1
+                    and segs_of(b2.args[0], rv_var.name) == ["kind"]
+                    and _is_apiversion_helper(vec, b2.path[0]))
+        return False
+
     fields = set()
     for stmt in r.body:
         if stmt.kind != "term" or not isinstance(stmt.terms[0], BinOp):
@@ -1051,21 +1386,14 @@ def _check_identity_helper(vec, name: str, scope: str):
         b = stmt.terms[0]
         if b.op != "==":
             raise _NoMatch()
-
-        def field_of(t, head, prefix):
-            if not (isinstance(t, Ref) and isinstance(t.head, Var)
-                    and t.head.name == head):
-                return None
-            segs = [_scalar_str(op) for op in t.operands]
-            if None in segs or segs[:-1] != prefix:
-                return None
-            return segs[-1]
-
         for a, b2 in ((b.lhs, b.rhs), (b.rhs, b.lhs)):
-            f1 = field_of(a, o_var.name, ["metadata"])
-            f2 = field_of(b2, rv_var.name, ["object", "metadata"])
-            if f1 is not None and f2 is not None and f1 == f2:
-                fields.add(f1)
+            o = segs_of(a, o_var.name)
+            rv = segs_of(b2, rv_var.name)
+            if (o is not None and len(o) == 2 and o[0] == "metadata"
+                    and rv in (["object"] + o, [o[1]])):
+                fields.add(o[1])
+                break
+            if same_kind_or_version(a, b2):
                 break
         else:
             raise _NoMatch()

@@ -152,7 +152,8 @@ class TestClassification:
         assert prog.join_plans[0].agg == agg
 
     def test_message_reading_remote_entity_stays_interp(self):
-        # a message embedding the OTHER row's fields depends on group
+        # a message embedding the OTHER row's fields (beyond its
+        # identity: tests/test_joinkernel_selector.py) depends on group
         # content the delta invalidation cannot see -> no plan
         rego = """
 package refbad
@@ -163,7 +164,7 @@ violation[{"msg": msg}] {
   otherhost := other.spec.rules[_].host
   host == otherhost
   not identical(other, input.review)
-  msg := sprintf("duplicate of %v", [other.metadata.name])
+  msg := sprintf("duplicate of %v", [other.metadata.uid])
 }
 
 identical(obj, review) {
