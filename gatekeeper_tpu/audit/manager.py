@@ -37,7 +37,7 @@ from ..obs import trace as obstrace
 from ..process.excluder import AUDIT, Excluder
 from ..target.target import AugmentedUnstructured
 from ..util import KNOWN_ENFORCEMENT_ACTIONS, get_enforcement_action
-from ..util import join_thread
+from ..util import heap, join_thread
 
 log = gklog.get("audit")
 
@@ -171,6 +171,9 @@ class AuditManager:
         if self._thread:
             join_thread(self._thread, 2.0, "audit loop")
             self._thread = None
+        # the sweeps' heap discipline (util/heap.py, engaged from
+        # Client._sweep_done): an embedding process gets its collector back
+        heap.release()
 
     def _loop(self):
         from ..obs import brownout as _brownout

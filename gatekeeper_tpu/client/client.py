@@ -18,6 +18,7 @@ from ..engine.interp import TemplatePolicy
 from ..obs import trace as obstrace
 from ..rego.ast import RegoError
 from ..target.target import K8sValidationTarget, WipeData
+from ..util import heap
 from . import crd as crdlib
 from .drivers import CompiledTemplate, Driver, InterpDriver, Result
 
@@ -281,17 +282,27 @@ class Client:
             self._sweep_done(clock)
 
     def _sweep_done(self, clock) -> None:
-        """Stop the sweep's clock, flush it to the counters, and add to
-        the driver's last_sweep_stats what only the clock knows: this
-        thread's `ingest` since its previous sweep, the `cap` stage, and
-        the collector's pauses inside the sweep's stages."""
+        """Run the heap discipline at the sweep's boundary (util/heap.py:
+        stage `collect`, opened only once a full collection inside a
+        sweep has engaged it), stop the sweep's clock, flush it to the
+        counters, and add to the driver's last_sweep_stats what only the
+        clock knows: this thread's `ingest` since its previous sweep,
+        the `cap` and `collect` stages, and the collector's pauses
+        inside the sweep's stages (generation 2, and the younger two)."""
+        if heap.engaged():
+            heap.after_sweep(clock.mark("collect"))
+        elif clock.gc_full_unlapsed() >= heap.ENGAGE_MIN_PAUSE_S:
+            heap.engage(clock.mark("collect"))
         clock.stop()
         rows, gc_full_s = clock.lapse()
         stats = getattr(self.driver, "last_sweep_stats", None)
         if isinstance(stats, dict) and stats:
             stats["ingest_ms"] = rows.get("ingest", (0.0,))[0] * 1e3
             stats["cap_ms"] = rows.get("cap", (0.0,))[0] * 1e3
+            stats["collect_ms"] = rows.get("collect", (0.0,))[0] * 1e3
             stats["gc_full_ms"] = gc_full_s * 1e3
+            stats["gc_young_ms"] = max(
+                0.0, sum(r[2] for r in rows.values()) - gc_full_s) * 1e3
         clock.flush()
 
     def _rebuild_resources(self, results):

@@ -555,6 +555,19 @@ GC_COLLECTIONS_M = Measure(
     "gc_collections",
     "Garbage collections run, by generation",
 )
+HEAP_COLLECTIONS_M = Measure(
+    "heap_collections",
+    "Explicit full collections the sweeping process's heap discipline "
+    "ran at a sweep's boundary (util/heap.py; the first is engage()'s): "
+    "0 means no full collection has landed inside a sweep to engage it",
+)
+HEAP_FROZEN_M = Measure(
+    "heap_frozen_objects",
+    "Objects the heap discipline's engagement moved to the collector's "
+    "permanent generation: the long-lived heap no later collection "
+    "walks (its objects are still freed by reference counting); 0 once "
+    "released",
+)
 PROCESS_CPU_M = Measure(
     "process_cpu_seconds",
     "CPU seconds (user + system, all threads) this process has used "
@@ -764,6 +777,8 @@ def catalog_views():
         View("gc_collections_total", GC_COLLECTIONS_M, AGG_SUM,
              tag_keys=("generation",)),
         View("process_cpu_seconds_total", PROCESS_CPU_M, AGG_SUM),
+        View("heap_collections_total", HEAP_COLLECTIONS_M, AGG_SUM),
+        View("heap_frozen_objects", HEAP_FROZEN_M, AGG_LAST_VALUE),
     ]
 
 
@@ -1558,3 +1573,17 @@ def record_process_counters(gc_pause_s, gc_runs, gc_background_s: float,
             reg.record(PROCESS_CPU_M, cpu_s)
     except Exception:  # telemetry never blocks the scrape
         record_dropped("record_process_counters")
+
+
+def record_heap(collections: int, frozen: int):
+    """Scrape-time push of the heap discipline (util/heap.py, through
+    obs/trace.py collect_hook): explicit collections since the last
+    scrape, objects the engagement froze.  A process that never
+    engaged it exports neither series.  Guarded like record_stage."""
+    try:
+        reg = _global()
+        if collections:
+            reg.record(HEAP_COLLECTIONS_M, float(collections))
+        reg.record(HEAP_FROZEN_M, float(frozen))
+    except Exception:  # telemetry never blocks the scrape
+        record_dropped("record_heap")

@@ -775,6 +775,11 @@ class StageClock:
                 seen[stage] = cur
         return out
 
+    def gc_full_unlapsed(self) -> float:
+        """Generation-2 pause seconds inside this clock's stages since
+        the previous lapse(): what the sweep about to end has seen."""
+        return self.gc_full_s - self._gc_full_lapped
+
     def lapse(self) -> Tuple[Dict[str, tuple], float]:
         """({stage: (seconds, calls, collector seconds)}, generation-2
         pause seconds) closed since the previous lapse() — one sweep's
@@ -872,7 +877,7 @@ _GC_RUNS = [0, 0, 0]
 _GC_BACKGROUND_S = [0.0]
 _GC_OPEN: list = []   # (start, annotation) of the collection in progress
 _GC_PUSHED = {"pause": [0.0, 0.0, 0.0], "runs": [0, 0, 0], "bg": 0.0,
-              "cpu": 0.0}
+              "cpu": 0.0, "heap": 0}
 _GC_PUSH_LOCK = threading.Lock()   # two scrapes must not push one growth
 
 
@@ -916,9 +921,11 @@ def _install_gc_hook() -> None:
 
 def collect_hook(registry=None) -> None:
     """Scrape-time push (MetricsExporter collect hook): the collector's
-    pauses and collections per generation, the background share, and
-    process_cpu_seconds_total — read when asked, no refresher thread."""
-    from ..metrics.catalog import record_process_counters
+    pauses and collections per generation, the background share,
+    process_cpu_seconds_total, and what the sweeps' heap discipline did
+    (util/heap.py) — read when asked, no refresher thread."""
+    from ..metrics.catalog import record_heap, record_process_counters
+    from ..util import heap
 
     pushed = _GC_PUSHED
     with _GC_PUSH_LOCK:
@@ -932,6 +939,10 @@ def collect_hook(registry=None) -> None:
             pushed["runs"][g] += runs[g]
         pushed["bg"] += bg
         pushed["cpu"] = cpu
+        collections, frozen = heap.counters()
+        if collections:   # engaged at least once
+            record_heap(collections - pushed["heap"], frozen)
+            pushed["heap"] = collections
 
 
 def dump_stacks() -> dict:

@@ -43,6 +43,15 @@ from gatekeeper_tpu.ops.driver import TpuDriver  # noqa: E402
 
 TpuDriver.DELTA_MASK_WAIT_S = 300.0
 
+# The sweeps' heap discipline (util/heap.py) engages when a full
+# collection lands inside a sweep.  A pytest worker lives for hundreds
+# of tests and its collections land where they will: pinned off, so no
+# test's sweep freezes the worker's heap and leaves its collector
+# disabled (tests/test_sweep_heap.py sets the gate itself).
+from gatekeeper_tpu.util import heap  # noqa: E402
+
+heap.ENGAGE_MIN_PAUSE_S = float("inf")
+
 # ---- chaos hygiene: no test may leak live fault-plane state or threads -----
 
 import threading  # noqa: E402

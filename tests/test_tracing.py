@@ -808,7 +808,8 @@ def test_sweep_stats_carry_every_stage_of_the_audit_clock(kind):
         wall_ms = (time.perf_counter() - t0) * 1e3
         assert driver.last_sweep_stats.get("delta_rows") == 3.0
     stats = driver.last_sweep_stats
-    for key in SWEEP_STAGE_KEYS + ("gc_full_ms",):
+    for key in SWEEP_STAGE_KEYS + ("collect_ms", "gc_full_ms",
+                                   "gc_young_ms"):
         assert key in stats, key
         assert stats[key] >= 0.0
     assert stats["fetch_ms"] > 0
