@@ -2315,20 +2315,13 @@ def bench_fleet() -> dict:
         restored corpus through review_batch concurrently (the batch1m
         chunk shape, in-process per replica so the HTTP framing cost —
         measured separately above — does not mask engine throughput).
-
-    BENCH_EDGE selects the front door serving the fleet sections:
-    "evloop" (default — the selectors reactor over the replicas' wire
-    listeners) or "threaded" (the deprecated thread-per-request
-    FrontDoor, kept measurable behind this explicit opt-in; see
-    docs/fleet.md).  The dedicated event-edge rounds (EDGE_r19) run in
-    either mode.
     """
     import http.client as _httpc
     import shutil
     import tempfile
     import threading
 
-    from gatekeeper_tpu.fleet import EventFrontDoor, FrontDoor, spawn_fleet
+    from gatekeeper_tpu.fleet import EventFrontDoor, spawn_fleet
     from gatekeeper_tpu.snapshot import Snapshotter
     from gatekeeper_tpu.util.synthetic import (
         build_driver,
@@ -2343,10 +2336,6 @@ def bench_fleet() -> dict:
     chunk = int(os.environ.get("BENCH_FLEET_CHUNK", "16384"))
     n_latency = int(os.environ.get("BENCH_FLEET_LATENCY_N", "400"))
     n_parity = int(os.environ.get("BENCH_FLEET_PARITY_N", "64"))
-    edge_kind = os.environ.get("BENCH_EDGE", "evloop")
-    if edge_kind not in ("evloop", "threaded"):
-        raise RuntimeError(f"BENCH_EDGE={edge_kind!r}: expected "
-                           "'evloop' or 'threaded'")
 
     root = tempfile.mkdtemp(prefix="gk-fleet-bench-")
     snap_dir = os.path.join(root, "snap")
@@ -2432,20 +2421,13 @@ def bench_fleet() -> dict:
             for h in handles
         ))
 
-        # the event door is the default serving edge (satellite of
-        # ISSUE 20: the threaded FrontDoor is deprecated and must be
-        # asked for explicitly with BENCH_EDGE=threaded)
-        if edge_kind == "threaded":
-            door = FrontDoor([h.backend() for h in handles]).start()
-        else:
-            no_wire = [h.replica_id for h in handles if not h.wire_port]
-            if no_wire:
-                raise RuntimeError(
-                    f"replicas {no_wire} announced no wire port — the "
-                    "default evloop edge cannot serve (BENCH_EDGE="
-                    "threaded to force the deprecated door)")
-            door = EventFrontDoor(
-                [h.wire_backend() for h in handles]).start()
+        no_wire = [h.replica_id for h in handles if not h.wire_port]
+        if no_wire:
+            raise RuntimeError(
+                f"replicas {no_wire} announced no wire port — the "
+                "door cannot serve")
+        door = EventFrontDoor(
+            [h.wire_backend() for h in handles]).start()
 
         # ---- parity: byte-identical across replicas, verdicts vs oracle --
         parity = True
@@ -2512,7 +2494,7 @@ def bench_fleet() -> dict:
         # from the parent tracer's wire traces, the no-dark-time share
         # (stage p50s vs the wire p50), the federated /metrics view, and
         # one seeded slow request assembled across processes.
-        from gatekeeper_tpu.fleet.frontdoor import WIRE_STAGES
+        from gatekeeper_tpu.fleet.wireproto import WIRE_STAGES
         from gatekeeper_tpu.obs import fleetobs
         from gatekeeper_tpu.obs import trace as obstrace
 
@@ -2807,7 +2789,7 @@ def bench_fleet() -> dict:
         # The selectors-based serving edge over the SAME warm replicas:
         #   (a) persistent-connection latency with per-stage p50s from
         #       the ring traces (sample 1.0), against the front
-        #       section's stage numbers above (the BENCH_EDGE door);
+        #       section's stage numbers above;
         #   (b) the door-capacity headline against an in-process stub
         #       wire responder — the front door's own data plane
         #       (accept/parse/route/splice/write), isolated from engine
@@ -2905,12 +2887,11 @@ def bench_fleet() -> dict:
             e_stage_p99 = {s: pct(sorted(xs), 0.99)
                            for s, xs in e_per_stage.items()}
             stage_p50_vs_front = {
-                s: {f"{edge_kind}_ms": stage_p50.get(s),
-                    "evloop_ms": e_stage_p50.get(s)}
+                s: {"evloop_ms": e_stage_p50.get(s)}
                 for s in WIRE_STAGES
             }
             log(f"fleet: event edge wire p50={pct(e_durs, 0.50)}ms over "
-                f"{len(e_wire)} traces; stage p50 vs {edge_kind} front: "
+                f"{len(e_wire)} traces; stage p50 vs front: "
                 + ", ".join(
                     f"{s} {e_stage_p50.get(s)}/{stage_p50.get(s)}"
                     for s in ("accept", "proxy_connect", "write_back")))
@@ -3079,7 +3060,7 @@ def bench_fleet() -> dict:
                 "wire_traces": len(e_wire),
                 "stage_p50_ms": e_stage_p50,
                 "stage_p99_ms": e_stage_p99,
-                "front_door_edge": edge_kind,
+                "front_door_edge": "evloop",
                 "stage_p50_vs_front_door": stage_p50_vs_front,
                 "overload": {
                     "counts": o_counts,
@@ -3452,7 +3433,7 @@ def bench_chaos_fleet() -> dict:
     import shutil
     import tempfile
 
-    from gatekeeper_tpu.fleet import FrontDoor, ReplicaSupervisor
+    from gatekeeper_tpu.fleet import EventFrontDoor, ReplicaSupervisor
     from gatekeeper_tpu.fleet.replica import spawn_replica
     from gatekeeper_tpu.snapshot import Snapshotter
     from gatekeeper_tpu.util.synthetic import (
@@ -3528,7 +3509,8 @@ def bench_chaos_fleet() -> dict:
         if backend is None:
             d.suspend(rid)
         else:
-            d.set_backend(rid, backend["host"], backend["port"])
+            d.set_backend(rid, backend["host"], backend["port"],
+                          backend.get("probe_port", 0))
 
     sup = ReplicaSupervisor(
         snapshot_dir=snap_dir, env=base_env,
@@ -3545,8 +3527,9 @@ def bench_chaos_fleet() -> dict:
             assert h.ready.get("restore_outcome") == "restored", h.ready
             sup.adopt(h)
         sup.start_monitor()
-        door = FrontDoor(
-            [h_wedge.backend(), h_crash.backend()], probe_interval_s=0.1
+        door = EventFrontDoor(
+            [h_wedge.wire_backend(), h_crash.wire_backend()],
+            probe_interval_s=0.1,
         ).start()
         door_box["door"] = door
         log(f"chaos_fleet: r0(wedge@~{wedge_after} pings) "
@@ -3719,7 +3702,7 @@ def bench_overload() -> dict:
 
     from gatekeeper_tpu import faults as _faults
     from gatekeeper_tpu.faults import FaultRule
-    from gatekeeper_tpu.fleet import FrontDoor, spawn_fleet
+    from gatekeeper_tpu.fleet import EventFrontDoor, spawn_fleet
     from gatekeeper_tpu.obs import brownout as obsbrownout
     from gatekeeper_tpu.obs import trace as obstrace
     from gatekeeper_tpu.snapshot import Snapshotter
@@ -3792,8 +3775,8 @@ def bench_overload() -> dict:
     try:
         for h in handles:
             assert h.ready.get("restore_outcome") == "restored", h.ready
-        door = FrontDoor(
-            [h.backend() for h in handles], probe_interval_s=0.1,
+        door = EventFrontDoor(
+            [h.wire_backend() for h in handles], probe_interval_s=0.1,
             max_inflight=max_inflight, admission_budget_s=budget_s,
         ).start()
         # a deep trace ring: door-side shed latency is read from the

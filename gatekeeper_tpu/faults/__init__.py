@@ -47,11 +47,11 @@ PROFILER_STALL = "obs.profiler_stall"  # obs/profiler.py sampler tick (hang
 #                                        = a wedged sampler; snapshots and
 #                                        the hot path must keep serving)
 # overload robustness plane (ISSUE 12)
-OVERLOAD_STORM = "fleet.overload_storm"  # fleet/frontdoor.py admission POST
+OVERLOAD_STORM = "fleet.overload_storm"  # fleet/evdoor.py proxied attempt
 #                                        before routing (latency = handler
 #                                        threads held -> inflight climbs ->
 #                                        the shed/brownout path exercises)
-SLOW_CLIENT = "frontdoor.slow_client"   # fleet/frontdoor.py inbound body
+SLOW_CLIENT = "frontdoor.slow_client"   # fleet/evdoor.py inbound read
 #                                        read (latency = a client trickling
 #                                        its body holds an accept thread —
 #                                        bounded by the inbound socket

@@ -14,17 +14,17 @@ Pieces:
 - :mod:`replica` — the replica worker runtime (subprocess entry point +
   parent-side spawn/ready/stop helpers used by ``bench.py fleet`` and
   ``tools/check_fleet_parity.py``);
-- :mod:`frontdoor` — a stdlib HTTP front door (round-robin or
-  least-inflight, with health-based ejection, probing readmission and
-  a bounded single retry) for benching and parity checks; production
-  fleets use a Service/LB, this one exists so the repo can DRIVE and
-  PROVE the topology end to end;
-- :mod:`evloop` / :mod:`wireproto` / :mod:`evdoor` /
-  :mod:`wirelistener` — the event-loop admission data plane (ISSUE 19):
-  a selectors-based reactor, the framed chunk protocol, the
-  non-blocking front door (persistent pipelined client connections,
-  byte-splice proxying) and the replica-side batch listener that feeds
-  whole chunks into the micro-batcher via ``submit_many``;
+- :mod:`evdoor` — the front door: a selectors reactor with persistent
+  pipelined client connections and byte-splice proxying, for benching
+  and parity checks; production fleets use a Service/LB, this one
+  exists so the repo can DRIVE and PROVE the topology end to end;
+- :mod:`roster` — the door's control plane: round-robin or
+  least-inflight choice with a locked inflight reservation,
+  health-based ejection, probing readmission, the retry token bucket;
+- :mod:`evloop` / :mod:`wireproto` / :mod:`wirelistener` — the
+  reactor, the framed chunk protocol (and the names both ends of the
+  hop share), and the replica-side batch listener that feeds whole
+  chunks into the micro-batcher via ``submit_many``;
 - :mod:`supervisor` — replica supervision (exit/wedge detection, warm
   restarts with capped backoff, flap quarantine, graceful drain and
   zero-failed-admission rolling restarts; ISSUE 8,
@@ -44,14 +44,12 @@ payload.
 """
 
 from .evdoor import EventFrontDoor
-from .frontdoor import FrontDoor
 from .replica import ReplicaHandle, spawn_replica, spawn_fleet
 from .supervisor import ReplicaSupervisor
 from .wirelistener import WireListener
 
 __all__ = [
     "EventFrontDoor",
-    "FrontDoor",
     "ReplicaHandle",
     "ReplicaSupervisor",
     "WireListener",

@@ -1,21 +1,26 @@
-"""Event-loop front door (ISSUE 19) — the selectors rebuild of the
-serving edge.
+"""The front door of a webhook replica fleet (docs/fleet.md): a
+selectors reactor that proxies admission reviews to N replicas over the
+batched wire protocol.
 
-:class:`EventFrontDoor` keeps the entire FrontDoor control plane —
-`_choose`'s locked inflight reservation, ejection/readmission streaks,
-the /readyz prober, the retry token bucket, `_refuse`'s shed/expired
-taxonomy, `stats()` — and replaces only the data plane: one reactor
-thread (fleet/evloop.py) running non-blocking accept/read/write state
-machines over persistent pipelined client connections, with the
-replica hop spoken over the batched wire protocol (fleet/wireproto.py)
-instead of HTTP.
+Production fleets sit behind a Kubernetes Service/LB; this door exists
+so the repo can drive and prove the fleet topology end to end (the
+benchmark's webhook cells, chip_smoke.py, bench.py fleet/chaos_fleet,
+tools/check_*.py) with nothing but the standard library.
+
+:class:`EventFrontDoor` is the data plane: one reactor thread
+(fleet/evloop.py) running non-blocking accept/read/write state machines
+over persistent pipelined client connections, with the replica hop
+spoken over GKW1 (fleet/wireproto.py).  The control plane — which
+backend takes a request, the locked inflight reservation, ejection and
+readmission, the /readyz prober — is the :class:`~.roster.Roster` the
+door holds (fleet/roster.py).
 
 Data-plane shape:
 
 * **Byte-splice proxying.**  The door never parses an AdmissionReview:
   it routes on headers, and the body bytes travel to the replica
   verbatim inside a request record.  The uid regex runs only on the
-  refusal paths, exactly as on the old edge.
+  refusal paths.
 * **Tick-chunking.**  Requests parsed out of one client read accumulate
   per backend and flush as ONE chunk frame at the end of the read (and
   at every loop tick) — a client that pipelines N requests hands the
@@ -23,20 +28,56 @@ Data-plane shape:
 * **Ordered pipelining.**  HTTP/1.1 pipelined responses must return in
   request order; each connection keeps its requests in a slot queue and
   writes a completed response only when every earlier slot has written.
-* **Same contracts, same names.**  The six WIRE_STAGES mark on a
-  per-request stage clock (explicit-parent spans — the loop thread
-  serves many requests interleaved, so CURRENT is meaningless);
-  X-GK-Deadline-Ms rides the wire as the record's remaining-budget
-  field; shed/expired refusals, Retry-After, the retry budget, 502
-  naming the last backend, X-GK-Trace-Id / X-GK-Replica — all
-  byte-compatible with frontdoor.py (the parameterized slowloris and
-  contract tests hold both doors to it).
+
+Wire-path observability (docs/tracing.md):
+
+- **Trace origination.**  A head-sampled POST (or any POST carrying
+  ``traceparent``) runs under a ``wire`` root span with disjoint stage
+  spans covering the full wire path — the stable set is
+  :data:`~.wireproto.WIRE_STAGES` — on a per-request stage clock
+  (explicit-parent spans: the loop thread serves many requests
+  interleaved, so CURRENT is meaningless).  The door's own
+  ``traceparent`` rides the request record, so the replica's
+  ``admission`` root adopts the SAME trace_id and /debug/fleet-traces
+  joins both halves.
+- **Stage metrics.**  Every sampled stage double-records into
+  ``frontdoor_stage_seconds{stage}``; requests count into
+  ``frontdoor_requests_total{outcome,backend}``.
+- **Correlation headers on EVERY response** — ``X-GK-Trace-Id`` always;
+  ``X-GK-Replica`` whenever a backend was involved, explicitly
+  including the 502 path (a 502's trace id is how the operator finds
+  which replicas the door tried).
+- ``/metrics`` serves the parent registry (wire metrics), or — with a
+  :class:`~gatekeeper_tpu.obs.fleetobs.MetricsFederator` attached — the
+  federated fleet view; ``/debug/*`` routes through the shared
+  DebugRouter; ``/fleetz`` serves :meth:`EventFrontDoor.stats`.
+
+Overload and resilience (docs/failure-modes.md):
+
+- **deadline propagation** — each request's budget is ``min(the door's
+  admission budget, the caller's X-GK-Deadline-Ms)``; the REMAINING
+  milliseconds ride the wire record's deadline field (the replica
+  re-enters `deadline.push` with what is left, never a fresh budget);
+  expired work answers the explicit fail-open/closed decision at
+  arrival, at its deadline timer, or before a retry.
+- **fast shed** — a request arriving while every live backend sits at
+  its inflight bound answers **429 + Retry-After** carrying the
+  explicit verdict (the bound itself is the roster's).
+- **bounded single retry** — a request whose backend fails at the
+  connection level is retried exactly once, onto a *different* live
+  backend, if the retry budget grants a token; otherwise an explicit
+  502 (the apiserver's failurePolicy decides — never a fabricated
+  verdict, never an unbounded retry storm).
+- **slow-client hardening** — a sweep timer closes connections stalled
+  mid-headers past ``header_timeout_s`` and answers 408 mid-body;
+  bodies above ``MAX_BODY`` answer 413 before the read.
 """
 
 from __future__ import annotations
 
 import errno
 import itertools
+import json
 import logging
 import selectors
 import socket
@@ -44,7 +85,7 @@ import threading
 import time
 from collections import deque
 from http.client import responses as _HTTP_REASONS
-from typing import Dict, Optional, Set
+from typing import Dict, Optional, Sequence, Set, Tuple
 
 from .. import deadline as _deadline
 from .. import faults
@@ -60,9 +101,8 @@ from ..metrics.catalog import (
 from ..obs import trace as obstrace
 from .evloop import Conn, EventLoop, HttpError, HttpRequestParser, \
     http_response
-from .frontdoor import (
-    _UID_RE,
-    FrontDoor,
+from .roster import LEAST_INFLIGHT, Backend, RetryBudget, Roster
+from .wireproto import (
     OUTCOME_BACKEND_ERROR,
     OUTCOME_BAD_REQUEST,
     OUTCOME_EXPIRED,
@@ -75,7 +115,8 @@ from .frontdoor import (
     STAGE_REPLICA_WAIT,
     STAGE_ROUTE_CHOOSE,
     STAGE_WRITE_BACK,
-    _admission_review_body,
+    admission_review_body,
+    uid_of,
 )
 from . import wireproto
 
@@ -95,11 +136,11 @@ _RESP_200_TAIL = b"\r\nConnection: keep-alive\r\n\r\n"
 
 
 class _EdgeStageClock:
-    """Explicit-parent twin of frontdoor._StageClock: the loop thread
-    interleaves many requests, so stage spans attach to each request's
-    own wire root instead of the thread's CURRENT.  Same contiguity
-    contract — mark() closes the open interval and opens the next, so
-    stage durations sum to the wire duration with no dark time.
+    """Contiguous wire-stage stopwatch with explicit parents: the loop
+    thread interleaves many requests, so stage spans attach to each
+    request's own wire root instead of the thread's CURRENT.  mark()
+    closes the open interval and opens the next, so stage durations sum
+    to the wire duration with no dark time.
 
     Marks accumulate as plain tuples on the reactor thread and
     materialize ONCE at response time (:meth:`flush`): a single
@@ -167,7 +208,7 @@ class _EdgeRequest:
         self.body = body
         self.deadline: Optional[float] = None
         self.req_id = 0
-        self.tried: Set[int] = set()
+        self.tried: Set[Backend] = set()
         self.attempt = 0
         self.backend = None
         self.t_attempt = 0.0
@@ -192,6 +233,11 @@ class _ClientConn(Conn):
     def on_bytes(self, data: bytes) -> None:
         if self.errored:
             return   # refusal queued; the connection is closing
+        if faults.ENABLED:
+            # the slow-client seam: a latency rule holds this read on
+            # the reactor before the parser sees it — the trickling
+            # client whose stall the sweep bounds in production
+            faults.fire(faults.SLOW_CLIENT)
         now = time.perf_counter()
         try:
             reqs = self.parser.feed(data, now)
@@ -246,8 +292,8 @@ class _WireClient(Conn):
         # keeping up); closed by on_writable when the backlog drains
         self._stall_t0: Optional[float] = None
         # gklint: disable=unbounded-queue -- drained every loop tick;
-        # admission to it is bounded upstream by the door's per-backend
-        # inflight reservation (_choose), the same cap the old edge had
+        # admission to it is bounded upstream by the roster's
+        # per-backend inflight reservation (Roster.choose)
         self.queued: list = []   # _EdgeRequests awaiting the tick flush
         sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         sock.setblocking(False)
@@ -341,13 +387,24 @@ class _WireClient(Conn):
         self.door._wire_client_lost(self, exc)
 
 
-class EventFrontDoor(FrontDoor):
-    """FrontDoor with the thread-per-request HTTP data plane swapped
-    for the reactor + batched-wire-protocol edge.  Backends are wire
-    listener ports (fleet/wirelistener.py); pass ``probe_port`` per
-    backend so the /readyz readmission prober can keep speaking HTTP to
+class EventFrontDoor:
+    """The reactor + batched-wire-protocol serving edge.  Backends are
+    wire listener ports (fleet/wirelistener.py); pass ``probe_port`` per
+    backend so the roster's /readyz readmission prober can speak HTTP to
     the replica's webhook listener."""
 
+    # bounded retry: one extra attempt on a DIFFERENT backend per request
+    RETRY_LIMIT = 1
+    # a client stalled mid-request this long is closed (mid-headers) or
+    # answered 408 (mid-body): slowloris gets a bounded hold
+    HEADER_TIMEOUT_S = 15.0
+    # inbound body bound; admission payloads are small — larger is abuse
+    MAX_BODY = 32 * 1024 * 1024
+    # Retry-After advertised on shed responses (seconds)
+    RETRY_AFTER_S = 1
+    # retry-budget bucket defaults (RetryBudget)
+    RETRY_BUDGET_CAP = 10.0
+    RETRY_BUDGET_RATE_PER_S = 1.0
     # clients stalled mid-request are swept on this cadence (bounded by
     # header_timeout_s, so a tight test timeout still sweeps in time)
     SWEEP_INTERVAL_S = 0.05
@@ -358,8 +415,43 @@ class EventFrontDoor(FrontDoor):
     # chunk-batch-size histogram samples kept per flush window
     WIRE_SAMPLE_CAP = 256
 
-    def __init__(self, *args, **kwargs):
-        super().__init__(*args, **kwargs)
+    def __init__(self, backends: Sequence[Tuple[str, int]] | Sequence[dict],
+                 port: int = 0, policy: str = LEAST_INFLIGHT,
+                 probe_interval_s: Optional[float] = None,
+                 admission_budget_s: Optional[float] = None,
+                 max_inflight: int = 0,
+                 fail_open: bool = False,
+                 retry_budget_cap: Optional[float] = None,
+                 retry_budget_rate_per_s: Optional[float] = None,
+                 header_timeout_s: Optional[float] = None):
+        self.roster = Roster(backends, policy=policy,
+                             max_inflight=max_inflight,
+                             probe_interval_s=probe_interval_s)
+        self.port = port
+        # per-request deadline the door itself grants (min()-merged with
+        # the caller's X-GK-Deadline-Ms); None = only the caller's bound
+        self.admission_budget_s = admission_budget_s
+        # the policy selecting the verdict on the door's OWN refusals
+        # (shed / expired) — mirrors the webhook's --admission-fail-open
+        self.fail_open = bool(fail_open)
+        self.retry_budget = RetryBudget(
+            cap=(retry_budget_cap if retry_budget_cap is not None
+                 else self.RETRY_BUDGET_CAP),
+            rate_per_s=(retry_budget_rate_per_s
+                        if retry_budget_rate_per_s is not None
+                        else self.RETRY_BUDGET_RATE_PER_S),
+        )
+        self.header_timeout_s = (
+            header_timeout_s if header_timeout_s is not None
+            else self.HEADER_TIMEOUT_S
+        )
+        # written on the loop thread only; stats() reads them
+        self.sheds = 0    # door-level overload refusals (shed + expired)
+        self.retries = 0  # requests salvaged by the retry
+        # fleet observability plane (obs/fleetobs.py): attached by the
+        # harness/supervisor that knows the replica roster
+        self.federator = None
+        self.collector = None
         # GKW1 wire telemetry (loop thread only): plain dict increments
         # on the hot path, flushed through record_wire_flush on the
         # WIRE_FLUSH_S gate inside _flush_dirty
@@ -379,12 +471,25 @@ class EventFrontDoor(FrontDoor):
         # (outcome, backend) -> n, flushed with the dirty set: the hot
         # path pays a dict increment instead of a registry lock
         self._outcomes: Dict = {}
-        # the roster list is append-only during __init__, so identity ->
-        # index is stable; saves the locked list scan per dispatch
-        self._bidx: Dict[int, int] = {
-            id(b): i for i, b in enumerate(self.backends)
-        }
         self._req_ids = itertools.count(1)
+
+    def attach_observability(self, federator=None, collector=None):
+        """Wire the fleet observability plane (ISSUE 11): a
+        MetricsFederator makes ``/metrics`` serve the merged fleet view;
+        a TraceCollector installs ``/debug/fleet-traces`` on the shared
+        router (served by this door's listener)."""
+        if federator is not None:
+            self.federator = federator
+        if collector is not None:
+            self.collector = collector.install()
+        return self
+
+    def suspend(self, replica_id: str) -> bool:
+        return self.roster.suspend(replica_id)
+
+    def set_backend(self, replica_id: str, host: str, port: int,
+                    probe_port: int = 0) -> bool:
+        return self.roster.set_backend(replica_id, host, port, probe_port)
 
     def _next_req_id(self) -> int:
         """Request ids are u32 on the wire (wireproto masks them), so
@@ -423,18 +528,11 @@ class EventFrontDoor(FrontDoor):
             reactorobs.register_door(self)
         except Exception:
             log.exception("reactor telemetry attach failed")
-        self._prober_stop.clear()
-        self._prober = threading.Thread(
-            target=self._probe_loop, name="evdoor-probe", daemon=True
-        )
-        self._prober.start()
+        self.roster.start()
         return self
 
     def stop(self):
-        self._prober_stop.set()
-        if self._prober is not None:
-            self._prober.join(timeout=5.0)
-            self._prober = None
+        self.roster.stop()
         if self._loop is not None:
             try:
                 from ..obs import reactorobs
@@ -543,26 +641,24 @@ class EventFrontDoor(FrontDoor):
         self._dirty.discard(conn)
         for req in conn.slots:
             # a slot with an open pending_stage holds a backend
-            # reservation (_choose) — release it NOW, exactly like
-            # _expire, or the disconnect pins backend.inflight forever
-            # and a bounded door sheds every later request.  No error
-            # charge: the replica did nothing wrong, the client left.
+            # reservation — release it NOW, or the disconnect pins
+            # backend.inflight forever and a bounded door sheds every
+            # later request.  No error charge: the replica did nothing
+            # wrong, the client left.
             if not req.done and req.pending_stage is not None \
                     and req.backend is not None:
-                backend = req.backend
-                wc = self._wire.get(backend.replica_id)
+                wc = self._wire.get(req.backend.replica_id)
                 if wc is not None:
                     wc.pending.pop(req.req_id, None)
                 req.pending_stage = None
-                with backend.lock:
-                    backend.inflight -= 1
+                self.roster.release(req.backend)
             req.done = True     # orphaned: late completions are no-ops
 
     def _client_http_error(self, conn: _ClientConn,
                            e: HttpError) -> None:
-        """Parser-level refusals keep the old door's wire shape: 400
-        for a bad Content-Length, 413 before the body is read — each
-        under its own (tiny) wire root so bad requests still trace."""
+        """Parser-level refusals: 400 for a bad Content-Length, 413
+        before the body is read — each under its own (tiny) wire root
+        so bad requests still trace."""
         body = {400: b"bad Content-Length",
                 413: b"body too large"}.get(e.code,
                                             e.message.encode())
@@ -631,34 +727,36 @@ class EventFrontDoor(FrontDoor):
                 req.deadline = time.monotonic() + budget
                 self._loop.call_later(budget,
                                       lambda r=req: self._expire(r))
-        if not self._has_capacity():
+        if not self.roster.has_capacity():
+            # every live backend at its inflight bound: fast 429 +
+            # Retry-After instead of queueing the request into a socket
             self._refuse(req, expired=False)
             return
         self._dispatch(req)
 
     def _dispatch(self, req: _EdgeRequest) -> None:
-        """One proxy attempt: reserve a backend (the base class's locked
-        reservation — identical shed semantics), queue the request
-        record on its wire client, arm nothing else; completion,
-        expiry, or connection loss drive what happens next."""
+        """One proxy attempt: reserve a backend (the roster's locked
+        reservation), queue the request record on its wire client, arm
+        nothing else; completion, expiry, or connection loss drive what
+        happens next."""
         try:
-            backend = self._choose(exclude=req.tried)
+            backend = self.roster.choose(exclude=req.tried)
         except _deadline.OverloadShed:
+            # slots filled between the arrival check and routing: the
+            # same fast 429, just decided one stage later
             self._refuse(req, expired=False)
             return
         if backend is None:
             self._no_backend(
                 req, f"no fleet backend answered: {req.last_exc!r}")
             return
-        idx = self._bidx.get(id(backend))
-        if idx is None:
-            with backend.lock:
-                backend.inflight -= 1
-            self._dispatch(req)   # raced a roster mutation; re-choose
-            return
         if req.attempt > 0 and not self.retry_budget.take():
-            with backend.lock:
-                backend.inflight -= 1
+            # the bounded retry exists, but a brownout must not be
+            # amplified by it: no token, no retry — the explicit 502
+            # answers.  Taken only AFTER a backend is secured, so a
+            # dead-end choose never burns a token; the reservation is
+            # given back since this backend will not be tried
+            self.roster.release(backend)
             gklog.log_event(
                 log, "front-door retry denied: retry budget empty",
                 level=logging.WARNING,
@@ -667,14 +765,17 @@ class EventFrontDoor(FrontDoor):
             self._no_backend(req, "no fleet backend answered: "
                                   "retry budget empty")
             return
-        req.tried.add(idx)
+        req.tried.add(backend)
         req.backend = backend
-        self._local.last_backend = backend.replica_id
         req.t_attempt = req.clock.mark(STAGE_ROUTE_CHOOSE,
                                        attempt=req.attempt)
         req.pending_stage = STAGE_PROXY_CONNECT
         try:
             if faults.ENABLED:
+                # the overload-storm seam: a latency rule here models a
+                # slow replica hop with the inflight slot HELD; an error
+                # rule is a failing backend and follows the ordinary
+                # error/eject path below
                 faults.fire(faults.OVERLOAD_STORM)
             rid = backend.replica_id
             wc = self._wire.get(rid)
@@ -695,14 +796,11 @@ class EventFrontDoor(FrontDoor):
 
     # ---- completion / failure paths --------------------------------------
 
-    def _complete(self, wc: _WireClient, rec) -> None:
-        self._complete_chunk(wc, (rec,))
-
     def _complete_chunk(self, wc: _WireClient, records) -> None:
         """A whole response chunk from one backend: per-record
-        completion, with the shared-state bookkeeping (inflight,
-        served, latency notes) batched under ONE backend-lock hold for
-        the chunk instead of one per record."""
+        completion, with the roster's bookkeeping (inflight, served,
+        latency notes) batched under ONE backend-lock hold for the
+        chunk instead of one per record."""
         backend = wc.backend
         rid = backend.replica_id
         pending = wc.pending
@@ -716,16 +814,11 @@ class EventFrontDoor(FrontDoor):
             done.append((req, rec, now))
         if not done:
             return
-        mono = time.monotonic()
-        with backend.lock:
-            backend.inflight -= len(done)
-            backend.served += len(done)
-            backend.consecutive_errors = 0
-            for req, _rec, now in done:
-                backend.lat.append(
-                    (mono, (now - req.t_attempt) * 1e3))
-        if backend.ejected and any(r.status != 503 for _q, r, _n in done):
-            self._readmit(backend, "served while ejected")
+        self.roster.served(
+            backend,
+            [(now - req.t_attempt) * 1e3 for req, _rec, now in done],
+            live=any(rec.status != 503 for _req, rec, _now in done),
+        )
         for req, rec, _now in done:
             if req.attempt > 0:
                 self.retries += 1
@@ -739,10 +832,10 @@ class EventFrontDoor(FrontDoor):
                           replica=rid)
 
     def _attempt_failed(self, req: _EdgeRequest, exc: Exception) -> None:
-        """Mirror of forward()'s per-attempt except block: close the
-        in-flight stage, charge the backend's error streak (refused
-        ejects immediately), then retry on a DIFFERENT backend or
-        answer the explicit 502."""
+        """Close the in-flight stage (the failed attempt's time was
+        real and must not become dark time), charge the backend's error
+        streak (refused ejects immediately), then retry on a DIFFERENT
+        backend or answer the explicit 502."""
         req.last_exc = exc
         backend = req.backend
         if req.pending_stage and backend is not None:
@@ -751,15 +844,7 @@ class EventFrontDoor(FrontDoor):
                            error=type(exc).__name__)
             req.pending_stage = None
         if backend is not None:
-            with backend.lock:
-                backend.inflight -= 1
-                backend.errors += 1
-                backend.consecutive_errors += 1
-                streak = backend.consecutive_errors
-            if isinstance(exc, ConnectionRefusedError):
-                self._eject(backend, "connection refused")
-            elif streak >= self.EJECT_ERROR_STREAK:
-                self._eject(backend, f"{streak} consecutive errors")
+            self.roster.failed(backend, exc)
             gklog.log_event(
                 log,
                 f"backend {backend.replica_id} failed "
@@ -794,9 +879,8 @@ class EventFrontDoor(FrontDoor):
 
     def _expire(self, req: _EdgeRequest) -> None:
         """Deadline timer: abandon the in-flight attempt (a late record
-        is dropped in _complete), charge the backend exactly like a
-        deadline-clamped timeout on the old edge, and answer the
-        explicit expired decision."""
+        is dropped in _complete_chunk), charge the backend's error
+        streak, and answer the explicit expired decision."""
         if req.done:
             return
         backend = req.backend
@@ -809,14 +893,8 @@ class EventFrontDoor(FrontDoor):
                                backend=backend.replica_id,
                                error="TimeoutError")
                 req.pending_stage = None
-            with backend.lock:
-                backend.inflight -= 1
-                backend.errors += 1
-                backend.consecutive_errors += 1
-                streak = backend.consecutive_errors
-            if streak >= self.EJECT_ERROR_STREAK:
-                self._eject(backend, f"{streak} consecutive errors "
-                                     "(deadline-clamped timeouts)")
+            self.roster.failed(backend, None,
+                               " (deadline-clamped timeouts)")
         self._refuse(req, expired=True)
 
     # ---- responses -------------------------------------------------------
@@ -863,9 +941,11 @@ class EventFrontDoor(FrontDoor):
         self._dirty.add(req.conn)
 
     def _refuse(self, req: _EdgeRequest, expired: bool) -> None:
-        """Byte-for-byte the old door's _refuse: expired answers the
-        explicit fail-open/closed verdict (HTTP 200, code 504 inside);
-        shed answers 429 + Retry-After with the same verdict shape."""
+        """The door's own fast refusal: an expired deadline answers the
+        explicit fail-open/closed decision the webhook would have
+        produced (HTTP 200, code 504 in the verdict); an overload shed
+        answers 429 + Retry-After with the same explicit verdict in the
+        body.  No routing, no proxying, one regex for the uid."""
         from ..webhook.policy import (
             DEADLINE_CODE,
             DEADLINE_MESSAGE,
@@ -877,8 +957,6 @@ class EventFrontDoor(FrontDoor):
 
         if req.done:
             return
-        m = _UID_RE.search(req.body or b"")
-        uid = m.group(1).decode("utf-8", "replace") if m else ""
         if expired:
             outcome, reason = OUTCOME_EXPIRED, "deadline_expired"
             msg, code, annot = (
@@ -891,14 +969,13 @@ class EventFrontDoor(FrontDoor):
                 SHED_MESSAGE, SHED_CODE, FAIL_OPEN_SHED
             )
             http_code, retry_after = 429, True
-        with self._mu:
-            self.sheds += 1
+        self.sheds += 1
         if req.root is not None:
             req.root.set_attrs(outcome=outcome, shed_reason=reason)
         self._count_outcome(outcome)
         record_shed(reason)
-        payload = _admission_review_body(
-            uid, self.fail_open, msg, code, annot
+        payload = admission_review_body(
+            uid_of(req.body), self.fail_open, msg, code, annot
         )
         self._respond(req, http_code, "application/json", payload,
                       retry_after=retry_after)
@@ -920,7 +997,23 @@ class EventFrontDoor(FrontDoor):
     # ---- introspection ----------------------------------------------------
 
     def stats(self) -> dict:
-        s = super().stats()
+        s = {
+            "policy": self.roster.policy,
+            "retries": self.retries,
+            "sheds": self.sheds,
+            "max_inflight": self.roster.max_inflight,
+            "admission_budget_ms": (
+                round(self.admission_budget_s * 1e3, 3)
+                if self.admission_budget_s is not None else None
+            ),
+            "retry_budget": {
+                "tokens": round(self.retry_budget.tokens(), 3),
+                "cap": self.retry_budget.cap,
+                "rate_per_s": self.retry_budget.rate_per_s,
+                "denied": self.retry_budget.denied,
+            },
+            "backends": self.roster.stats(),
+        }
         try:
             from ..obs import reactorobs
 
@@ -980,20 +1073,14 @@ class EventFrontDoor(FrontDoor):
                 lambda: self._respond(req, code, ctype, body))
 
     def _get_response(self, target: str):
-        import json as _json
-
         path, _, query = target.partition("?")
         if path == "/healthz":
-            live = sum(
-                1 for b in self.backends
-                if not b.ejected
-                and b.consecutive_errors < self.LIVE_ERROR_STREAK
-            )
+            live = self.roster.live_count()
             return ((200 if live else 503), "text/plain",
                     b"ok" if live else b"no backends")
         if path == "/fleetz":
             return (200, "application/json",
-                    _json.dumps(self.stats()).encode())
+                    json.dumps(self.stats()).encode())
         if path == "/metrics":
             from ..metrics.exporter import (
                 CONTENT_TYPE_TEXT,

@@ -22,8 +22,9 @@ individual replica failures:
   spawn capacity forever.  ``revive()`` re-arms it;
 - **front-door integration** — ``on_backend_change(replica_id, backend
   | None)`` fires on every liveness transition; wiring it to
-  ``FrontDoor.suspend`` / ``FrontDoor.set_backend`` keeps traffic off
-  dead replicas and re-points the door at the restarted port;
+  ``EventFrontDoor.suspend`` / ``EventFrontDoor.set_backend`` keeps
+  traffic off dead replicas and re-points the door at the restarted
+  port;
 - **graceful drain + rolling restart** — ``drain()`` runs the child's
   drain protocol (stop accepting, flush the micro-batcher within a
   deadline budget); ``rolling_restart()`` sequences eject -> drain ->
@@ -161,7 +162,9 @@ class ReplicaSupervisor:
 
     on_backend_change(replica_id, backend_dict_or_None) is invoked
     OUTSIDE supervisor locks: None = stop routing to this replica,
-    a dict = (re)start routing to {"host", "port", "replica_id"}.
+    a dict = (re)start routing to the replica's wire backend
+    ({"host", "port", "probe_port", "replica_id"}: ``port`` speaks GKW1,
+    ``probe_port`` answers /readyz).
     """
 
     def __init__(
@@ -255,7 +258,7 @@ class ReplicaSupervisor:
             timeout_s=self.spawn_timeout_s,
         )
         self.adopt(handle)
-        self._notify(replica_id, handle.backend())
+        self._notify(replica_id, handle.wire_backend())
         return handle
 
     def _notify(self, replica_id: str, backend: Optional[dict]):
@@ -403,7 +406,7 @@ class ReplicaSupervisor:
         )
         slot.restart_reason = ""
         self.adopt(handle)
-        self._notify(slot.replica_id, handle.backend())
+        self._notify(slot.replica_id, handle.wire_backend())
         log.info("replica %s restarted warm in %.2fs (ready_s=%.2fs, "
                  "restore=%s)", slot.replica_id, slot.last_restart_s,
                  handle.ready_s, handle.ready.get("restore_outcome"))

@@ -40,7 +40,6 @@ from ..metrics.catalog import record_shed, record_wire_flush
 from ..obs import trace as obstrace
 from ..util import join_thread
 from .evloop import Conn, EventLoop
-from .frontdoor import _UID_RE
 from . import wireproto
 
 log = gklog.get("fleet.wirelistener")
@@ -319,8 +318,6 @@ class WireListener:
             AdmissionResponse,
         )
 
-        m = _UID_RE.search(body or b"")
-        uid = m.group(1).decode("utf-8", "replace") if m else ""
         resp = AdmissionResponse(
             self.fail_open, SHED_MESSAGE, 200 if self.fail_open
             else SHED_CODE,
@@ -329,7 +326,7 @@ class WireListener:
                 if self.fail_open else None
             ),
         )
-        return _envelope(resp.to_dict(uid=uid))
+        return _envelope(resp.to_dict(uid=wireproto.uid_of(body)))
 
     # ---- worker side -----------------------------------------------------
 
@@ -385,12 +382,11 @@ class WireListener:
         try:
             out = []
             for r in records:
-                m = _UID_RE.search(r.body or b"")
-                uid = m.group(1).decode("utf-8", "replace") if m else ""
                 resp = AdmissionResponse(
                     False, "wire chunk processing failed", 500)
                 out.append(wireproto.ResponseRecord(
-                    r.req_id, 200, _envelope(resp.to_dict(uid=uid))))
+                    r.req_id, 200,
+                    _envelope(resp.to_dict(uid=wireproto.uid_of(r.body)))))
             return wireproto.encode_response_chunk(out)
         except Exception:
             log.exception("wire failure-chunk fallback failed")

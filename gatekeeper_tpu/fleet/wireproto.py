@@ -32,11 +32,18 @@ This module is PURE framing: no sockets, no threads — `encode_*` are
 functions and :class:`FrameDecoder` is an incremental push parser, so
 partial reads, pipelined frames sharing one buffer, and N-way split
 recv() sequences are unit-testable without a listener.
+
+It also holds the names both ends of the hop agree on (the door↔replica
+contract): the stable wire-stage and request-outcome sets, the uid
+regex of the refusal paths, and the AdmissionReview body of a refusal —
+so neither side imports the other's module for them.
 """
 
 from __future__ import annotations
 
+import json
 import math
+import re
 import struct
 from typing import List, NamedTuple, Optional, Tuple
 
@@ -53,6 +60,65 @@ _RESP = struct.Struct("!IHI")            # req_id, status, blen
 #: or abuse, mirroring the edge's 32MB body bound with chunk headroom
 MAX_PAYLOAD = 64 * 1024 * 1024
 MAX_RECORDS = 4096
+
+
+# ---- the stable wire-path stage set (docs/tracing.md) -----------------------
+# Disjoint by construction: their durations sum to the wire latency the
+# client observes at the door (minus socket-level residue).  The tuple is
+# the contract tools/check_observability.py checks against the docs
+# table and bench.py's wire-path section reports per-stage p50/p99 over.
+STAGE_ACCEPT = "accept"
+STAGE_READ_BODY = "read_body"
+STAGE_ROUTE_CHOOSE = "route_choose"
+STAGE_PROXY_CONNECT = "proxy_connect"
+STAGE_REPLICA_WAIT = "replica_wait"
+STAGE_WRITE_BACK = "write_back"
+WIRE_STAGES = (
+    STAGE_ACCEPT, STAGE_READ_BODY, STAGE_ROUTE_CHOOSE,
+    STAGE_PROXY_CONNECT, STAGE_REPLICA_WAIT, STAGE_WRITE_BACK,
+)
+
+# request outcomes for frontdoor_requests_total (docs/metrics.md)
+OUTCOME_OK = "ok"
+OUTCOME_BACKEND_ERROR = "backend_error"
+OUTCOME_NO_BACKEND = "no_backend"
+OUTCOME_BAD_REQUEST = "bad_request"
+OUTCOME_SHED = "shed"          # refused by the overload plane (429)
+OUTCOME_EXPIRED = "expired"    # deadline exhausted before/at the door
+
+# cheap uid extraction for the shed/expired fast paths: a full JSON parse
+# per shed would tax exactly the path whose contract is single-digit-ms
+# refusals, and the uid is the only field those responses need
+UID_RE = re.compile(rb'"uid"\s*:\s*"([^"\\]*)"')
+
+
+def uid_of(body: Optional[bytes]) -> str:
+    """The request uid of an AdmissionReview body, by regex (no JSON
+    parse); "" when the body carries none."""
+    m = UID_RE.search(body or b"")
+    return m.group(1).decode("utf-8", "replace") if m else ""
+
+
+def admission_review_body(uid: str, allowed: bool, message: str,
+                          code: int, reason: str) -> bytes:
+    """A well-formed AdmissionReview for the door's OWN refusals (shed /
+    expired): the explicit fail-open/closed decision the webhook itself
+    would have produced, built through the SAME AdmissionResponse
+    machinery (webhook/policy.py) so door-produced and replica-produced
+    verdicts cannot drift in shape.  This is NOT a fabricated
+    enforcement verdict — it is the policy-selected degraded decision
+    the overload contract mandates (docs/failure-modes.md)."""
+    from ..webhook.policy import FAIL_OPEN_ANNOTATION, AdmissionResponse
+
+    resp = AdmissionResponse(
+        allowed, message, code,
+        annotations={FAIL_OPEN_ANNOTATION: reason} if allowed else None,
+    )
+    return json.dumps({
+        "apiVersion": "admission.k8s.io/v1beta1",
+        "kind": "AdmissionReview",
+        "response": resp.to_dict(uid=uid),
+    }).encode()
 
 
 class ProtocolError(ValueError):

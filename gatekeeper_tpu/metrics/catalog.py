@@ -276,7 +276,7 @@ MESH_WIDTH_M = Measure(
 # Wire-path stage telemetry recorded by the front door (the serving edge
 # the FLEET_r06 176 reviews/s number traverses), scrape-health gauges
 # recorded by the metrics federator, and the sampling profiler's own
-# accounting.  Stage names are the frontdoor.WIRE_STAGES stable set
+# accounting.  Stage names are the wireproto.WIRE_STAGES stable set
 # (docs/tracing.md); tools/check_observability.py cross-checks them.
 FRONTDOOR_STAGE_M = Measure(
     "frontdoor_stage_seconds",
@@ -1136,28 +1136,14 @@ def record_mesh_width(width: int):
         record_dropped("record_mesh_width")
 
 
-def record_frontdoor_stage(stage: str, seconds: float):
-    """One wire-path stage interval at the fleet front door (stage in
-    frontdoor.WIRE_STAGES), exemplar-linked to the active wire trace.
-    Guarded like record_stage."""
-    try:
-        _global().record(
-            FRONTDOOR_STAGE_M, seconds, {"stage": stage},
-            exemplar_trace_id=_current_trace_id(),
-        )
-    except Exception:  # telemetry never blocks the wire path
-        record_dropped("record_frontdoor_stage")
-
-
 _FRONTDOOR_STAGE_OBS = None
 
 
 def record_frontdoor_stages(samples, exemplar_trace_id=None):
     """A batch of wire-stage intervals in ONE registry lock hold
-    (samples: [(stage, seconds)] with stage in frontdoor.WIRE_STAGES) —
-    the event-loop door flushes a whole reactor tick's stage observes
-    through here instead of one record_frontdoor_stage round-trip per
-    interval.  The prebound observer memoizes per-stage row keys.
+    (samples: [(stage, seconds)] with stage in wireproto.WIRE_STAGES) —
+    the door flushes a sampled request's stage observes through here at
+    response time.  The prebound observer memoizes per-stage row keys.
     Guarded like record_stage."""
     global _FRONTDOOR_STAGE_OBS
     try:
@@ -1171,10 +1157,12 @@ def record_frontdoor_stages(samples, exemplar_trace_id=None):
 
 
 def record_frontdoor_requests(counts):
-    """Tick-batched request outcomes from the event-loop door: counts
-    maps (outcome, backend) -> n, flushed once per reactor tick so the
-    hot path pays a dict increment instead of a registry lock per
-    request.  Guarded like record_stage."""
+    """Tick-batched request outcomes from the front door: counts maps
+    (outcome, backend) -> n with outcome in wireproto's OUTCOME_* set
+    and backend = the serving replica id ('' when none answered),
+    flushed once per reactor tick so the hot path pays a dict increment
+    instead of a registry lock per request.  Guarded like
+    record_stage."""
     try:
         reg = _global()
         for (outcome, backend), n in counts.items():
@@ -1184,19 +1172,6 @@ def record_frontdoor_requests(counts):
             )
     except Exception:  # telemetry never blocks the wire path
         record_dropped("record_frontdoor_requests")
-
-
-def record_frontdoor_request(outcome: str, backend: str):
-    """One request through the front door: outcome in (ok,
-    backend_error, no_backend, bad_request); backend = the serving
-    replica id ('' when none answered).  Guarded like record_stage."""
-    try:
-        _global().record(
-            FRONTDOOR_REQS_M, 1.0,
-            {"outcome": outcome, "backend": backend},
-        )
-    except Exception:  # telemetry never blocks the wire path
-        record_dropped("record_frontdoor_request")
 
 
 def record_scrape(replica_id: str, ok: bool, age_s: float):

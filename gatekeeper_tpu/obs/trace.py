@@ -644,7 +644,7 @@ _CLOCKS = threading.local()  # path -> this thread's StageClock
 # imported in the process, else absent.  Never imported for this: the
 # door and the benchmark's parent stay jax-free.
 _ANNOTATION = None
-_STAGE_NAMES: Dict[tuple, str] = {}   # (prefix, path, stage) -> span name
+_STAGE_NAMES: Dict[tuple, str] = {}   # (path, stage) -> span name
 
 
 def _bind_annotation() -> None:
@@ -656,15 +656,11 @@ def _bind_annotation() -> None:
 class StageClock:
     """Contiguous stage stopwatch of one thread on one path (see the
     section comment above).  ``mark(stage)`` names the interval it
-    OPENS; ``lap(stage)`` names the interval it CLOSES (for code that
-    learns what an interval was only at its end — the front door)."""
+    OPENS."""
 
     __slots__ = ("path", "stage", "t", "totals", "gc_full_s", "_ann",
                  "_outer", "_flushed", "_lapped", "_gc_full_lapped",
                  "flushed_at")
-
-    SPAN_PREFIX = "gk."   # ring + annotation names: gk.<path>.<stage>
-    STAGE_ATTR = False    # True: ring spans carry the `stage` attribute
 
     def __init__(self, path: str, start: Optional[float] = None):
         self.path = path
@@ -685,11 +681,11 @@ class StageClock:
             _install_gc_hook()
 
     def _name(self, stage: str) -> str:
-        key = (self.SPAN_PREFIX, self.path, stage)
+        """Ring + annotation name: gk.<path>.<stage>."""
+        key = (self.path, stage)
         name = _STAGE_NAMES.get(key)
         if name is None:
-            name = _STAGE_NAMES[key] = (
-                f"{self.SPAN_PREFIX}{self.path}.{stage}")
+            name = _STAGE_NAMES[key] = f"gk.{self.path}.{stage}"
         return name
 
     def _account(self, stage: str, seconds: float) -> None:
@@ -699,7 +695,7 @@ class StageClock:
         acc[0] += seconds
         acc[1] += 1
 
-    def _close(self, stage: str, now: float, attrs: dict) -> None:
+    def _close(self, stage: str, now: float) -> None:
         self._account(stage, now - self.t)
         ann = self._ann
         if ann is not None:
@@ -709,18 +705,16 @@ class StageClock:
         if cur is not None:
             # the ring sink: the finished record filed as it is (no
             # Span object on the hot threads)
-            if self.STAGE_ATTR:
-                attrs["stage"] = stage
             _file_record(cur.trace, _finished_record(
                 self._name(stage), cur.trace, _new_span_id(), cur.span_id,
-                self.t, now, attrs))
+                self.t, now, {}))
 
     def mark(self, stage: str) -> float:
         """Close the open stage (if any) and open ``stage`` at *now*."""
         now = time.perf_counter()
         opened = self.stage
         if opened is not None:
-            self._close(opened, now, {})
+            self._close(opened, now)
         self.stage = stage
         self.t = now
         if opened is None:
@@ -739,20 +733,12 @@ class StageClock:
         only, and no part of the thread's own contiguous time."""
         self._account(stage, seconds)
 
-    def lap(self, stage: str, **attrs) -> float:
-        """Close the interval since the last boundary AS ``stage``; the
-        clock keeps running with the next interval yet unnamed."""
-        now = time.perf_counter()
-        self._close(stage, now, attrs)
-        self.t = now
-        return now
-
     def stop(self) -> float:
         """Close the open stage; the clock is stopped until the next
         mark (time until then belongs to no stage)."""
         now = time.perf_counter()
         if self.stage is not None:
-            self._close(self.stage, now, {})
+            self._close(self.stage, now)
             # off the collector's map first: a collection between the
             # two lines must not find a clock with no open stage
             ident = threading.get_ident()

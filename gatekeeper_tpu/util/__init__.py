@@ -139,8 +139,7 @@ def close_listener(server, thread) -> None:
     then close the socket.  Callers null their own references afterwards
     — a double ``start()`` replaces the previous listener instead of
     leaking its thread and socket (the WebhookServer / MetricsExporter
-    contract; used by HealthServer, ProfileServer and the fleet
-    FrontDoor)."""
+    contract; used by HealthServer and ProfileServer)."""
     if server is None:
         return
     if thread is not None and thread.is_alive():

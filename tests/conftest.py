@@ -117,7 +117,7 @@ def _no_listener_leaks():
     the session: the next test binding the same --port gets EADDRINUSE
     minutes away from the actual culprit.  Servers must stop via
     close_listener()/server_close() (WebhookServer.stop, exporter.stop,
-    FrontDoor.stop...)."""
+    EventFrontDoor.stop...)."""
     import time as _t
 
     before = _listening_socket_inodes()

@@ -1260,141 +1260,115 @@ class TestOverloadStorm:
             mb.stop()
 
     def test_overload_storm_point_drives_door_sheds(self, fault_plane):
-        """The fleet.overload_storm seam: a latency rule holds proxied
-        attempts with their inflight slot taken, so the door's
-        accept-time shed engages — 429s answer FAST while the slow
-        requests complete correctly."""
-        from http.server import BaseHTTPRequestHandler
-        from http.server import ThreadingHTTPServer as _TS
+        """The fleet.overload_storm seam: a latency rule holds a proxied
+        attempt with its inflight slot taken, so the door's arrival-time
+        shed engages — the requests that arrived behind it are refused
+        with the explicit verdict while the slow one completes
+        correctly.  The rule stalls the reactor itself, so a shed is
+        fast by the DOOR's clock (its wire trace), not the client's."""
+        from gatekeeper_tpu.fleet import EventFrontDoor
+        from gatekeeper_tpu.obs import trace as obstrace
+        from tests.wirestub import StubWire, post, wait_until
 
-        class H(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *a):
-                pass
-
-            def do_GET(self):
-                self.send_response(200)
-                self.send_header("Content-Length", "2")
-                self.end_headers()
-                self.wfile.write(b"ok")
-
-            def do_POST(self):
-                n = int(self.headers.get("Content-Length", 0))
-                self.rfile.read(n)
-                body = b'{"served": true}'
-                self.send_response(200)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        backend = _TS(("127.0.0.1", 0), H)
-        bport = backend.server_address[1]
-        threading.Thread(target=backend.serve_forever,
-                         daemon=True).start()
-        from gatekeeper_tpu.fleet.frontdoor import FrontDoor
-
+        obstrace.configure(buffer_size=256, sample_rate=1.0)
+        backend = StubWire(name="b")
         fault_plane.add(
             faults.OVERLOAD_STORM,
             FaultRule(mode="latency", latency_s=0.4),
         )
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": bport, "replica_id": "b"}],
-            probe_interval_s=3600.0, max_inflight=1,
+        door = EventFrontDoor(
+            [backend.backend()], probe_interval_s=3600.0, max_inflight=1,
         ).start()
         body = json.dumps({"request": ns_review("storm")}).encode()
         results: list = []
         lock = threading.Lock()
 
-        def post():
-            import http.client as hc
+        def one():
+            st, hd, data = post(door.port, body)
+            with lock:
+                results.append((st, hd.get("X-GK-Trace-Id"), data))
 
-            t0 = time.perf_counter()
-            conn = hc.HTTPConnection("127.0.0.1", door.port, timeout=10)
-            try:
-                conn.request(
-                    "POST", "/v1/admit", body=body,
-                    headers={"Content-Type": "application/json"},
-                )
-                r = conn.getresponse()
-                data = r.read()
-                with lock:
-                    results.append(
-                        (r.status, time.perf_counter() - t0, data))
-            finally:
-                conn.close()
-
-        threads = [threading.Thread(target=post) for _ in range(6)]
+        threads = [threading.Thread(target=one) for _ in range(6)]
         try:
             for t in threads:
                 t.start()
                 time.sleep(0.02)
             for t in threads:
                 t.join(timeout=30)
-            codes = [c for c, _d, _b in results]
-            assert 200 in codes, "the storm starved every request"
-            shed = [(c, d, b) for c, d, b in results if c == 429]
+            assert len(results) == 6
+            served = [d for c, _t, d in results if c == 200]
+            assert served, "the storm starved every request"
+            assert all(json.loads(d)["served_by"] == "b" for d in served)
+            shed = [(t, d) for c, t, d in results if c == 429]
             assert shed, "inflight bound never shed under the storm"
-            for _c, dur, data in shed:
-                assert dur < 0.2, f"shed took {dur:.3f}s"
+
+            def door_ms(tid):
+                return next(
+                    (t["duration_ms"]
+                     for t in obstrace.get_tracer().traces()
+                     if t["trace_id"] == tid), None)
+
+            for tid, data in shed:
                 out = json.loads(data)["response"]
                 assert out["allowed"] is False
                 assert out["status"]["code"] == 429
+                assert wait_until(lambda: door_ms(tid) is not None)
+                assert door_ms(tid) < 200.0, \
+                    f"shed took {door_ms(tid):.1f} ms at the door"
         finally:
             door.stop()
-            backend.shutdown()
-            backend.server_close()
+            backend.stop()
 
-    def test_slow_client_point_fires_in_read_body(self, fault_plane):
-        """The frontdoor.slow_client seam: a latency rule stretches the
-        request's read_body stage (an accept thread held by a trickling
-        client) without corrupting the response."""
-        from http.server import BaseHTTPRequestHandler
-        from http.server import ThreadingHTTPServer as _TS
+    def test_slow_client_point_fires_in_the_inbound_read(self, fault_plane):
+        """The frontdoor.slow_client seam on the door that serves: a
+        latency rule holds the reactor's inbound read (a trickling
+        client's shape) without corrupting the response."""
+        from gatekeeper_tpu.fleet import EventFrontDoor
+        from tests.wirestub import StubWire, post
 
-        class H(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *a):
-                pass
-
-            def do_POST(self):
-                n = int(self.headers.get("Content-Length", 0))
-                self.rfile.read(n)
-                body = b'{"served": true}'
-                self.send_response(200)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        backend = _TS(("127.0.0.1", 0), H)
-        bport = backend.server_address[1]
-        threading.Thread(target=backend.serve_forever,
-                         daemon=True).start()
-        from gatekeeper_tpu.fleet.frontdoor import FrontDoor
-
+        backend = StubWire(name="b")
         fault_plane.add(
             faults.SLOW_CLIENT,
             FaultRule(mode="latency", latency_s=0.25, count=1),
         )
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": bport, "replica_id": "b"}],
-            probe_interval_s=3600.0,
+        door = EventFrontDoor(
+            [backend.backend()], probe_interval_s=3600.0,
         ).start()
         try:
-            import http.client as hc
-
             t0 = time.perf_counter()
-            conn = hc.HTTPConnection("127.0.0.1", door.port, timeout=10)
-            conn.request("POST", "/v1/admit", body=b"{}",
-                         headers={"Content-Type": "application/json"})
-            r = conn.getresponse()
-            data = r.read()
+            st, _hd, data = post(door.port)
             dur = time.perf_counter() - t0
-            conn.close()
-            assert r.status == 200 and b"served" in data
+            assert st == 200 and json.loads(data)["served_by"] == "b"
             assert dur >= 0.25, "the slow-client latency never applied"
+            # the rule is spent: the next request is not held
+            t0 = time.perf_counter()
+            assert post(door.port)[0] == 200
+            assert time.perf_counter() - t0 < 0.25
         finally:
             door.stop()
-            backend.shutdown()
-            backend.server_close()
+            backend.stop()
+
+    def test_slow_client_error_rule_drops_only_that_connection(
+            self, fault_plane):
+        """An error rule at the seam is a client connection the door
+        gives up on: it closes, the door keeps serving."""
+        import http.client
+
+        from gatekeeper_tpu.fleet import EventFrontDoor
+        from tests.wirestub import StubWire, post
+
+        backend = StubWire(name="b")
+        fault_plane.add(
+            faults.SLOW_CLIENT, FaultRule(mode="error", count=1),
+        )
+        door = EventFrontDoor(
+            [backend.backend()], probe_interval_s=3600.0,
+        ).start()
+        try:
+            with pytest.raises((http.client.HTTPException, OSError)):
+                post(door.port)
+            assert backend.records == []  # nothing was proxied
+            assert post(door.port)[0] == 200
+        finally:
+            door.stop()
+            backend.stop()

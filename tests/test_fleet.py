@@ -14,15 +14,16 @@ import json
 import threading
 import time
 import urllib.request
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
 from gatekeeper_tpu import operations as ops_mod
-from gatekeeper_tpu.fleet import FrontDoor
+from gatekeeper_tpu.fleet import EventFrontDoor
+from gatekeeper_tpu.fleet.roster import Roster
 from gatekeeper_tpu.kube.inmem import InMemoryKube
 from gatekeeper_tpu.util import replica_id, set_replica_id
 from gatekeeper_tpu.webhook import MicroBatcher
+from tests.wirestub import StubWire, get, post
 
 
 @pytest.fixture(autouse=True)
@@ -108,69 +109,17 @@ class TestSingleRoleApp:
 # ---- front door -------------------------------------------------------------
 
 
-class _StubBackend:
-    """Tiny HTTP backend that echoes its name (and can be made slow)."""
-
-    def __init__(self, name: str, delay_s: float = 0.0):
-        self.name = name
-        self.delay_s = delay_s
-        self.served = 0
-        outer = self
-
-        class Handler(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *args):
-                pass
-
-            def do_POST(self):
-                n = int(self.headers.get("Content-Length", 0))
-                self.rfile.read(n)
-                if outer.delay_s:
-                    time.sleep(outer.delay_s)
-                outer.served += 1
-                body = json.dumps({"backend": outer.name}).encode()
-                self.send_response(200)
-                self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        self._server = ThreadingHTTPServer(("127.0.0.1", 0), Handler)
-        self.port = self._server.server_address[1]
-        self._thread = threading.Thread(
-            target=self._server.serve_forever, daemon=True
-        )
-        self._thread.start()
-
-    def stop(self):
-        self._server.shutdown()
-        self._server.server_close()
-
-
-def _post_door(door, body=b"{}"):
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{door.port}/v1/admit", data=body,
-        headers={"Content-Type": "application/json"},
-    )
-    with urllib.request.urlopen(req, timeout=10) as resp:
-        # resp.headers is case-insensitive (email.message.Message)
-        return resp.status, resp.headers, resp.read()
-
-
 class TestFrontDoor:
     def test_round_robin_rotates(self):
-        a, b = _StubBackend("a"), _StubBackend("b")
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": a.port, "replica_id": "a"},
-             {"host": "127.0.0.1", "port": b.port, "replica_id": "b"}],
-            policy="round_robin",
+        a, b = StubWire(name="a"), StubWire(name="b")
+        door = EventFrontDoor(
+            [a.backend(), b.backend()], policy="round_robin",
         ).start()
         try:
             replicas = []
             for _ in range(6):
-                _st, hd, data = _post_door(door)
-                assert json.loads(data)["backend"] in ("a", "b")
+                _st, hd, data = post(door.port)
+                assert json.loads(data)["served_by"] in ("a", "b")
                 replicas.append(hd["X-GK-Replica"])
             assert replicas.count("a") == 3
             assert replicas.count("b") == 3
@@ -180,18 +129,17 @@ class TestFrontDoor:
             b.stop()
 
     def test_least_inflight_prefers_idle_backend(self):
-        slow, fast = _StubBackend("slow", delay_s=0.25), _StubBackend("fast")
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": slow.port, "replica_id": "slow"},
-             {"host": "127.0.0.1", "port": fast.port, "replica_id": "fast"}],
-            policy="least_inflight",
+        slow, fast = StubWire(name="slow", delay_s=0.25), \
+            StubWire(name="fast")
+        door = EventFrontDoor(
+            [slow.backend(), fast.backend()], policy="least_inflight",
         ).start()
         try:
             out = []
             lock = threading.Lock()
 
             def one():
-                _st, hd, _d = _post_door(door)
+                _st, hd, _d = post(door.port)
                 with lock:
                     out.append(hd["X-GK-Replica"])
 
@@ -200,7 +148,8 @@ class TestFrontDoor:
                 t.start()
                 time.sleep(0.02)  # arrivals overlap the slow service time
             for t in threads:
-                t.join()
+                t.join(timeout=30)
+            assert len(out) == 10
             # while the slow backend holds a request in flight, new
             # arrivals must land on the idle one
             assert out.count("fast") > out.count("slow")
@@ -210,16 +159,14 @@ class TestFrontDoor:
             fast.stop()
 
     def test_dead_backend_fails_over(self):
-        dead, live = _StubBackend("dead"), _StubBackend("live")
+        dead, live = StubWire(name="dead"), StubWire(name="live")
         dead.stop()  # port is now refused
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": dead.port, "replica_id": "dead"},
-             {"host": "127.0.0.1", "port": live.port, "replica_id": "live"}],
-            policy="round_robin",
+        door = EventFrontDoor(
+            [dead.backend(), live.backend()], policy="round_robin",
         ).start()
         try:
             for _ in range(4):
-                st, hd, data = _post_door(door)
+                st, hd, _data = post(door.port)
                 assert st == 200
                 assert hd["X-GK-Replica"] == "live"
             stats = {
@@ -236,66 +183,37 @@ class TestFrontDoor:
         dead: /healthz must go 503 once every backend's error streak
         passes LIVE_ERROR_STREAK — a sticky served counter would keep
         answering 200 while every POST returns 502."""
-        b = _StubBackend("b0")
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": b.port, "replica_id": "b0"}],
-        ).start()
+        b = StubWire(name="b0")
+        door = EventFrontDoor([b.backend()]).start()
         try:
-            st, _hd, _data = _post_door(door)
-            assert st == 200  # served > 0: the old sticky predicate
+            assert post(door.port)[0] == 200  # served > 0
+            assert get(door.port, "/healthz")[0] == 200
             b.stop()  # backend dies after serving
-            for _ in range(FrontDoor.LIVE_ERROR_STREAK):
-                with pytest.raises(urllib.error.HTTPError):
-                    _post_door(door)
-            with pytest.raises(urllib.error.HTTPError) as ei:
-                urllib.request.urlopen(
-                    f"http://127.0.0.1:{door.port}/healthz", timeout=10
-                )
-            assert ei.value.code == 503
-        finally:
-            door.stop()
-
-    def test_all_backends_down_is_an_explicit_502(self):
-        gone = _StubBackend("gone")
-        gone.stop()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": gone.port, "replica_id": "gone"}],
-        ).start()
-        try:
-            req = urllib.request.Request(
-                f"http://127.0.0.1:{door.port}/v1/admit", data=b"{}",
-            )
-            with pytest.raises(urllib.error.HTTPError) as ei:
-                urllib.request.urlopen(req, timeout=10)
-            # 502, never a fabricated AdmissionReview verdict
-            assert ei.value.code == 502
+            for _ in range(Roster.LIVE_ERROR_STREAK):
+                assert post(door.port)[0] == 502
+            assert get(door.port, "/healthz")[0] == 503
         finally:
             door.stop()
 
     def test_fleetz_and_unknown_path(self):
-        a = _StubBackend("a")
-        door = FrontDoor([("127.0.0.1", a.port)]).start()
+        a = StubWire(name="a")
+        door = EventFrontDoor([("127.0.0.1", a.port)]).start()
         try:
-            with urllib.request.urlopen(
-                f"http://127.0.0.1:{door.port}/fleetz", timeout=10
-            ) as resp:
-                stats = json.loads(resp.read())
+            st, body = get(door.port, "/fleetz")
+            stats = json.loads(body)
+            assert st == 200
             assert stats["policy"] == "least_inflight"
             assert len(stats["backends"]) == 1
-            with pytest.raises(urllib.error.HTTPError) as ei:
-                urllib.request.urlopen(
-                    f"http://127.0.0.1:{door.port}/nope", timeout=10
-                )
-            assert ei.value.code == 404
+            assert get(door.port, "/nope")[0] == 404
         finally:
             door.stop()
             a.stop()
 
     def test_rejects_unknown_policy_and_empty_backends(self):
         with pytest.raises(ValueError):
-            FrontDoor([("127.0.0.1", 1)], policy="weighted")
+            EventFrontDoor([("127.0.0.1", 1)], policy="weighted")
         with pytest.raises(ValueError):
-            FrontDoor([])
+            EventFrontDoor([])
 
 
 # ---- load-adaptive micro-batcher -------------------------------------------

@@ -19,9 +19,6 @@ from .test_snapshot_concurrent import spawn_available
 
 @spawn_available
 def test_repo_fleet_is_conformant():
-    # edge="both" drives the corpus through the threaded door AND the
-    # ISSUE 19 event-loop door against one spawned fleet: byte parity,
-    # attribution, and oracle conformance must hold on both edges
     assert chk.run_checks() == []
 
 

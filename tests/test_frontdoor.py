@@ -1,101 +1,43 @@
 """Front-door resilience (ISSUE 8): the bounded single retry onto a
 different live backend, health-based ejection, probing readmission, and
 the supervisor's backend-swap hook — plus the ISSUE 11 wire-path
-observability contract (trace origination + stage spans, correlation
-headers on EVERY path, /fleetz latency summaries, stage metrics).  All
-against stub HTTP backends — no replica spawn, so this runs everywhere
-tier-1 does."""
+observability contract (correlation headers on EVERY path, /fleetz
+latency summaries, stage and request metrics) and the ISSUE 12 overload
+plane.  All against stub wire backends (tests/wirestub.py) — no replica
+spawn, so this runs everywhere tier-1 does.  The refusal taxonomy and
+the pipelining contract are tests/test_event_edge.py's; the control
+plane without a socket is tests/test_roster.py's."""
 
 import http.client
 import json
 import socket
-import threading
 import time
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
-from gatekeeper_tpu.fleet.frontdoor import (
-    ROUND_ROBIN,
-    WIRE_STAGES,
-    FrontDoor,
-)
+from gatekeeper_tpu.fleet.evdoor import EventFrontDoor
+from gatekeeper_tpu.fleet.roster import ROUND_ROBIN, Roster
+from gatekeeper_tpu.fleet.wireproto import WIRE_STAGES
 from gatekeeper_tpu.metrics.views import global_registry
 from gatekeeper_tpu.obs import trace as obstrace
+from tests.wirestub import ReadyStub, StubWire, free_port, get, post, \
+    wait_until
+
+ADMIT_BODY = json.dumps({"request": {"uid": "uid-overload"}}).encode()
 
 
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+def _dead(replica_id: str) -> dict:
+    return {"host": "127.0.0.1", "port": free_port(),
+            "replica_id": replica_id}
 
 
-class _Stub:
-    """Minimal backend: answers POSTs with its own name and /healthz ok."""
-
-    def __init__(self, name: str, port: int = 0):
-        self.name = name
-        outer = self
-
-        class H(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *a):
-                pass
-
-            def _reply(self, code, body: bytes):
-                self.send_response(code)
-                self.send_header("Content-Type", "application/json")
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-            def do_GET(self):
-                self._reply(200, b"ok")
-
-            def do_POST(self):
-                n = int(self.headers.get("Content-Length", 0))
-                self.rfile.read(n)
-                self._reply(
-                    200, json.dumps({"served_by": outer.name}).encode()
-                )
-
-        self.server = ThreadingHTTPServer(("127.0.0.1", port), H)
-        self.port = self.server.server_address[1]
-        threading.Thread(
-            target=self.server.serve_forever, daemon=True
-        ).start()
-
-    def stop(self):
-        self.server.shutdown()
-        self.server.server_close()
-
-
-def _post(port: int, body: bytes = b"{}"):
-    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=10)
-    try:
-        conn.request("POST", "/v1/admit", body=body,
-                     headers={"Content-Type": "application/json"})
-        r = conn.getresponse()
-        return r.status, dict(r.getheaders()), r.read()
-    finally:
-        conn.close()
-
-
-def wait_until(cond, timeout_s=5.0, step_s=0.02):
-    end = time.monotonic() + timeout_s
-    while time.monotonic() < end:
-        if cond():
-            return True
-        time.sleep(step_s)
-    return cond()
+def _served_by(resp_body: bytes) -> str:
+    return json.loads(resp_body)["served_by"]
 
 
 @pytest.fixture()
 def live_backend():
-    stub = _Stub("live")
+    stub = StubWire(name="live")
     yield stub
     stub.stop()
 
@@ -105,18 +47,15 @@ class TestBoundedRetry:
         """The satellite regression: a refused backend connection must be
         retried (exactly once) on a DIFFERENT live backend — never a 502
         while a live backend exists."""
-        dead_port = _free_port()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": dead_port, "replica_id": "dead"},
-             {"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}],
+        door = EventFrontDoor(
+            [_dead("dead"), live_backend.backend()],
             policy=ROUND_ROBIN, probe_interval_s=3600.0,
         ).start()
         try:
             for _ in range(6):
-                st, hd, body = _post(door.port)
+                st, hd, body = post(door.port)
                 assert st == 200
-                assert json.loads(body)["served_by"] == "live"
+                assert _served_by(body) == "live"
                 assert hd.get("X-GK-Replica") == "live"
             stats = door.stats()
             by_id = {b["replica_id"]: b for b in stats["backends"]}
@@ -129,31 +68,17 @@ class TestBoundedRetry:
         finally:
             door.stop()
 
-    def test_all_backends_down_is_an_explicit_502(self):
-        door = FrontDoor(
-            [("127.0.0.1", _free_port()), ("127.0.0.1", _free_port())],
-            probe_interval_s=3600.0,
-        ).start()
-        try:
-            st, _hd, body = _post(door.port)
-            assert st == 502
-            assert b"no fleet backend answered" in body
-        finally:
-            door.stop()
-
     def test_retry_is_bounded_to_one(self, live_backend):
         """Three dead backends + one live under round robin: a request
         whose first AND second choices are dead must 502 (the retry
         budget is one), until ejection converges the live set."""
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": _free_port(),
-              "replica_id": f"dead{i}"} for i in range(3)]
-            + [{"host": "127.0.0.1", "port": live_backend.port,
-                "replica_id": "live"}],
+        door = EventFrontDoor(
+            [_dead(f"dead{i}") for i in range(3)]
+            + [live_backend.backend()],
             policy=ROUND_ROBIN, probe_interval_s=3600.0,
         ).start()
         try:
-            codes = [_post(door.port)[0] for _ in range(8)]
+            codes = [post(door.port)[0] for _ in range(8)]
             assert 502 in codes or all(c == 200 for c in codes)
             # ejection converges: once the dead trio is ejected, every
             # request lands on the live backend directly
@@ -161,128 +86,67 @@ class TestBoundedRetry:
                 b["ejected"] for b in door.stats()["backends"]
                 if b["replica_id"].startswith("dead")
             ))
-            assert all(_post(door.port)[0] == 200 for _ in range(4))
+            assert all(post(door.port)[0] == 200 for _ in range(4))
         finally:
             door.stop()
 
-
-class _EchoHeaders:
-    """Backend that records the request headers it received."""
-
-    def __init__(self):
-        outer = self
-        self.headers: list = []
-
-        class H(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *a):
-                pass
-
-            def do_POST(self):
-                n = int(self.headers.get("Content-Length", 0))
-                self.rfile.read(n)
-                outer.headers.append(dict(self.headers))
-                body = b'{"ok": true}'
-                self.send_response(200)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        self.server = ThreadingHTTPServer(("127.0.0.1", 0), H)
-        self.port = self.server.server_address[1]
-        threading.Thread(target=self.server.serve_forever,
-                         daemon=True).start()
-
-    def stop(self):
-        self.server.shutdown()
-        self.server.server_close()
+    def test_empty_retry_bucket_denies_the_retry(self, live_backend):
+        """Two dead backends ahead of a live one under round robin with
+        a zero-capacity retry budget: the first request's failure CANNOT
+        be retried — explicit 502 even though a live backend exists."""
+        door = EventFrontDoor(
+            [_dead("dead0"), _dead("dead1"), live_backend.backend()],
+            policy=ROUND_ROBIN, probe_interval_s=3600.0,
+            retry_budget_cap=0.0, retry_budget_rate_per_s=0.0,
+        ).start()
+        try:
+            codes = [post(door.port, ADMIT_BODY)[0] for _ in range(6)]
+            assert 502 in codes
+            assert door.retry_budget.denied >= 1
+            assert door.stats()["retry_budget"]["denied"] >= 1
+            # the dead pair still ejects on refusal, so the door
+            # converges onto the live backend WITHOUT retries
+            assert wait_until(lambda: all(
+                b["ejected"] for b in door.stats()["backends"]
+                if b["replica_id"].startswith("dead")))
+            assert post(door.port, ADMIT_BODY)[0] == 200
+            # a denied retry gave its reservation back
+            assert all(b["inflight"] == 0
+                       for b in door.stats()["backends"])
+        finally:
+            door.stop()
 
 
 class TestWireObservability:
-    """ISSUE 11: the door originates a W3C trace per request with the
-    stable stage set, injects traceparent downstream, stamps
-    correlation headers on every path, and summarizes per-backend
-    latency on /fleetz."""
+    """ISSUE 11: the door originates a W3C trace per request, injects
+    traceparent downstream, stamps correlation headers on every path,
+    and summarizes per-backend latency on /fleetz."""
 
-    def test_trace_originated_with_full_stage_set(self, live_backend):
-        # the global tracer's sampling/buffer config is sticky across
-        # tests: pin full retention so the wire trace cannot be dropped
-        obstrace.configure(buffer_size=256, sample_rate=1.0)
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}], probe_interval_s=3600.0,
-        ).start()
-        try:
-            st, hd, _body = _post(door.port)
-            assert st == 200
-            tid = hd.get("X-GK-Trace-Id")
-            assert tid and len(tid) == 32
-
-            def find():
-                # the root span completes AFTER the response bytes are
-                # flushed (write_back is marked before the ctx exits):
-                # the ring entry lands a hair behind the client's read
-                return next(
-                    (t for t in obstrace.get_tracer().traces()
-                     if t["trace_id"] == tid), None,
-                )
-
-            assert wait_until(lambda: find() is not None), \
-                "wire trace never completed into the ring"
-            tr = find()
-            assert tr["root"] == "wire"
-            bd = obstrace.stage_breakdown(tr)
-            # every wire stage present, nothing undocumented
-            assert set(bd) == set(WIRE_STAGES)
-            # disjoint stages: the breakdown sums within the root
-            assert sum(bd.values()) <= tr["duration_ms"] * 1.05
-        finally:
-            door.stop()
-
-    def test_caller_traceparent_adopted_and_reinjected(self):
-        echo = _EchoHeaders()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": echo.port,
-              "replica_id": "e"}], probe_interval_s=3600.0,
-        ).start()
+    def test_caller_traceparent_adopted_and_reinjected(self, live_backend):
+        door = EventFrontDoor([live_backend.backend()],
+                              probe_interval_s=3600.0).start()
         try:
             caller_tid = "ab" * 16
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=10)
-            conn.request(
-                "POST", "/v1/admit", body=b"{}",
-                headers={
-                    "Content-Type": "application/json",
-                    "traceparent":
-                        f"00-{caller_tid}-{'12' * 8}-01",
-                },
-            )
-            r = conn.getresponse()
-            hd = dict(r.getheaders())
-            r.read()
-            conn.close()
+            _st, hd, _body = post(door.port, headers={
+                "traceparent": f"00-{caller_tid}-{'12' * 8}-01"})
             # the caller's trace id is adopted...
             assert hd["X-GK-Trace-Id"] == caller_tid
             # ...and re-injected downstream with the DOOR's span id,
             # not the caller's (the replica must parent to the door)
-            seen = echo.headers[-1].get("traceparent")
-            assert seen is not None and caller_tid in seen
+            seen = live_backend.records[-1].traceparent
+            assert seen and caller_tid in seen
             assert "12" * 8 not in seen
         finally:
             door.stop()
-            echo.stop()
 
     def test_correlation_headers_on_error_paths(self):
         """The satellite regression: 502/all-down and bad-request
         responses must carry the trace id (and the last-tried backend)
         too — an unattributable 502 is unactionable."""
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": _free_port(),
-              "replica_id": "dead"}], probe_interval_s=3600.0,
-        ).start()
+        door = EventFrontDoor([_dead("dead")],
+                              probe_interval_s=3600.0).start()
         try:
-            st, hd, _body = _post(door.port)
+            st, hd, _body = post(door.port)
             assert st == 502
             assert hd.get("X-GK-Trace-Id")
             assert hd.get("X-GK-Replica") == "dead"
@@ -301,125 +165,111 @@ class TestWireObservability:
             door.stop()
 
     def test_stage_and_request_metrics_recorded(self, live_backend):
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}], probe_interval_s=3600.0,
-        ).start()
+        obstrace.configure(buffer_size=256, sample_rate=1.0)
+        door = EventFrontDoor([live_backend.backend()],
+                              probe_interval_s=3600.0).start()
         try:
             reqs_before = dict(global_registry().view_rows(
                 "frontdoor_requests_total"))
-            assert _post(door.port)[0] == 200
+            assert post(door.port)[0] == 200
 
             def stages_seen():
-                # write_back records a hair after the response flushes
                 return {k[0] for k in global_registry().view_rows(
                     "frontdoor_stage_seconds")}
 
             assert wait_until(
                 lambda: set(WIRE_STAGES) <= stages_seen()
             ), stages_seen()
-            reqs = global_registry().view_rows(
-                "frontdoor_requests_total")
             key = ("ok", "live")
-            assert reqs.get(key, 0) == reqs_before.get(key, 0) + 1
+
+            def counted():
+                # outcomes flush with the reactor's next tick
+                return global_registry().view_rows(
+                    "frontdoor_requests_total").get(key, 0)
+
+            assert wait_until(
+                lambda: counted() == reqs_before.get(key, 0) + 1)
         finally:
             door.stop()
 
     def test_fleetz_latency_summary(self, live_backend):
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}], probe_interval_s=3600.0,
-        ).start()
+        door = EventFrontDoor([live_backend.backend()],
+                              probe_interval_s=3600.0).start()
         try:
             for _ in range(5):
-                assert _post(door.port)[0] == 200
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=10)
-            conn.request("GET", "/fleetz")
-            stats = json.loads(conn.getresponse().read())
-            conn.close()
+                assert post(door.port)[0] == 200
+            stats = json.loads(get(door.port, "/fleetz")[1])
             lat = stats["backends"][0]["latency"]
             assert lat["n"] == 5
             assert lat["p50_ms"] is not None
             assert lat["p99_ms"] >= lat["p50_ms"]
-            assert lat["window_s"] == FrontDoor.LATENCY_WINDOW_S
+            assert lat["window_s"] == Roster.LATENCY_WINDOW_S
         finally:
             door.stop()
 
     def test_door_serves_metrics_and_debug(self, live_backend):
         obstrace.configure(buffer_size=256, sample_rate=1.0)
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}], probe_interval_s=3600.0,
-        ).start()
+        door = EventFrontDoor([live_backend.backend()],
+                              probe_interval_s=3600.0).start()
         try:
-            assert _post(door.port)[0] == 200
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=10)
-            conn.request("GET", "/metrics")
-            body = conn.getresponse().read().decode()
+            assert post(door.port)[0] == 200
+            body = get(door.port, "/metrics")[1].decode()
             assert "gatekeeper_frontdoor_stage_seconds" in body
             assert "# EOF" not in body
 
             def ring_traces():
-                conn.request("GET", "/debug/traces?min_ms=0")
-                r = conn.getresponse()
-                assert r.status == 200
-                return json.loads(r.read())["traces"]
+                st, data = get(door.port, "/debug/traces?min_ms=0")
+                assert st == 200
+                return json.loads(data)["traces"]
 
-            # the wire trace completes just after the response flushes
             assert wait_until(lambda: bool(ring_traces()))
-            conn.close()
         finally:
             door.stop()
 
 
 class TestEjectionReadmission:
-    def test_dead_backend_readmitted_when_it_returns(self):
-        port = _free_port()
-        live = _Stub("a")
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": port, "replica_id": "flappy"},
-             {"host": "127.0.0.1", "port": live.port, "replica_id": "a"}],
+    def test_dead_backend_readmitted_when_it_returns(self, live_backend):
+        port, ready_port = free_port(), free_port()
+        door = EventFrontDoor(
+            [{"host": "127.0.0.1", "port": port,
+              "probe_port": ready_port, "replica_id": "flappy"},
+             live_backend.backend()],
             policy=ROUND_ROBIN, probe_interval_s=0.05,
         ).start()
+        revived = ready = None
         try:
-            _post(door.port)  # trips the refused->eject path
+            post(door.port)  # trips the refused->eject path
             assert wait_until(
                 lambda: door.stats()["backends"][0]["ejected"]
             )
-            # the replica comes back on the SAME port: the prober readmits
-            revived = _Stub("flappy", port=port)
-            try:
-                assert wait_until(
-                    lambda: not door.stats()["backends"][0]["ejected"]
-                ), "prober never readmitted the revived backend"
-                served = {
-                    json.loads(_post(door.port)[2])["served_by"]
-                    for _ in range(8)
-                }
-                assert served == {"flappy", "a"}
-            finally:
-                revived.stop()
+            # the replica comes back on the SAME ports: the prober's
+            # /readyz GET readmits it
+            revived = StubWire(name="flappy", port=port)
+            ready = ReadyStub(port=ready_port)
+            assert wait_until(
+                lambda: not door.stats()["backends"][0]["ejected"]
+            ), "prober never readmitted the revived backend"
+            served = {_served_by(post(door.port)[2]) for _ in range(8)}
+            assert served == {"flappy", "live"}
         finally:
             door.stop()
-            live.stop()
+            if revived is not None:
+                revived.stop()
+            if ready is not None:
+                ready.stop()
 
     def test_set_backend_repoints_and_readmits(self, live_backend):
         """The supervisor's restart hook: the replica comes back on a
         fresh ephemeral port; set_backend re-points the named entry."""
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": _free_port(),
-              "replica_id": "r0"}],
-            probe_interval_s=3600.0,
-        ).start()
+        door = EventFrontDoor([_dead("r0")],
+                              probe_interval_s=3600.0).start()
         try:
-            assert _post(door.port)[0] == 502
+            assert post(door.port)[0] == 502
             assert door.set_backend(
                 "r0", "127.0.0.1", live_backend.port) is True
-            st, _hd, body = _post(door.port)
+            st, _hd, body = post(door.port)
             assert st == 200
-            assert json.loads(body)["served_by"] == "live"
+            assert _served_by(body) == "live"
             b = door.stats()["backends"][0]
             assert b["port"] == live_backend.port
             assert b["ejected"] is False
@@ -428,20 +278,14 @@ class TestEjectionReadmission:
             door.stop()
 
     def test_suspend_takes_backend_out_of_rotation(self, live_backend):
-        second = _Stub("b")
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"},
-             {"host": "127.0.0.1", "port": second.port,
-              "replica_id": "b"}],
+        second = StubWire(name="b")
+        door = EventFrontDoor(
+            [live_backend.backend(), second.backend()],
             policy=ROUND_ROBIN, probe_interval_s=3600.0,
         ).start()
         try:
             assert door.suspend("b") is True
-            served = {
-                json.loads(_post(door.port)[2])["served_by"]
-                for _ in range(6)
-            }
+            served = {_served_by(post(door.port)[2]) for _ in range(6)}
             assert served == {"live"}
             assert door.suspend("ghost") is False
         finally:
@@ -449,177 +293,36 @@ class TestEjectionReadmission:
             second.stop()
 
     def test_healthz_counts_ejected_backends_dead(self):
-        door = FrontDoor(
-            [("127.0.0.1", _free_port())], probe_interval_s=3600.0,
+        door = EventFrontDoor(
+            [("127.0.0.1", free_port())], probe_interval_s=3600.0,
         ).start()
         try:
-            _post(door.port)  # refused -> ejected
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=5)
-            conn.request("GET", "/healthz")
-            resp = conn.getresponse()
-            assert resp.status == 503
-            resp.read()
-            conn.close()
+            post(door.port)  # refused -> ejected
+            assert get(door.port, "/healthz")[0] == 503
         finally:
             door.stop()
-
-
-class _SlowStub:
-    """Backend that parks each POST on a gate (a wedged/slow replica)."""
-
-    def __init__(self, name: str = "slow"):
-        self.name = name
-        self.gate = threading.Event()
-        outer = self
-
-        class H(BaseHTTPRequestHandler):
-            protocol_version = "HTTP/1.1"
-
-            def log_message(self, *a):
-                pass
-
-            def do_GET(self):
-                body = b"ok"
-                self.send_response(200)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-            def do_POST(self):
-                n = int(self.headers.get("Content-Length", 0))
-                self.rfile.read(n)
-                outer.gate.wait(10)
-                body = json.dumps({"served_by": outer.name}).encode()
-                self.send_response(200)
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
-
-        self.server = ThreadingHTTPServer(("127.0.0.1", 0), H)
-        self.port = self.server.server_address[1]
-        threading.Thread(target=self.server.serve_forever,
-                         daemon=True).start()
-
-    def stop(self):
-        self.gate.set()
-        self.server.shutdown()
-        self.server.server_close()
-
-
-ADMIT_BODY = json.dumps({"request": {"uid": "uid-overload"}}).encode()
 
 
 class TestDeadlinePropagation:
-    """ISSUE 12: the door derives min(own budget, caller header), clamps
-    backend timeouts to the remaining budget, forwards the REMAINING
-    milliseconds downstream, and answers expired work with the explicit
+    """ISSUE 12: the door derives min(own budget, caller header), sends
+    the REMAINING milliseconds downstream (test_event_edge.py reads them
+    off the wire record), and answers expired work with the explicit
     fail-open/closed verdict."""
 
-    def test_remaining_budget_forwarded_in_header(self):
-        echo = _EchoHeaders()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": echo.port, "replica_id": "e"}],
-            probe_interval_s=3600.0, admission_budget_s=0.5,
-        ).start()
-        try:
-            st, _hd, _body = _post(door.port, ADMIT_BODY)
-            assert st == 200
-            fwd = echo.headers[-1].get("X-GK-Deadline-Ms")
-            assert fwd is not None
-            # REMAINING budget: below the granted 500ms, above zero
-            assert 0.0 < float(fwd) <= 500.0
-        finally:
-            door.stop()
-            echo.stop()
-
-    def test_caller_header_min_merged_with_door_budget(self):
-        echo = _EchoHeaders()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": echo.port, "replica_id": "e"}],
-            probe_interval_s=3600.0, admission_budget_s=10.0,
-        ).start()
-        try:
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=10)
-            conn.request("POST", "/v1/admit", body=ADMIT_BODY,
-                         headers={"Content-Type": "application/json",
-                                  "X-GK-Deadline-Ms": "200"})
-            resp = conn.getresponse()
-            resp.read()
-            conn.close()
-            assert resp.status == 200
-            fwd = float(echo.headers[-1]["X-GK-Deadline-Ms"])
-            assert fwd <= 200.0  # the tighter caller bound won
-        finally:
-            door.stop()
-            echo.stop()
-
-    def test_expired_on_arrival_answers_explicit_verdict(self):
-        """Dead-on-arrival work is dropped at door accept: a well-formed
-        fail-closed AdmissionReview (code 504), never a proxied hop —
-        the backend must not even see it."""
-        echo = _EchoHeaders()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": echo.port, "replica_id": "e"}],
-            probe_interval_s=3600.0,
-        ).start()
-        try:
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=10)
-            conn.request("POST", "/v1/admit", body=ADMIT_BODY,
-                         headers={"Content-Type": "application/json",
-                                  "X-GK-Deadline-Ms": "-5"})
-            resp = conn.getresponse()
-            body = resp.read()
-            conn.close()
-            assert resp.status == 200
-            out = json.loads(body)["response"]
-            assert out["allowed"] is False
-            assert out["status"]["code"] == 504
-            assert out["uid"] == "uid-overload"  # extracted from the body
-            assert echo.headers == []  # never proxied
-            assert door.sheds == 1
-        finally:
-            door.stop()
-            echo.stop()
-
-    def test_expired_fail_open_allows_with_annotation(self):
-        door = FrontDoor(
-            [("127.0.0.1", _free_port())],
-            probe_interval_s=3600.0, fail_open=True,
-        ).start()
-        try:
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=10)
-            conn.request("POST", "/v1/admit", body=ADMIT_BODY,
-                         headers={"Content-Type": "application/json",
-                                  "X-GK-Deadline-Ms": "0"})
-            resp = conn.getresponse()
-            out = json.loads(resp.read())["response"]
-            conn.close()
-            assert out["allowed"] is True
-            assert out["auditAnnotations"] == {
-                "admission.gatekeeper.sh/fail-open": "deadline-exhausted"
-            }
-        finally:
-            door.stop()
-
     def test_slow_backend_with_tight_budget_expires_in_budget(self):
-        """The clamped socket timeout firing on an exhausted budget
-        answers the explicit expired verdict within ~budget — never a
-        30s socket park.  ONE expiry charges the error streak (a
-        backend timing out every request is indistinguishable from
-        wedged) but does not eject; the next success clears it."""
-        slow = _SlowStub()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": slow.port,
-              "replica_id": "slow"}],
+        """The deadline timer firing on an exhausted budget answers the
+        explicit expired verdict within ~budget — never a parked
+        socket.  ONE expiry charges the error streak (a backend timing
+        out every request is indistinguishable from wedged) but does
+        not eject; the next success clears it."""
+        slow = StubWire(mode="gate", name="slow")
+        door = EventFrontDoor(
+            [slow.backend()],
             probe_interval_s=3600.0, admission_budget_s=0.3,
         ).start()
         try:
             t0 = time.perf_counter()
-            st, _hd, body = _post(door.port, ADMIT_BODY)
+            st, _hd, body = post(door.port, ADMIT_BODY)
             dur = time.perf_counter() - t0
             assert st == 200
             out = json.loads(body)["response"]
@@ -633,7 +336,7 @@ class TestDeadlinePropagation:
             # that occasionally carries a too-tight request never
             # accumulates toward ejection
             slow.gate.set()
-            st2, _hd2, _b2 = _post(door.port, ADMIT_BODY)
+            st2, _hd2, _b2 = post(door.port, ADMIT_BODY)
             assert st2 == 200
             assert door.stats()["backends"][0]["consecutive_errors"] == 0
         finally:
@@ -641,165 +344,65 @@ class TestDeadlinePropagation:
             slow.stop()
 
     def test_wedged_backend_ejects_under_deadline_timeouts(self):
-        """A backend that times out EVERY budget-clamped request is
-        wedged from the door's perspective and must eject like any
-        failing backend — never-ejecting would leave it burning half
-        of all request budgets forever; a falsely-ejected healthy one
-        is readmitted by the /readyz prober."""
-        slow = _SlowStub()  # gate never set: wedged
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": slow.port,
-              "replica_id": "wedged"}],
+        """A backend that times out EVERY budgeted request is wedged
+        from the door's perspective and must eject like any failing
+        backend — never-ejecting would leave it burning half of all
+        request budgets forever; a falsely-ejected healthy one is
+        readmitted by the /readyz prober."""
+        wedged = StubWire(mode="hang", name="wedged")
+        door = EventFrontDoor(
+            [wedged.backend()],
             probe_interval_s=3600.0, admission_budget_s=0.2,
         ).start()
         try:
-            for _ in range(FrontDoor.EJECT_ERROR_STREAK):
-                st, _hd, body = _post(door.port, ADMIT_BODY)
+            for _ in range(Roster.EJECT_ERROR_STREAK):
+                st, _hd, body = post(door.port, ADMIT_BODY)
                 assert st == 200
                 assert json.loads(body)["response"]["status"]["code"] \
                     == 504
             assert door.stats()["backends"][0]["ejected"] is True
         finally:
             door.stop()
-            slow.stop()
+            wedged.stop()
 
 
 class TestInflightShed:
-    def test_saturated_backends_shed_fast_with_retry_after(self):
-        slow = _SlowStub()
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": slow.port,
-              "replica_id": "slow"}],
-            probe_interval_s=3600.0, max_inflight=1,
-        ).start()
-        occupier = threading.Thread(
-            target=lambda: _post(door.port, ADMIT_BODY))
-        try:
-            occupier.start()
-            assert wait_until(
-                lambda: door.stats()["backends"][0]["inflight"] >= 1)
-            t0 = time.perf_counter()
-            conn = http.client.HTTPConnection(
-                "127.0.0.1", door.port, timeout=10)
-            conn.request("POST", "/v1/admit", body=ADMIT_BODY,
-                         headers={"Content-Type": "application/json"})
-            resp = conn.getresponse()
-            body = resp.read()
-            dur = time.perf_counter() - t0
-            hd = dict(resp.getheaders())
-            conn.close()
-            assert resp.status == 429
-            assert hd.get("Retry-After") == "1"
-            out = json.loads(body)["response"]
-            assert out["allowed"] is False
-            assert out["status"]["code"] == 429
-            assert out["uid"] == "uid-overload"
-            assert dur < 0.2, f"shed took {dur:.3f}s (must be fast)"
-            assert door.sheds >= 1
-        finally:
-            slow.gate.set()
-            occupier.join(timeout=10)
-            door.stop()
-            slow.stop()
-
     def test_no_bound_means_no_shed(self, live_backend):
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}], probe_interval_s=3600.0,
-        ).start()
+        door = EventFrontDoor([live_backend.backend()],
+                              probe_interval_s=3600.0).start()
         try:
-            assert door._has_capacity() is True
-            st, _hd, _body = _post(door.port, ADMIT_BODY)
+            assert door.roster.has_capacity() is True
+            st, _hd, _body = post(door.port, ADMIT_BODY)
             assert st == 200 and door.sheds == 0
         finally:
             door.stop()
 
 
-class TestRetryBudget:
-    def test_empty_bucket_denies_the_retry(self, live_backend):
-        """Two dead backends ahead of a live one under round robin with
-        a zero-capacity retry budget: the first request's failure CANNOT
-        be retried — explicit 502 even though a live backend exists."""
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": _free_port(),
-              "replica_id": "dead0"},
-             {"host": "127.0.0.1", "port": _free_port(),
-              "replica_id": "dead1"},
-             {"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}],
-            policy=ROUND_ROBIN, probe_interval_s=3600.0,
-            retry_budget_cap=0.0, retry_budget_rate_per_s=0.0,
-        ).start()
-        try:
-            codes = [_post(door.port, ADMIT_BODY)[0] for _ in range(6)]
-            assert 502 in codes
-            assert door.retry_budget.denied >= 1
-            assert door.stats()["retry_budget"]["denied"] >= 1
-            # the dead pair still ejects on refusal, so the door
-            # converges onto the live backend WITHOUT retries
-            assert wait_until(lambda: all(
-                b["ejected"] for b in door.stats()["backends"]
-                if b["replica_id"].startswith("dead")))
-            assert _post(door.port, ADMIT_BODY)[0] == 200
-        finally:
-            door.stop()
-
-    def test_bucket_refills_and_grants_again(self):
-        from gatekeeper_tpu.fleet.frontdoor import RetryBudget
-
-        rb = RetryBudget(cap=2.0, rate_per_s=1000.0)
-        assert rb.take() and rb.take()
-        # cap 2, both taken; at 1000/s the bucket refills immediately
-        assert wait_until(rb.take, timeout_s=1.0)
-
-    def test_deny_then_starve(self):
-        from gatekeeper_tpu.fleet.frontdoor import RetryBudget
-
-        rb = RetryBudget(cap=1.0, rate_per_s=0.0)
-        assert rb.take()
-        assert not rb.take()
-        assert rb.denied == 1
-        assert rb.tokens() == 0.0
-
-
-@pytest.fixture(params=["threaded", "evloop"])
-def door_cls(request):
-    """Both serving edges must survive slow clients: the original
-    thread-per-connection door (socket timeouts) and the ISSUE 19
-    event-loop door (sweep timer) — same externally visible contract."""
-    if request.param == "evloop":
-        from gatekeeper_tpu.fleet.evdoor import EventFrontDoor
-
-        return EventFrontDoor
-    return FrontDoor
-
-
 class TestSlowClientHardening:
-    def test_slowloris_header_stall_is_closed_by_timeout(self, door_cls):
-        door = door_cls(
-            [("127.0.0.1", _free_port())],
+    def test_slowloris_header_stall_is_closed_by_timeout(self):
+        door = EventFrontDoor(
+            [("127.0.0.1", free_port())],
             probe_interval_s=3600.0, header_timeout_s=0.3,
         ).start()
         try:
             s = socket.create_connection(("127.0.0.1", door.port),
                                          timeout=5)
             s.sendall(b"POST /v1/admit HTTP/1.1\r\nHost: x\r\n")
-            # ...and never finish the headers: the inbound socket
-            # timeout must close the connection instead of parking the
-            # accept thread forever
+            # ...and never finish the headers: the sweep must close the
+            # connection instead of holding it forever
             s.settimeout(5.0)
             t0 = time.perf_counter()
             data = s.recv(1024)
             dur = time.perf_counter() - t0
             s.close()
             assert data == b""  # server closed on us
-            assert dur < 3.0, f"slowloris held the thread {dur:.1f}s"
+            assert dur < 3.0, f"slowloris was held {dur:.1f}s"
         finally:
             door.stop()
 
-    def test_stalled_body_answers_408(self, door_cls):
-        door = door_cls(
-            [("127.0.0.1", _free_port())],
+    def test_stalled_body_answers_408(self):
+        door = EventFrontDoor(
+            [("127.0.0.1", free_port())],
             probe_interval_s=3600.0, header_timeout_s=0.3,
         ).start()
         try:
@@ -823,15 +426,13 @@ class TestSlowClientHardening:
             door.stop()
 
     def test_oversized_body_answers_413_without_reading(
-            self, live_backend, door_cls):
-        door = door_cls(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}], probe_interval_s=3600.0,
-        ).start()
+            self, live_backend):
+        door = EventFrontDoor([live_backend.backend()],
+                              probe_interval_s=3600.0).start()
         try:
             s = socket.create_connection(("127.0.0.1", door.port),
                                          timeout=5)
-            huge = FrontDoor.MAX_BODY + 1
+            huge = EventFrontDoor.MAX_BODY + 1
             s.sendall(f"POST /v1/admit HTTP/1.1\r\nHost: x\r\n"
                       f"Content-Length: {huge}\r\n\r\n".encode())
             s.settimeout(5.0)
@@ -840,63 +441,3 @@ class TestSlowClientHardening:
             assert b"413" in data.split(b"\r\n", 1)[0]
         finally:
             door.stop()
-
-
-class TestInflightReservation:
-    """The max_inflight bound is enforced by RESERVATION in _choose
-    (slot taken under the backend's lock), not by a check-then-act
-    read: concurrent accepts cannot overshoot the bound, and a
-    saturated-but-live fleet raises OverloadShed instead of silently
-    falling through to a saturated backend."""
-
-    def test_choose_reserves_and_sheds_at_the_bound(self, live_backend):
-        from gatekeeper_tpu.deadline import OverloadShed
-
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}],
-            probe_interval_s=3600.0, max_inflight=2,
-        )
-        b1 = door._choose()
-        b2 = door._choose()
-        assert b1 is b2 and b1.inflight == 2  # both slots reserved
-        try:
-            door._choose()
-            assert False, "third choose must shed, not overshoot"
-        except OverloadShed:
-            pass
-        # releasing one reservation makes the slot choosable again
-        with b1.lock:
-            b1.inflight -= 1
-        assert door._choose() is b1 and b1.inflight == 2
-
-    def test_concurrent_chooses_never_overshoot(self, live_backend):
-        from gatekeeper_tpu.deadline import OverloadShed
-
-        door = FrontDoor(
-            [{"host": "127.0.0.1", "port": live_backend.port,
-              "replica_id": "live"}],
-            probe_interval_s=3600.0, max_inflight=3,
-        )
-        granted, shed = [], []
-        lock = threading.Lock()
-        start = threading.Barrier(16)
-
-        def race():
-            start.wait()
-            try:
-                b = door._choose()
-            except OverloadShed:
-                with lock:
-                    shed.append(1)
-                return
-            with lock:
-                granted.append(b)
-
-        ts = [threading.Thread(target=race) for _ in range(16)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=10)
-        assert len(granted) == 3 and len(shed) == 13
-        assert door.backends[0].inflight == 3  # exactly the bound

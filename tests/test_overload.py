@@ -3,7 +3,8 @@ deadline derivation (configured budget x AdmissionReview timeoutSeconds
 x forwarded wire budget — min() semantics pinned), the micro-batcher's
 bounded pending queue with dry-run-first shedding, and the explicit
 fail-open/closed shed decision.  Front-door-side overload behavior:
-tests/test_frontdoor.py TestOverloadPlane; ladder: tests/test_brownout.py.
+tests/test_event_edge.py TestRefusalTaxonomy, tests/test_roster.py;
+ladder: tests/test_brownout.py.
 """
 
 import json

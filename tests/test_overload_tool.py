@@ -17,11 +17,7 @@ from .test_snapshot_concurrent import spawn_available
 
 @spawn_available
 def test_fleet_sheds_fast_and_keeps_verdicts_under_saturation():
-    """Both serving edges hold the overload contract: the threaded door
-    and the ISSUE 19 selectors-based door + batched wire listeners must
-    shed/expire/serve under the identical saturation burst (one shared
-    replica fleet — the taxonomy is a property of the doors)."""
-    assert chk.run_checks(edge="both") == []
+    assert chk.run_checks() == []
 
 
 def test_classify_taxonomy():

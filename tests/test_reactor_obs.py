@@ -18,8 +18,8 @@ from gatekeeper_tpu.fleet.evloop import EventLoop
 from gatekeeper_tpu.fleet.wirelistener import WireListener
 from gatekeeper_tpu.obs import flightrec, reactorobs
 from gatekeeper_tpu.obs.debug import get_router
-from tests.test_event_edge import _Handler, _raw_post
-from tests.test_frontdoor import wait_until
+from tests.test_event_edge import _Handler
+from tests.wirestub import raw_post, wait_until
 
 ADMIT_BODY = json.dumps({"request": {"uid": "uid-reactor"}}).encode()
 
@@ -232,12 +232,12 @@ class TestConnz:
 
         def churn():
             while not stop.is_set():
-                _raw_post(door.port, [ADMIT_BODY] * 4)
+                raw_post(door.port, [ADMIT_BODY] * 4)
 
         threads = [threading.Thread(target=churn) for _ in range(3)]
         try:
             # prime: one admission completes end to end before churn
-            status, _body = _raw_post(door.port, [ADMIT_BODY])[0]
+            status, _body = raw_post(door.port, [ADMIT_BODY])[0]
             assert status == 200
             for t in threads:
                 t.start()

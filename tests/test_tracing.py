@@ -649,27 +649,6 @@ class TestStageClock:
             spans["gk.audit.pack"]["duration_ms"] / 1e3 == pytest.approx(
                 spans["gk.audit.render"]["start"], abs=1e-6)
 
-    def test_lap_names_the_closed_interval_like_the_front_door(self):
-        from gatekeeper_tpu.fleet import frontdoor
-
-        import time
-
-        t0 = time.perf_counter()
-        clock = frontdoor._StageClock(t0)
-        assert isinstance(clock, obs.StageClock)
-        with obs.root_span("wire", start=t0):
-            a = clock.mark(frontdoor.STAGE_ACCEPT)
-            b = clock.mark(frontdoor.STAGE_READ_BODY, attempt=1)
-        assert clock.t == b and b >= a >= t0
-        [tr] = obs.get_tracer().traces()
-        stages = [(s["name"], s["attrs"]["stage"]) for s in tr["spans"]
-                  if s["name"].startswith("wire.")]
-        assert stages == [("wire.accept", "accept"),
-                          ("wire.read_body", "read_body")]
-        # the door keeps its own series; nothing lands in host_stage_*
-        assert not any(k[0] == "wire" and k[1] in ("accept", "read_body")
-                       for k in _stage_rows("host_stage_seconds_total"))
-
     def test_annotation_sink_is_absent_without_jax(self):
         """In a process that has not imported jax (the door, the
         harness parent) the clock neither imports it nor fails."""

@@ -9,21 +9,19 @@ tests/test_fleet_parity_tool.py; also runnable standalone):
    divergence here means shared-warmth restore drifted between
    processes, the one bug class a fleet can ship that a single process
    cannot.
-2. Front-door fidelity: the body returned through the front door must be
-   byte-identical to what the chosen backend answered (the door must
-   never rewrite a verdict), and the X-GK-Replica attribution must name
-   a real backend.
+2. Front-door fidelity: the body returned through the front door —
+   the batched wire protocol to the replicas' wire listeners — must be
+   byte-identical to what a replica's HTTP listener answers for the
+   same request (the door is a byte splice and the replica parses the
+   AdmissionReview once on either listener: it must never rewrite a
+   verdict), and the X-GK-Replica attribution must name a real backend.
 3. Oracle parity: allow/deny and the rendered violation text (sans the
    webhook's "[denied by ...]" prefix) must match a freshly loaded
    interpreter oracle evaluating the same requests byte-for-byte.
-4. Event-edge fidelity (ISSUE 19): the same corpus through the
-   selectors-based front door — persistent connections, batched wire
-   protocol to the replicas' wire listeners — must answer byte-identical
-   bodies too.  The door is a byte splice on both edges or it is wrong.
 
-Run: python tools/check_fleet_parity.py [--edge threaded|evloop|both]
-(exit 0 clean, 1 with findings).  Spawns 3 replica subprocesses; where
-process spawn is unavailable the tier-1 wrapper skips cleanly.
+Run: python tools/check_fleet_parity.py (exit 0 clean, 1 with
+findings).  Spawns 3 replica subprocesses; where process spawn is
+unavailable the tier-1 wrapper skips cleanly.
 """
 
 from __future__ import annotations
@@ -128,12 +126,11 @@ def diff_verdicts(raw_bodies, oracle_verdicts) -> list:
     return problems
 
 
-def run_checks(edge: str = "both") -> list:
+def run_checks() -> list:
     import shutil
 
     from gatekeeper_tpu.fleet import (
         EventFrontDoor,
-        FrontDoor,
         spawn_fleet,
         spawn_replica,
     )
@@ -169,10 +166,15 @@ def run_checks(edge: str = "both") -> list:
                     f"{h.ready.get('restore_outcome')!r}, not the shared "
                     f"snapshot — parity would compare cold processes"
                 )
+        missing = [h.replica_id for h in fleet if not h.wire_port]
+        if missing:
+            problems.append(
+                f"replicas {missing} announced no wire_port — the "
+                "door cannot be driven"
+            )
         if problems:
             return problems
-        if edge in ("threaded", "both"):
-            door = FrontDoor([h.backend() for h in fleet]).start()
+        door = EventFrontDoor([h.wire_backend() for h in fleet]).start()
 
         raw: dict = {h.replica_id: [] for h in [solo] + fleet}
         door_bodies = []
@@ -186,8 +188,6 @@ def run_checks(edge: str = "both") -> list:
                         f"answered {st}"
                     )
                 raw[h.replica_id].append(data)
-            if door is None:
-                continue
             st, hd, data = _post(door.port, body)
             if st != 200:
                 problems.append(f"request {i}: front door answered {st}")
@@ -210,44 +210,6 @@ def run_checks(edge: str = "both") -> list:
                     f"replica answer (door {len(data)}B, "
                     f"replica {len(raw['solo'][i])}B)"
                 )
-
-        # event-loop edge (ISSUE 19): the same corpus through the
-        # selectors door + batched wire protocol.  The replica parses
-        # the AdmissionReview once at its wire listener and the door
-        # splices bytes both ways, so the body must STILL be identical
-        # to what the HTTP listener answers for the same request.
-        if edge in ("evloop", "both"):
-            missing = [h.replica_id for h in fleet if not h.wire_port]
-            if missing:
-                return problems + [
-                    f"replicas {missing} announced no wire_port — the "
-                    "event edge cannot be driven"
-                ]
-            evdoor = EventFrontDoor(
-                [h.wire_backend() for h in fleet]).start()
-            try:
-                for i, req in enumerate(reqs):
-                    body = json.dumps({"request": req}).encode()
-                    st, hd, data = _post(evdoor.port, body)
-                    if st != 200:
-                        problems.append(
-                            f"request {i}: event-loop door answered {st}"
-                        )
-                        continue
-                    rid = hd.get("X-GK-Replica", "")
-                    if rid not in raw:
-                        problems.append(
-                            f"request {i}: event-loop door attributed "
-                            f"to unknown replica {rid!r}"
-                        )
-                    if data != raw["solo"][i]:
-                        problems.append(
-                            f"request {i}: event-edge body differs from "
-                            f"the replica answer (edge {len(data)}B, "
-                            f"replica {len(raw['solo'][i])}B)"
-                        )
-            finally:
-                evdoor.stop()
         return problems
     finally:
         if door is not None:
@@ -260,17 +222,7 @@ def run_checks(edge: str = "both") -> list:
 
 
 def main() -> int:
-    import argparse
-
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--edge", choices=("threaded", "evloop", "both"),
-                    default="evloop",
-                    help="which serving edge(s) to drive the corpus "
-                         "through (default: evloop — the threaded "
-                         "FrontDoor is deprecated and must be asked for "
-                         "explicitly, or use 'both' for back-to-back)")
-    args = ap.parse_args()
-    problems = run_checks(edge=args.edge)
+    problems = run_checks()
     if problems:
         print("fleet parity check FAILED:")
         for p in problems:
@@ -278,8 +230,8 @@ def main() -> int:
         return 1
     print(
         f"fleet parity ok: {N_REQUESTS} requests byte-identical across "
-        f"solo + 2 fleet replicas, front-door fidelity verified on the "
-        f"{args.edge} edge(s), verdicts match the interpreter oracle"
+        "solo + 2 fleet replicas, front-door fidelity verified, "
+        "verdicts match the interpreter oracle"
     )
     return 0
 

@@ -85,7 +85,7 @@ HOT_PATH_MODULES = (
     "gatekeeper_tpu/obs/reactorobs.py",
     "gatekeeper_tpu/ops/xlacache.py",
     "gatekeeper_tpu/ops/asynccompile.py",
-    "gatekeeper_tpu/fleet/frontdoor.py",
+    "gatekeeper_tpu/fleet/roster.py",
     "gatekeeper_tpu/fleet/evloop.py",
     "gatekeeper_tpu/fleet/evdoor.py",
     "gatekeeper_tpu/fleet/wirelistener.py",
@@ -258,26 +258,26 @@ def check_label_cardinality() -> list:
 
 
 def check_wire_stages() -> list:
-    """The front door's WIRE_STAGES set vs its own STAGE_* constants and
-    the docs/tracing.md stage table."""
-    from gatekeeper_tpu.fleet import frontdoor
+    """The wire contract's WIRE_STAGES set vs its own STAGE_* constants
+    and the docs/tracing.md stage table."""
+    from gatekeeper_tpu.fleet import wireproto
 
     problems = []
-    stages = set(frontdoor.WIRE_STAGES)
+    stages = set(wireproto.WIRE_STAGES)
     declared = {
-        v for k, v in vars(frontdoor).items()
+        v for k, v in vars(wireproto).items()
         if k.startswith("STAGE_") and isinstance(v, str)
     }
     for s in declared - stages:
         problems.append(
-            f"frontdoor stage constant {s!r} is not listed in "
+            f"wire stage constant {s!r} is not listed in "
             "WIRE_STAGES — it would be invisible to the stage-breakdown "
             "contract"
         )
     for s in stages - declared:
         problems.append(
             f"WIRE_STAGES entry {s!r} has no STAGE_* constant in "
-            "fleet/frontdoor.py"
+            "fleet/wireproto.py"
         )
     doc_path = os.path.join(REPO, "docs", "tracing.md")
     try:

@@ -22,13 +22,9 @@ the check asserts the overload contract of docs/failure-modes.md:
 4. **nothing unexplained** — no 502s, no connection errors, no
    responses outside the (accepted | shed | expired) taxonomy.
 
-Run: python tools/check_overload.py [--edge threaded|evloop|both]
-(exit 0 clean, 1 with findings).  ``--edge evloop`` drives the same
-burst through the ISSUE 19 selectors-based front door and the replicas'
-wire listeners — the overload contract is edge-independent and tier-1
-proves it on both via ``--edge both`` (one fleet, both doors back to
-back).  Spawns replica subprocesses; where spawn is unavailable the
-tier-1 wrapper skips cleanly (same contract as check_self_heal).
+Run: python tools/check_overload.py (exit 0 clean, 1 with findings).
+Spawns replica subprocesses; where spawn is unavailable the tier-1
+wrapper skips cleanly (same contract as check_self_heal).
 """
 
 from __future__ import annotations
@@ -99,7 +95,7 @@ classify = classify_response
 _verdict_matches = verdict_matches
 
 
-def _drive_door(door, edge: str, reqs, bodies, oracle_verdicts) -> list:
+def _drive_door(door, reqs, bodies, oracle_verdicts) -> list:
     problems: list = []
     try:
         results: list = []  # (kind, dur_s, status, out, corpus_idx)
@@ -194,7 +190,7 @@ def _drive_door(door, edge: str, reqs, bodies, oracle_verdicts) -> list:
             )
 
         print(
-            f"overload [{edge}]: {len(results)} responses in "
+            f"overload: {len(results)} responses in "
             f"{BURST_S:.0f}s — {by_kind}; door sheds {len(door_sheds)} "
             f"(p99 {door_sheds[-1] * 1e3:.1f}ms max) ; door stats "
             f"{json.dumps(door.stats()['retry_budget'])}",
@@ -205,21 +201,15 @@ def _drive_door(door, edge: str, reqs, bodies, oracle_verdicts) -> list:
         door.stop()
 
 
-def run_checks(edge: str = "evloop") -> list:
-    """Drive the saturation burst through the requested serving edge(s).
-
-    ``edge="both"`` stages ONE snapshot + replica fleet and drives the
-    threaded door and the event-loop door against it back to back —
-    the fleet spawn dominates the tool's runtime, and the contract
-    being asserted is a property of the doors, not of the replicas.
-    """
+def run_checks() -> list:
+    """Stage one snapshot + a 2-replica fleet and drive the saturation
+    burst through the front door."""
     import shutil
 
-    from gatekeeper_tpu.fleet import EventFrontDoor, FrontDoor, spawn_fleet
+    from gatekeeper_tpu.fleet import EventFrontDoor, spawn_fleet
     from gatekeeper_tpu.snapshot import Snapshotter
     from gatekeeper_tpu.util.synthetic import build_driver
 
-    problems: list = []
     root = tempfile.mkdtemp(prefix="gk-overload-")
     snap_dir = os.path.join(root, "snap")
     # no cache dir is handed to the replicas: each resolves the fixed one
@@ -240,29 +230,16 @@ def run_checks(edge: str = "evloop") -> list:
             env={"JAX_PLATFORMS": "cpu"},
             extra_flags=["--webhook-max-pending", str(MAX_PENDING)],
         )
-        edges = ("threaded", "evloop") if edge == "both" else (edge,)
-        for e in edges:
-            if e == "evloop":
-                missing = [h.replica_id for h in handles if not h.wire_port]
-                if missing:
-                    problems.append(
-                        f"replicas {missing} announced no wire_port — "
-                        "the event edge cannot be driven")
-                    continue
-                door = EventFrontDoor(
-                    [h.wire_backend() for h in handles],
-                    probe_interval_s=0.1, max_inflight=MAX_INFLIGHT,
-                    admission_budget_s=BUDGET_S,
-                ).start()
-            else:
-                door = FrontDoor(
-                    [h.backend() for h in handles], probe_interval_s=0.1,
-                    max_inflight=MAX_INFLIGHT, admission_budget_s=BUDGET_S,
-                ).start()
-            problems.extend(
-                f"[{e}] {p}"
-                for p in _drive_door(door, e, reqs, bodies, oracle_verdicts))
-        return problems
+        missing = [h.replica_id for h in handles if not h.wire_port]
+        if missing:
+            return [f"replicas {missing} announced no wire_port — "
+                    "the door cannot be driven"]
+        door = EventFrontDoor(
+            [h.wire_backend() for h in handles],
+            probe_interval_s=0.1, max_inflight=MAX_INFLIGHT,
+            admission_budget_s=BUDGET_S,
+        ).start()
+        return _drive_door(door, reqs, bodies, oracle_verdicts)
     finally:
         for h in handles:
             h.stop()
@@ -270,26 +247,15 @@ def run_checks(edge: str = "evloop") -> list:
 
 
 def main() -> int:
-    import argparse
-
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--edge", choices=("threaded", "evloop", "both"),
-                    default="evloop",
-                    help="which serving edge to saturate (default: the "
-                         "event-loop door + wire listeners; the threaded "
-                         "FrontDoor is deprecated and must be asked for "
-                         "explicitly; both = one fleet, both doors back "
-                         "to back)")
-    args = ap.parse_args()
-    problems = run_checks(edge=args.edge)
+    problems = run_checks()
     if problems:
         print("overload check FAILED:")
         for p in problems:
             print(f"  - {p}")
         return 1
     print(
-        f"overload ok ({args.edge} edge): the saturation burst shed "
-        "fast with explicit fail-open/closed verdicts, kept goodput, "
+        "overload ok: the saturation burst shed fast with explicit "
+        "fail-open/closed verdicts, kept goodput, "
         "and accepted requests matched the interpreter oracle with "
         "zero divergence"
     )

@@ -113,7 +113,7 @@ def _check_verdict(i: int, data: bytes, oracle_verdicts, problems: list):
 def run_checks() -> list:
     import shutil
 
-    from gatekeeper_tpu.fleet import FrontDoor, ReplicaSupervisor
+    from gatekeeper_tpu.fleet import EventFrontDoor, ReplicaSupervisor
     from gatekeeper_tpu.snapshot import Snapshotter
     from gatekeeper_tpu.util.synthetic import build_driver
 
@@ -142,7 +142,8 @@ def run_checks() -> list:
             if backend is None:
                 d.suspend(rid)
             else:
-                d.set_backend(rid, backend["host"], backend["port"])
+                d.set_backend(rid, backend["host"], backend["port"],
+                              backend.get("probe_port", 0))
 
         sup = ReplicaSupervisor(
             snapshot_dir=snap_dir,
@@ -159,8 +160,8 @@ def run_checks() -> list:
                 )
         if problems:
             return problems
-        door = FrontDoor(
-            [h.backend() for h in handles], probe_interval_s=0.1
+        door = EventFrontDoor(
+            [h.wire_backend() for h in handles], probe_interval_s=0.1
         ).start()
         door_box["door"] = door
 
