@@ -28,13 +28,58 @@ not cluster size — the reference re-evaluates everything every interval
 from __future__ import annotations
 
 from bisect import insort
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 
 class NeedsFullSweep(Exception):
     """Capped rendering needs candidates beyond the known horizon."""
+
+
+class RenderEntry(NamedTuple):
+    """What one constraint's capped walk READ, and the Result slice it
+    produced (DeltaState.render_cache, driver._render_capped): the slice
+    is a pure function of the cap, the constraint (the state's cs_epoch)
+    and the packed content of the rows the walk visited, in order.  Rows
+    past the one at which the cap was reached, and the cluster-wide
+    candidate count, move only the reported total, which a hit
+    recomputes — so churn elsewhere in the cluster never costs a
+    re-render."""
+
+    cap: int
+    walked: Tuple[int, ...]  # rows visited in order, tombstoned included
+    gens: Tuple[int, ...]    # row_gens() of them when the walk ran
+    capped: bool             # a further candidate stood behind the cap
+    n_cand: int              # the count the walk saw
+    results: tuple           # the kept Result slice
+
+    def serves(self, cap: int, cand: Sequence[int], n_cand: int,
+               row_gen: Sequence[int]) -> bool:
+        """True when a walk over `cand` (the constraint's known candidate
+        rows now) against the pack's `row_gen` would visit the same rows
+        with the same content and end the same way."""
+        n = len(self.walked)
+        if self.cap != cap:
+            return False
+        if self.capped:
+            # the walk ends capped only while a candidate stands behind
+            # the walked prefix (any: the cap check precedes the row's)
+            if len(cand) <= n:
+                return False
+        elif len(cand) != n or n_cand != self.n_cand:
+            # the walk consumed every candidate: one appended after its
+            # last row (or one unknown past the horizon) extends it
+            return False
+        return (tuple(cand[:n]) == self.walked
+                and row_gens(row_gen, self.walked) == self.gens)
+
+
+def row_gens(row_gen: Sequence[int], rows: Sequence[int]) -> Tuple[int, ...]:
+    """The pack generations of `rows`; -1 where the pack has no such row
+    (the mask's padding past its last row)."""
+    n = len(row_gen)
+    return tuple([row_gen[r] if r < n else -1 for r in rows])
 
 
 import atexit as _atexit
@@ -196,8 +241,8 @@ class DeltaState:
         # (pending_mask_rows; absolute values, so patching is idempotent)
         self.host_mask: Optional[np.ndarray] = None
         self.pending_mask_rows: set = set()
-        # per-constraint rendered-result reuse across sweeps, keyed by the
-        # (count, candidates, row generations) signature (driver
+        # per-constraint rendered-result reuse across sweeps: (kind, name)
+        # -> RenderEntry, keyed on the rows the capped walk read (driver
         # _render_capped); traced renders bypass it
         self.render_cache: Dict = {}
         self.mask_src = mask_src

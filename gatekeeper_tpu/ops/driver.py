@@ -3702,6 +3702,20 @@ class TpuDriver(InterpDriver):
             "namespaceSelector"
         )
 
+    def _capped_total(self, kind: str, constraint: dict, capped: bool,
+                      n_cand: int, kept: int) -> Tuple[int, str]:
+        """The total a capped walk reports for one constraint (the rule
+        of audit_capped's docstring, for a rendered walk and a replayed
+        one alike): `kept` violations rendered, `n_cand` device
+        candidates."""
+        if not capped:
+            return kept, "exact"
+        if self._count_exact(kind, constraint):
+            # device count == violation count, provably: report the
+            # full total past the cap (manager.go:188 semantics)
+            return n_cand, "exact"
+        return max(n_cand, kept), "resources"
+
     # dirty rows per steady-state sweep beyond which a full device sweep
     # is cheaper than the delta evaluation + host merge
     DELTA_MAX_ROWS = 256
@@ -4161,11 +4175,23 @@ class TpuDriver(InterpDriver):
         incremental state's candidate lists (identical for a
         fresh-from-full-sweep state and a delta-updated one).
 
-        Per-constraint result reuse: a constraint whose walked candidates
-        and their row generations are unchanged since the last sweep
-        renders the identical Result slice; with 1-object churn, ~all
-        constraints reuse wholesale and the render cost is O(changed)."""
-        from .deltasweep import NeedsFullSweep
+        Per-constraint result reuse (st.render_cache, one RenderEntry per
+        constraint): an entry is keyed on what the walk READ — the cap,
+        the candidate rows it visited in order up to the one at which
+        the cap was reached, their pack row generations, and whether a
+        further candidate stood behind the cap.  The kept slice is a
+        pure function of those (for a template that reads no inventory,
+        or whose every inventory read is a join plan: the join index
+        bumps the reader rows' generations), so a hit replays the
+        identical Result objects.  The candidate count n_cand is NOT
+        part of the key: churn anywhere in the cluster moves it for
+        almost every constraint while the walked prefix stands, and it
+        decides only the reported total, which a hit recomputes from the
+        current count (_capped_total).  Only a walk that consumed every
+        candidate also keys on the count: a candidate appended after its
+        last row would extend it.  Render cost is O(churn inside the
+        walked prefixes), not O(constraints whose count moved)."""
+        from .deltasweep import NeedsFullSweep, RenderEntry, row_gens
 
         import time as _time
 
@@ -4176,13 +4202,14 @@ class TpuDriver(InterpDriver):
             self._render_memo.clear()
             self._render_memo_epoch = self._cs_epoch
         reuse = st.render_cache if trace is None else {}
-        new_cache: Dict[Tuple, Tuple] = {}
+        new_cache: Dict[Tuple, RenderEntry] = {}
         inventory = self._inventory_for_render()
         rowviews: Dict[int, object] = {}
         results: List[Result] = []
         totals: Dict[Tuple[str, str], Tuple[int, str]] = {}
         R = len(reviews)
         rendered_cells = 0
+        render_reused = 0
         fallback_rows = 0
         fallback_bytes = 0
         tiers0 = dict(self._tier_counts)
@@ -4291,53 +4318,53 @@ class TpuDriver(InterpDriver):
                         kind, sorted(join_rows), inventory,
                     )
                     join_inv_by_kind[kind] = join_inv
-            lst = st.cand[ci]
-            sig = None
-            if trace is None and not uses_inv and len(lst) <= 512:
-                # unchanged candidates + row generations (and the same cap)
-                # render identically; cap is per-call, so it keys the entry
-                sig = (
-                    cap, n_cand, tuple(lst),
-                    tuple(ap.row_gen[r] for r in lst if r < R),
-                )
+            # traced calls and templates that read the whole inventory
+            # bypass the reuse
+            cacheable = trace is None and not uses_inv
+            if cacheable:
                 hit = reuse.get(ckey)
-                if hit is not None and hit[0] == sig:
-                    results.extend(hit[1])
-                    totals[ckey] = hit[2]
+                # an entry of any other shape (a snapshot an older
+                # process wrote) is a miss: re-rendered and replaced
+                if isinstance(hit, RenderEntry) and hit.serves(
+                        cap, st.cand[ci], n_cand, ap.row_gen):
+                    results.extend(hit.results)
+                    totals[ckey] = self._capped_total(
+                        kind, constraint, hit.capped, n_cand,
+                        len(hit.results),
+                    )
                     new_cache[ckey] = hit
+                    render_reused += 1
                     if cost_on:
                         # wholesale render-cache reuse: zero cells walked,
                         # one memo hit, the cached violations replayed
                         cost_entries.append((
-                            kind, name, 0, "interp", len(hit[1]), 1,
+                            kind, name, 0, "interp", len(hit.results), 1,
                         ))
                     continue
             action = self._enforcement_action(constraint)
             start = len(results)
             r_start = rendered_cells
             capped = False
+            walked: List[int] = []
             for ri in candidates(ci, n_cand):
                 if len(results) - start >= cap:
                     capped = True
                     break
+                walked.append(ri)
                 if ri >= R or reviews[ri] is None:
                     continue  # tombstoned row (valid=False on device too)
                 render(ri, kind, name, constraint, uses_inv, action,
                        join_strict=join_strict,
                        inv=join_inv if ri in join_rows else None)
                 rendered_cells += 1
-            if not capped:
-                totals[ckey] = (len(results) - start, "exact")
-            elif self._count_exact(kind, constraint):
-                # device count == violation count, provably: report the
-                # full total past the cap (manager.go:188 semantics)
-                totals[ckey] = (n_cand, "exact")
-            else:
-                totals[ckey] = (
-                    max(n_cand, len(results) - start), "resources"
+            totals[ckey] = self._capped_total(
+                kind, constraint, capped, n_cand, len(results) - start,
+            )
+            if cacheable:
+                new_cache[ckey] = RenderEntry(
+                    cap, tuple(walked), row_gens(ap.row_gen, walked),
+                    capped, n_cand, tuple(results[start:]),
                 )
-            if sig is not None:
-                new_cache[ckey] = (sig, tuple(results[start:]), totals[ckey])
             if cost_on:
                 plan = self._render_plan_for(kind, name, constraint)
                 cost_entries.append((
@@ -4354,13 +4381,14 @@ class TpuDriver(InterpDriver):
         obstrace.record_span(
             "audit.render", t0, _time.perf_counter(),
             stage=obstrace.RENDER, tier="tpu",
-            rendered_cells=rendered_cells,
+            rendered_cells=rendered_cells, render_reused=render_reused,
             plan_static=tiers["static"], plan_slots=tiers["slots"],
             plan_interp=tiers["interp"],
         )
         self.last_sweep_stats.update(
             render_ms=(_time.perf_counter() - t0) * 1e3,
             rendered_cells=float(rendered_cells),
+            render_reused=float(render_reused),
             render_plan_cells=float(tiers["static"] + tiers["slots"]),
             render_interp_cells=float(tiers["interp"]),
             fallback_rows=float(fallback_rows),

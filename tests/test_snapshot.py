@@ -132,6 +132,52 @@ class TestRoundTrip:
         ns = client2.driver.store.cached_namespace("ns-000")
         assert ns is None or isinstance(ns, dict)
 
+    def test_render_cache_of_an_older_shape_restores_as_misses(
+            self, snap_dir):
+        """A basis whose render_cache holds entries of the shape before
+        ISSUE 32 — (signature, results, total), keyed on the cluster-wide
+        count and all known candidates — restores; no entry is read as
+        the new shape: the first sweep re-renders every constraint,
+        answers as the interpreter does, and leaves RenderEntry rows the
+        second sweep is served from."""
+        from gatekeeper_tpu.ops.deltasweep import RenderEntry
+
+        kube = build_cluster(n=12)
+        client1 = make_client(kube)
+        cold_sig, cold_totals = audit_sig(client1)
+        st = client1.driver._delta_state
+        assert st.render_cache
+        for ckey, e in list(st.render_cache.items()):
+            assert isinstance(e, RenderEntry)
+            total = (len(e.results), "exact")
+            st.render_cache[ckey] = (
+                (e.cap, e.n_cand, e.walked, e.gens), e.results, total)
+        assert Snapshotter(client1, snap_dir, interval_s=0.0).write_once()
+
+        client2 = fresh_client()
+        loader = SnapshotLoader(snap_dir)
+        assert loader.restore(client2, kube) == "restored"
+        assert loader.delta_restored is True
+        restored = client2.driver._delta_state.render_cache
+        assert restored and all(
+            type(e) is tuple and len(e) == 3 for e in restored.values())
+        warm_sig, warm_totals = audit_sig(client2)
+        stats = client2.driver.last_sweep_stats
+        assert stats["render_reused"] == 0.0
+        assert stats["rendered_cells"] == len(cold_sig) > 0
+        oracle = Client()
+        oracle.add_template(TEMPLATE)
+        oracle.add_constraint(CONSTRAINT)
+        for obj in kube.list(("", "v1", "Namespace")):
+            oracle.add_data(obj)
+        assert (warm_sig, warm_totals) == audit_sig(oracle)
+        assert (warm_sig, warm_totals) == (cold_sig, cold_totals)
+        cache = client2.driver._delta_state.render_cache
+        assert all(isinstance(e, RenderEntry) for e in cache.values())
+        assert audit_sig(client2) == (warm_sig, warm_totals)
+        assert client2.driver.last_sweep_stats["render_reused"] == len(cache)
+        assert client2.driver.last_sweep_stats["rendered_cells"] == 0.0
+
     def test_delta_basis_dropped_on_mesh_width_drift(self, snap_dir):
         """A basis persisted under one sweep sharding layout must not
         serve a process whose mesh width differs: the restore keeps the
