@@ -597,10 +597,12 @@ class App:
         self.snapshotter = None
         self.snapshot_restore_outcome = "none"
         self._stopping = False
+        self._join_warm: Optional[threading.Event] = None
 
     def start(self):
         args = self.args
         self._stopping = False  # a stopped App may be restarted
+        self._join_warm = None
         from .ops.deltasweep import BG_STOP
 
         BG_STOP.clear()  # re-arm background workers after a stop()
@@ -775,7 +777,7 @@ class App:
                 port=args.port,
                 certfile=certfile,
                 keyfile=keyfile,
-                readiness_check=self.tracker.satisfied,
+                readiness_check=self._admission_ready,
                 deadline_budget_s=(budget_ms / 1000.0) or None,
                 health_status=health_status,
             )
@@ -917,6 +919,37 @@ class App:
                 "replica_id": replica_id(),
             }},
         )
+
+    def _admission_ready(self) -> bool:
+        """/readyz of a pod that serves admissions: the tracker's
+        expectations met, and for a referential bundle the join index
+        built from what that sync brought (TpuDriver.warm_join_index,
+        once, on a background thread): the first Service review of a
+        cold webhook-only pod must not pack the cluster under the
+        driver lock.  A warm resume brought the index, so the build
+        finds nothing to do."""
+        if not self.tracker.satisfied():
+            return False
+        if self._join_warm is None:
+            self._join_warm = done = threading.Event()
+            driver = self.client.driver
+            warm = getattr(driver, "warm_join_index", None)
+            if warm is None or not driver.join_plan_shapes():
+                done.set()  # nothing to build: ready on this very probe
+            else:
+                from .ops.deltasweep import spawn_bg
+
+                def run():
+                    try:
+                        warm()
+                    except Exception:
+                        # the first referential review builds it then
+                        log.exception("join index warm-up failed")
+                    finally:
+                        done.set()
+
+                spawn_bg("gk-join-warm", run)
+        return self._join_warm.is_set()
 
     def _start_routing_calibration(self):
         """Background startup calibration of the driver's interp-vs-device

@@ -371,6 +371,29 @@ JOIN_DIVERGENCE_M = Measure(
     "was empty (interned-key/aggregate divergence; raises under "
     "GK_JOIN_ASSERT=1)",
 )
+ADMISSION_JOIN_CELLS_M = Measure(
+    "admission_join_cells",
+    "Referential cells of the review path — (constraint of a template "
+    "that reads data.inventory, review) pairs flagged by the mask or "
+    "matched by the interpreter walk — by outcome: index (resolved from "
+    "the join index, ops/joinreview.py) or fallback (the full inventory "
+    "handed to the interpreter), and by whether the interpreter rendered "
+    "the cell (rendered=no: the index proved it cannot raise)",
+)
+ADMISSION_JOIN_ROWS_M = Measure(
+    "admission_join_render_rows",
+    "Provider rows in the pruned inventories the review path handed to "
+    "the interpreter for its index-resolved cells: over "
+    "admission_join_cells_total{outcome=\"index\",rendered=\"yes\"} it "
+    "is a key group's size",
+)
+JOIN_UPKEEP_M = Measure(
+    "join_index_upkeep_seconds",
+    "Seconds spent bringing the join index current, by trigger: sweep "
+    "(a sweep's join_commit stage) or write (outside a sweep: the review "
+    "path folding in the writes since the index was last current)",
+    unit="s",
+)
 COMPILE_LAG_M = Measure(
     "compile_epoch_lag",
     "Constraint-side mutation epochs the async background compiler is "
@@ -727,6 +750,12 @@ def catalog_views():
         View("join_plans", JOIN_PLANS_M, AGG_LAST_VALUE),
         View("join_delta_affected_rows_total", JOIN_AFFECTED_M, AGG_COUNT),
         View("join_plan_divergence_total", JOIN_DIVERGENCE_M, AGG_COUNT),
+        View("admission_join_cells_total", ADMISSION_JOIN_CELLS_M, AGG_COUNT,
+             tag_keys=("outcome", "rendered")),
+        View("admission_join_render_rows_total", ADMISSION_JOIN_ROWS_M,
+             AGG_COUNT),
+        View("join_index_upkeep_seconds_total", JOIN_UPKEEP_M, AGG_SUM,
+             tag_keys=("trigger",)),
         View("compile_epoch_lag", COMPILE_LAG_M, AGG_LAST_VALUE),
         View("device_bytes", DEVICE_BYTES_M, AGG_LAST_VALUE,
              tag_keys=("component",)),
@@ -1305,6 +1334,36 @@ def record_join_divergence(kind: str):
         )
     except Exception:  # telemetry never blocks rendering
         record_dropped("record_join_divergence")
+
+
+def record_admission_join(cleared: int, rendered: int, fallback: int,
+                          rows: int):
+    """One admission batch's referential cells (ops/joinreview.py):
+    admission_join_cells_total{outcome,rendered} and
+    admission_join_render_rows_total."""
+    try:
+        reg = _global()
+        for n, tags in (
+            (cleared, {"outcome": "index", "rendered": "no"}),
+            (rendered, {"outcome": "index", "rendered": "yes"}),
+            (fallback, {"outcome": "fallback", "rendered": "yes"}),
+        ):
+            if n:
+                reg.record(ADMISSION_JOIN_CELLS_M, float(n), tags,
+                           count=int(n))
+        if rows:
+            reg.record(ADMISSION_JOIN_ROWS_M, float(rows), count=int(rows))
+    except Exception:  # telemetry never blocks a review
+        record_dropped("record_admission_join")
+
+
+def record_join_upkeep(trigger: str, seconds: float):
+    """The join index brought current
+    (join_index_upkeep_seconds_total{trigger})."""
+    try:
+        _global().record(JOIN_UPKEEP_M, seconds, {"trigger": trigger})
+    except Exception:  # telemetry never blocks a sweep or a review
+        record_dropped("record_join_upkeep")
 
 
 def record_compile_lag(lag: int):

@@ -129,6 +129,10 @@ class AuditPackCache:
         # above, so neither consumer starves the other and the delta path
         # never rescans cumulative churn (advisor r3)
         self.delta_dirty: set = set()
+        # third channel, drained by the join index (ops/joinkernel.py
+        # JoinState.commit) wherever it is brought current: a sweep's
+        # join_commit, or the review path between sweeps
+        self.join_dirty: set = set()
         # rows packed (batch re-packs and rebuilds) since the last
         # take_packed_rows(): the driver publishes it as `pack_rows`
         self.packed_rows = 0
@@ -165,6 +169,7 @@ class AuditPackCache:
         self.synced_epoch = synced_epoch
         self.dirty = set()
         self.delta_dirty = set()
+        self.join_dirty = set()
         self.layout_gen += 1
         self.rebuild_gen += 1
 
@@ -186,6 +191,11 @@ class AuditPackCache:
     def take_delta_dirty(self) -> set:
         d = self.delta_dirty
         self.delta_dirty = set()
+        return d
+
+    def take_join_dirty(self) -> set:
+        d = self.join_dirty
+        self.join_dirty = set()
         return d
 
     def take_packed_rows(self) -> int:
@@ -289,6 +299,7 @@ class AuditPackCache:
         self.synced_epoch = store.epoch
         self.dirty = set()
         self.delta_dirty = set()
+        self.join_dirty = set()
         self.layout_gen += 1
         self.rebuild_gen += 1
         self.packed_rows += len(reviews)
@@ -336,6 +347,7 @@ class AuditPackCache:
         self.row_gen[row] = self._gen
         self.dirty.add(row)
         self.delta_dirty.add(row)
+        self.join_dirty.add(row)
         self.free.append(row)
 
     def _alloc_row(self) -> int:
@@ -418,4 +430,5 @@ class AuditPackCache:
             self.row_gen[r] = self._gen
         self.dirty.update(rows)
         self.delta_dirty.update(rows)
+        self.join_dirty.update(rows)
         self.packed_rows += len(rows)

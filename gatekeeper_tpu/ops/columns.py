@@ -143,6 +143,30 @@ def _encode(values: List[Any], interner: Interner, shape) -> Dict[str, np.ndarra
     }
 
 
+def joinkey_values(resource, spec: "ColumnSpec") -> List[Any]:
+    """The raw join-key values one resource yields under a joinkey spec,
+    before normalization: one per iterated entity for a slot key
+    (``_ABSENT`` where the entity lacks the path), one or none for a
+    scalar key, exactly one for a computed key (an absent map flattens
+    to the empty string, as the Rego's does).  The packed column
+    (:func:`_extract_joinkey`) and the admission path's index lookup
+    (joinkernel.JoinState.review_lookup) both read keys through this."""
+    if not spec.iter_paths:
+        hits: List[Any] = []
+        _walk(resource, spec.rel_path, 0, hits)
+        if spec.form:
+            from .joinkernel import join_pairs
+
+            return [join_pairs(hits[0] if hits else None, *spec.form[1:])]
+        return hits[:1]
+    if spec.form:
+        raise ValueError("computed join keys are scalar")
+    ents: List[Any] = []
+    for p in spec.iter_paths:
+        _walk(resource, p, 0, ents)
+    return [_get_rel(ent, spec.rel_path) for ent in ents]
+
+
 def _extract_joinkey(
     resources, spec: "ColumnSpec", interner: Interner, rows: int
 ) -> Dict[str, np.ndarray]:
@@ -153,38 +177,21 @@ def _extract_joinkey(
     {"sid" [R]}; slot keys (iteration paths) -> {"sid", "mask"} [R, S]
     with the slot width bucketed exactly like slot columns over the same
     iteration group (shared axes stay aligned)."""
-    from .joinkernel import UNKNOWN_KEY, intern_join_key, join_pairs
+    from .joinkernel import intern_join_key
 
     if not spec.iter_paths:  # scalar key
         sid = np.full(rows, Interner.MISSING, np.int32)
         for i, r in enumerate(resources):
-            hits: List[Any] = []
-            _walk(r, spec.rel_path, 0, hits)
-            if spec.form:
-                # a computed key is defined on every row (an absent map
-                # flattens to the empty string, as the Rego's does)
-                sid[i] = intern_join_key(
-                    join_pairs(hits[0] if hits else None, *spec.form[1:]),
-                    interner,
-                )
-            elif hits:
-                sid[i] = intern_join_key(hits[0], interner)
+            for v in joinkey_values(r, spec):
+                sid[i] = intern_join_key(v, interner)
         return {"sid": sid}
-    if spec.form:
-        raise ValueError("computed join keys are scalar")
-    ents: List[List[Any]] = []
-    for r in resources:
-        hits: List[Any] = []
-        for p in spec.iter_paths:
-            _walk(r, p, 0, hits)
-        ents.append(hits)
+    ents = [joinkey_values(r, spec) for r in resources]
     width = _bucket(max((len(e) for e in ents), default=0), 1)
     sid = np.full((rows, width), Interner.MISSING, np.int32)
     mask = np.zeros((rows, width), bool)
     for i, row_ents in enumerate(ents):
-        for j, ent in enumerate(row_ents):
+        for j, v in enumerate(row_ents):
             mask[i, j] = True
-            v = _get_rel(ent, spec.rel_path)
             if v is not _ABSENT:
                 sid[i, j] = intern_join_key(v, interner)
     return {"sid": sid, "mask": mask}

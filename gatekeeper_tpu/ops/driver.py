@@ -34,6 +34,7 @@ from ..metrics.catalog import (
     DISPATCH_M,
     PACK_M,
     record_cache,
+    record_join_upkeep,
     record_render_cells,
     record_stage,
 )
@@ -48,6 +49,7 @@ from ..client.drivers import (
 )
 from ..target.match import constraint_matches, needs_autoreject
 from ..target.target import K8sValidationTarget
+from . import joinreview
 from .columns import extract_columns
 from .interning import Interner, PredicateTable
 from .matchkernel import match_kernel
@@ -265,7 +267,7 @@ class TpuDriver(InterpDriver):
         self.render_plan_enabled = os.environ.get("GK_RENDER_PLAN", "1") != "0"
         self._bound_plans: Dict[Tuple[str, str], object] = {}
         self._bound_plans_epoch = -1
-        self._uses_inventory_cache: Optional[Tuple[int, bool]] = None
+        self._uses_inventory_cache: Optional[Tuple[int, frozenset]] = None
         self._n_constraints_cache: Optional[Tuple[int, int]] = None
         # per-template constraint counts for the cost ledger's dispatch
         # apportioning (obs/costs.py), cached per constraint-side epoch —
@@ -984,6 +986,7 @@ class TpuDriver(InterpDriver):
                 from ..metrics.catalog import set_join_plans
 
                 set_join_plans(0)
+            ap.join_dirty.clear()  # nobody to drain it
             return None
         from .joinkernel import JoinState
 
@@ -995,12 +998,70 @@ class TpuDriver(InterpDriver):
             js = JoinState(plans, ap.rebuild_gen)
             self._join_state = js
         bump = js.rebuild(ap, self.interner)
+        ap.join_dirty.clear()  # the rebuild read every row
         if bump:
             ap.bump_row_gen(bump)
         from ..metrics.catalog import set_join_plans
 
         set_join_plans(len(plans))
         return js
+
+    def _join_index_current(self):
+        """The join index brought current with the store OUTSIDE a sweep
+        (the review path, before a batch that holds a referential cell;
+        caller holds the lock), or None without join plans.  A
+        webhook-only replica never sweeps, so the write that moved a
+        provider is folded in here: the pack re-packs the rows the
+        store's change log names, the index commits them (O(changed
+        rows)); an index that is missing, of another plan set or of
+        another pack generation is rebuilt.  Readers whose key group
+        moved are left ``pending`` for the next delta sweep, which
+        therefore does not do this work again."""
+        plans = self._active_join_plans()
+        if not plans:
+            return None
+        ap = self._audit_pack
+        js = self._join_state
+        sig = tuple(p.sig for p in plans)
+        fresh = (
+            js is not None and js.built and js.sig == sig
+            and js.rebuild_gen == ap.rebuild_gen
+        )
+        if (fresh and ap.rp is not None and not ap.join_dirty
+                and ap.synced_epoch == self.store.epoch):
+            return js
+        import time as _time
+
+        t0 = _time.perf_counter()
+        ap.sync(self, self._constraint_side()[3])
+        if fresh and js.rebuild_gen == ap.rebuild_gen:
+            dirty = ap.take_join_dirty()
+            if dirty:
+                js.commit(ap, self.interner, dirty, sweep=False)
+        else:
+            js = self._ensure_join_state()
+        record_join_upkeep("write", _time.perf_counter() - t0)
+        return js
+
+    def warm_join_index(self) -> bool:
+        """Build the join index where the data arrives (the loader at
+        the end of a restore, a serving pod before it reports ready:
+        main.App._admission_ready) -> True where the bundle holds join
+        plans.  The review path then only folds in the rows written
+        since (``join_dirty``); a replica that holds no pack would
+        otherwise pack the whole cluster and build the index under this
+        lock in its first referential review, every other review
+        waiting behind it."""
+        with self._lock:
+            return self._join_index_current() is not None
+
+    def _review_joins(self, reviews, inventory):
+        """This batch's join binding (ops/joinreview.py), or None for a
+        bundle no template of which reads the inventory (the per-epoch
+        set of _inventory_kinds)."""
+        if not self._inventory_kinds():
+            return None
+        return joinreview.ReviewJoins(self, reviews, inventory)
 
     def _join_safe(self, kind: str) -> bool:
         """True when a referential template's rendered results are
@@ -1091,8 +1152,6 @@ class TpuDriver(InterpDriver):
         plans = getattr(prog, "join_plans", ()) or ()
         if not plans:
             return None
-        ap = self._audit_pack
-        reviews = ap.reviews
         by_sig = {p.sig: i for i, p in enumerate(js.plans)}
         provider_rows: set = set()
         for plan in plans:
@@ -1106,6 +1165,13 @@ class TpuDriver(InterpDriver):
                 keys.update(row_rkeys.get(int(r), ()))
             for k in keys:
                 provider_rows |= providers.get(k, set())
+        return self._inventory_of_rows(provider_rows)
+
+    def _inventory_of_rows(self, provider_rows) -> Optional[object]:
+        """The frozen inventory tree that holds exactly the objects of
+        these pack rows (a key group's providers), or None where a row
+        lies outside the pack."""
+        reviews = self._audit_pack.reviews
         tree: Dict[str, dict] = {}
         for ri in sorted(provider_rows):
             if ri >= len(reviews):
@@ -1862,20 +1928,25 @@ class TpuDriver(InterpDriver):
         scan is cached per constraint-side epoch: it ran per np-served
         review, which at 500 installed templates was a measurable slice
         of the admission path."""
-        cached = self._uses_inventory_cache
-        if cached is not None and cached[0] == self._cs_epoch:
-            uses = cached[1]
-        else:
-            uses = any(
-                getattr(t.policy, "uses_inventory", True)
-                for t in self.templates.values()
-            )
-            self._uses_inventory_cache = (self._cs_epoch, uses)
+        uses = bool(self._inventory_kinds())
         if uses:
             return self.store.frozen()
         from ..engine.value import freeze
 
         return freeze({})
+
+    def _inventory_kinds(self) -> frozenset:
+        """The kinds of the installed templates that read
+        data.inventory.  Cached per constraint-side epoch."""
+        cached = self._uses_inventory_cache
+        if cached is not None and cached[0] == self._cs_epoch:
+            return cached[1]
+        kinds = frozenset(
+            kind for kind, t in self.templates.items()
+            if getattr(t.policy, "uses_inventory", True)
+        )
+        self._uses_inventory_cache = (self._cs_epoch, kinds)
+        return kinds
 
     def _interp_review_memo(self, review: dict, memo_key=None):
         """InterpDriver.review semantics served through the content-keyed
@@ -1915,6 +1986,7 @@ class TpuDriver(InterpDriver):
 
             rowview = RowView(review, frozen_review)
             results: List[Result] = []
+            joins = self._review_joins((review,), inventory)
             for kind, name, constraint in self._gvk_walk_list(review):
                 if needs_autoreject(constraint, review, cached_ns):
                     results.append(
@@ -1928,14 +2000,32 @@ class TpuDriver(InterpDriver):
                             ),
                         )
                     )
+                cell_inv = inventory
+                if joins is not None and joins.referential(kind):
+                    # a referential cell resolves through the join index
+                    # (ops/joinreview.py): cleared, or rendered against
+                    # its key group; only a matching constraint is a cell
+                    if not constraint_matches(constraint, review,
+                                              cached_ns):
+                        continue
+                    clock = obstrace.running_clock(obstrace.PATH_BATCH)
+                    was = clock.stage
+                    clock.mark("join_lookup")
+                    cell_inv = joins.resolve(kind, 0)
+                    if was is not None:
+                        clock.mark(was)
+                    if cell_inv is joinreview.CLEARED:
+                        continue
                 # _render_cell re-checks the match and returns nothing
                 # for non-matching constraints or missing templates —
                 # identical semantics to the oracle's walk
                 self._render_cell(
                     results, constraint, kind, review, frozen_review,
-                    inventory, None, memo_review=memo_review,
+                    cell_inv, None, memo_review=memo_review,
                     rowview=rowview,
                 )
+            if joins is not None:
+                joins.flush()
             if memoable:
                 self._store_request_memo(review, results, memo_review)
             self._flush_render_counts()
@@ -2545,13 +2635,23 @@ class TpuDriver(InterpDriver):
                     return self._review_batch_traced(
                         reviews, ordered, mask_np, rej_np, inventory
                     )
+                joins = self._review_joins(reviews, inventory)
+                join_inv = None
+                if joins is not None:
+                    # referential cells: exact mask bits and pruned
+                    # inventories from the join index
+                    clock.mark("join_lookup")
+                    join_inv = joins.refine(ordered, mask_np)
                 clock.mark("render")
                 with obstrace.span("render", stage=obstrace.RENDER,
                                    tier="tpu"):
                     out = self._render_masked(
                         reviews, ordered, mask_np, rej_np, inventory,
-                        memo_keys=memo_reviews,
+                        memo_keys=memo_reviews, joins=joins,
+                        join_inv=join_inv,
                     )
+                if joins is not None:
+                    joins.flush()
                 clock.mark("account")  # request-memo stores
                 # admission-sized batches feed the request memo from the
                 # device path too, so repeat content (replica/retry
@@ -2620,7 +2720,7 @@ class TpuDriver(InterpDriver):
             ]
 
     def _render_masked(self, reviews, ordered, mask_np, rej_np, inventory,
-                       memo_keys=None):
+                       memo_keys=None, joins=None, join_inv=None):
         """Bulk sparse render shared by the device and host (numpy) mask
         paths: iterate only (review, constraint) cells the mask marked
         positive, review-major so per-review result ordering matches the
@@ -2738,16 +2838,22 @@ class TpuDriver(InterpDriver):
             deferred.append((idx, ri, i, mkey))
         t1 = _time.perf_counter()
         if deferred:
+            # a referential cell renders against the pruned inventory of
+            # its key group (joins.refine), every other against the whole
             thunks = [
                 (lambda c=ordered[i][2], k=ordered[i][0], r=reviews[ri],
-                        f=rows[ri].frozen():
-                 self._eval_cell(c, k, r, f, inventory,
+                        f=rows[ri].frozen(),
+                        inv=(join_inv.get((ri, i), inventory)
+                             if join_inv else inventory):
+                 self._eval_cell(c, k, r, f, inv,
                                  allow_plan=False, count=False))
                 for _idx, ri, i, _mkey in deferred
             ]
             evaled = RenderPool.map_ordered(thunks)
             self._tier_counts["interp"] += len(deferred)
-            for (idx, _ri, i, mkey), violations in zip(deferred, evaled):
+            for (idx, ri, i, mkey), violations in zip(deferred, evaled):
+                if join_inv and not violations and (ri, i) in join_inv:
+                    joins.note_empty(*ordered[i], ri)
                 resolved[idx] = violations
                 if cost_on and violations:
                     attv[i] = attv.get(i, 0) + len(violations)
@@ -2864,12 +2970,22 @@ class TpuDriver(InterpDriver):
                 )
             ordered, mask, rej = got
             inventory = self._inventory_for_render()
+            joins = self._review_joins(reviews, inventory)
+            join_inv = None
+            if joins is not None:
+                clock.mark("join_lookup")
+                mask = np.array(mask, dtype=bool)  # refine() clears bits
+                join_inv = joins.refine(ordered, mask)
+                clock.mark("render")
             with obstrace.span("render", stage=obstrace.RENDER,
                                tier="numpy"):
                 out = self._render_masked(
                     reviews, ordered, mask, rej, inventory,
-                    memo_keys=memo_reviews,
+                    memo_keys=memo_reviews, joins=joins,
+                    join_inv=join_inv,
                 )
+            if joins is not None:
+                joins.flush()
             clock.mark("account")  # request-memo stores
             if (
                 len(reviews) <= self.REQUEST_MEMO_BATCH_MAX
@@ -3322,6 +3438,8 @@ class TpuDriver(InterpDriver):
         t_join = clock.mark("join_commit") \
             if self._active_join_plans() else None
         self._ensure_join_state()
+        if t_join is not None:
+            record_join_upkeep("sweep", _time.perf_counter() - t_join)
         jargs = self._join_trace_args()
         mesh = self._mesh()
         t1 = clock.mark("enqueue")  # dirty-row scatter, placement, launch
@@ -3909,7 +4027,10 @@ class TpuDriver(InterpDriver):
             ):
                 return None
             t_aff = clock.mark("join_affected")
-            affected = js.affected(ap, self.interner, ap.delta_dirty)
+            # with the readers a commit outside a sweep left pending
+            affected = js.affected(ap, self.interner, ap.delta_dirty) | (
+                js.pending - ap.delta_dirty
+            )
             join_affected_s = clock.mark("pack") - t_aff
             if len(ap.delta_dirty) + len(affected) > self.DELTA_MAX_ROWS:
                 return None
@@ -3942,6 +4063,7 @@ class TpuDriver(InterpDriver):
         # point must invalidate the state (the caller then runs a full
         # sweep, which rebases knowledge and clears both dirty channels)
         rows = sorted(ap.take_delta_dirty())
+        ap.join_dirty.clear()  # a subset of `rows`, committed below
         join_rows = 0
         if js is not None:
             # commit the churn to the join index: updates provider/reader
@@ -3950,6 +4072,7 @@ class TpuDriver(InterpDriver):
             # the stage stays open over _apply_delta's delta_tables
             t_commit = clock.mark("join_commit")
             extra = js.commit(ap, self.interner, rows)
+            record_join_upkeep("sweep", _time.perf_counter() - t_commit)
             if extra:
                 join_rows = len(extra)
                 rows = sorted(set(rows) | extra)

@@ -45,6 +45,12 @@ class Interner:
                 self._strings.append(s)
             return i
 
+    def find(self, s: str) -> int:
+        """The id of an already-interned string, MISSING otherwise: a
+        lookup that must not grow the vocabulary (admission-time join
+        keys — a key nobody interned is a key no inventory row holds)."""
+        return self._ids.get(s, self.MISSING)
+
     def intern_value(self, v) -> int:
         """Intern strings; map non-strings to sentinels so id-equality stays
         sound (two equal strings share an id; a non-string never equals)."""

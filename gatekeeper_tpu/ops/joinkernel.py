@@ -40,9 +40,13 @@ This module keeps referential templates inside the vectorized sweep:
   the snapshot sweep basis (gatekeeper_tpu/snapshot/) so warm restores
   keep the delta path; plan drift drops the basis for a rebase.
 
-Soundness: a JoinCmp in the REVIEW path (admission batches — no inventory
-on the device) resolves to its polarity's ``unknown_default`` and the
-interpreter render filters, exactly like an unclassified template.  On the
+Soundness: a JoinCmp inside the REVIEW path's mask executables (admission
+batches — no inventory on the device) resolves to its polarity's
+``unknown_default``; the host then resolves every flagged cell of a
+join-safe template from this index (:meth:`JoinState.review_lookup`,
+ops/joinreview.py): exactly false cells are cleared, the rest render
+against the pruned inventory of their key group, and what cannot be
+proven falls back to the interpreter on the full inventory.  On the
 AUDIT path the plan is exact modulo one documented corner (two inventory
 objects of the same kind/namespace/name under different groupVersions count
 as two provider rows where the reference's ``identical`` helper sees one) —
@@ -57,6 +61,7 @@ applied to the referential tier.  See docs/referential.md.
 from __future__ import annotations
 
 import json
+import operator
 import os
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -430,6 +435,22 @@ def _row_identity(ap, row: int) -> Optional[Tuple]:
             meta.get("namespace"), meta.get("name"))
 
 
+@dataclass(frozen=True)
+class ReviewJoin:
+    """What the index says of one admission review under one join-safe
+    program (JoinState.review_lookup): the provider rows of the review
+    object's keys — the whole inventory its render can read — and the
+    exact value of the program's join conditions where it could be
+    decided (None: the interpreter decides)."""
+
+    rows: Tuple[int, ...]
+    verdict: Optional[bool]
+
+
+_CMP = {"==": operator.eq, "!=": operator.ne, "<": operator.lt,
+        "<=": operator.le, ">": operator.gt, ">=": operator.ge}
+
+
 class JoinState:
     """The join-group index: per plan, key -> provider rows (drives the
     aggregate) and key -> reader rows (rows whose verdict/message reads
@@ -465,6 +486,12 @@ class JoinState:
         self.row_ident: List[Dict[int, Tuple]] = [
             {} for _ in range(n)
         ]
+        # reader rows whose key group a commit OUTSIDE a sweep changed
+        # (the review path bringing the index current after a write):
+        # their generations are bumped at once, but the sweep's mask
+        # still holds their old verdict, so the next delta sweep takes
+        # them with its own affected rows; a rebuild drops them
+        self.pending: set = set()
 
     # ---- build / diff ------------------------------------------------------
 
@@ -522,6 +549,7 @@ class JoinState:
             self.row_pkeys[i] = new_rowp
             self.row_rkeys[i] = new_rowr
         self.built = True
+        self.pending = set()
         return bump
 
     # ---- delta -------------------------------------------------------------
@@ -555,10 +583,15 @@ class JoinState:
             return old | new, old, new, ident
         return old ^ new, old, new, ident
 
-    def commit(self, ap, interner: Interner, dirty) -> set:
+    def commit(self, ap, interner: Interner, dirty,
+               sweep: bool = True) -> set:
         """Apply a churn batch to the index; returns the affected reader
         rows (beyond the dirty set) and bumps their pack row generations
-        so stale rendered results cannot be reused."""
+        so stale rendered results cannot be reused.  A sweep's commit
+        also returns (and forgets) the readers that commits outside a
+        sweep left ``pending``; a commit outside a sweep (``sweep``
+        False: the review path after a write) adds its readers to
+        them."""
         out: set = set()
         dirty = set(dirty)
         for i, plan in enumerate(self.plans):
@@ -610,7 +643,128 @@ class JoinState:
         out -= dirty
         if out:
             ap.bump_row_gen(out)
+        if not sweep:
+            self.pending |= out
+        elif self.pending:
+            out |= self.pending - dirty
+            self.pending = set()
         return out
+
+    # ---- admission ---------------------------------------------------------
+
+    def review_lookup(self, prog, review: dict, ap,
+                      interner: Interner) -> Optional["ReviewJoin"]:
+        """Resolve one admission review against the index: for every
+        join plan of ``prog`` (a join-safe program: each of its inventory
+        reads is a classified plan) the review object's own keys — the
+        plan's local column read off the review (columns.joinkey_values),
+        the one normalize_join_key — and the provider rows that hold
+        them.  None where a key cannot be normalized (UNKNOWN_KEY) or
+        the index does not know a plan: the caller falls back to the
+        full inventory.
+
+        The verdict of the program's JoinCmp conditions is exact where
+        it can be decided: a clause holding a JoinCmp that is false for
+        every key cannot raise, and a program whose clauses all hold one
+        is exactly False.  The review object itself is dropped from a
+        ``exclude_self`` count by identity, and only where the Rego's
+        ``identical`` helper is sure to agree (one provider row of the
+        review's namespace, name, kind and apiVersion, the request's own
+        name / namespace equal to the object's): anything else leaves
+        the condition undecided, and the interpreter, which is handed
+        these provider rows as its whole inventory, decides."""
+        from .columns import _ABSENT, joinkey_values
+        from .vexpr import JoinCmp
+
+        by_sig = {p.sig: i for i, p in enumerate(self.plans)}
+        specs = {sp.key: sp for sp in prog.column_specs}
+        rows: set = set()
+        per_plan: List[Tuple[int, List[set]]] = []  # (index plan, groups)
+        for plan in prog.join_plans:
+            i = by_sig.get(plan.sig)
+            spec = specs.get(plan.local_colkey)
+            if i is None or spec is None:
+                return None
+            groups: List[set] = []
+            for v in joinkey_values(review, spec):
+                if v is _ABSENT:
+                    continue
+                norm = normalize_join_key(v)
+                if norm is None:
+                    return None
+                got = self.providers[i].get(interner.find(norm), ())
+                groups.append(set(got))
+                rows.update(got)
+            per_plan.append((i, groups))
+        obj = review.get("object")
+        verdict: Optional[bool] = None
+        if isinstance(obj, dict) or hasattr(obj, "get"):
+            me = self._review_self(review, obj)
+            possible, certain = False, True
+            for clause in prog.clauses:
+                vals = [
+                    self._joincmp_value(c, per_plan[c.plan_id], me, ap)
+                    for c in clause.conds if isinstance(c, JoinCmp)
+                ]
+                if not any(v is False for v in vals):
+                    possible = True
+                if not vals or not all(v is True for v in vals):
+                    certain = False
+            verdict = False if not possible else (
+                True if certain else None
+            )
+        return ReviewJoin(tuple(sorted(rows)), verdict)
+
+    @staticmethod
+    def _review_self(review: dict, obj) -> Optional[Tuple]:
+        """(namespace, name, kind, apiVersion) of the reviewed object
+        where the request and the object agree on them, else None (no
+        row can then be told to be the review's own)."""
+        meta = obj.get("metadata") or {}
+        ns, name = meta.get("namespace") or "", meta.get("name")
+        kind = review.get("kind") or {}
+        if (not name or review.get("name") != name
+                or (review.get("namespace") or "") != ns
+                or kind.get("kind") != obj.get("kind")):
+            return None
+        group, version = kind.get("group") or "", kind.get("version")
+        api = f"{group}/{version}" if group else version
+        if api != obj.get("apiVersion"):
+            return None
+        return ns, name, obj.get("kind"), api
+
+    def _joincmp_value(self, node, plan_groups, me, ap) -> Optional[bool]:
+        """One JoinCmp of a review: True / False where exact, None where
+        undecided (no key, a parameter on the right, or a self row that
+        cannot be told apart)."""
+        from .vexpr import Lit
+
+        i, groups = plan_groups
+        rhs = node.rhs
+        if not groups or not isinstance(rhs, Lit) or isinstance(
+            rhs.value, bool
+        ) or not isinstance(rhs.value, (int, float)):
+            return None
+        results = []
+        for got in groups:
+            n = len(got)
+            if node.exclude_self and got:
+                if me is None:
+                    return None
+                own = [r for r in got if self._is_self(i, r, me, ap)]
+                if len(own) > 1:
+                    return None  # groupVersion twins: the Rego decides
+                n -= len(own)
+            results.append(_CMP[node.op](n, rhs.value))
+        return any(results)
+
+    def _is_self(self, i: int, row: int, me: Tuple, ap) -> bool:
+        ident = self.row_ident[i].get(row)
+        if ident is None or (ident[2] or "") != me[0] or ident[3] != me[1]:
+            return False
+        rv = ap.reviews[row] if row < len(ap.reviews) else None
+        o = (rv or {}).get("object") or {}
+        return o.get("kind") == me[2] and o.get("apiVersion") == me[3]
 
     # ---- tables ------------------------------------------------------------
 
@@ -758,16 +912,20 @@ def gv_twin_corner(js: "JoinState", plans, ap, row: int) -> bool:
         except ValueError:
             continue
         for k in js.row_rkeys[i].get(int(row), ()):
-            rows = js.providers[i].get(k, ())
-            idents = set()
-            for r in rows:
-                rv = ap.reviews[r] if r < len(ap.reviews) else None
-                if rv is None:
-                    continue
-                idents.add((rv.get("namespace", ""), rv.get("name", "")))
-            if len(idents) < len(rows):
+            if share_an_identity(ap, js.providers[i].get(k, ())):
                 return True
     return False
+
+
+def share_an_identity(ap, rows) -> bool:
+    """Do two of these provider rows hold one object identity
+    (namespace, name): the groupVersion twins of the corner above?"""
+    idents = set()
+    for r in rows:
+        rv = ap.reviews[r] if r < len(ap.reviews) else None
+        if rv is not None:
+            idents.add((rv.get("namespace", ""), rv.get("name", "")))
+    return len(idents) < len(rows)
 
 
 def note_false_positive(kind: str, name: str, row: int):

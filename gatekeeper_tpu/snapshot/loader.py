@@ -194,6 +194,7 @@ class SnapshotLoader:
             "reviews": reviews,
             "row_gen": row_gen,
             "delta": inv.get("delta"),
+            "join_index": inv.get("join_index"),
         }
 
     # ---- install -----------------------------------------------------------
@@ -427,32 +428,20 @@ class SnapshotLoader:
                         "render cache (first sweep re-renders)"
                     )
                     render_cache = {}
-            # referential policies: rebuild the persisted join-group
-            # index (ops/joinkernel.py).  Plan drift — a template change
-            # reclassifying the join families between writer and reader —
-            # or a missing index drops the WHOLE basis: candidates and
-            # counts were produced by the old aggregates, and the delta
-            # path cannot maintain aggregates it has no index for.
-            plans = ()
-            if hasattr(driver, "_active_join_plans"):
-                plans = driver._active_join_plans()
-            join_state = None
-            if plans:
-                from ..ops.joinkernel import JoinState
-
-                ji = delta.get("join_index")
-                join_state = (
-                    JoinState.restore(tuple(plans), ji, ap.rebuild_gen)
-                    if ji else None
+            # referential policies: the delta path cannot maintain
+            # aggregates it has no index for, and candidates and counts
+            # were produced by the writer's.  _restore_join_index ran
+            # first; where it installed none (plan drift, a snapshot
+            # without one) the WHOLE basis is dropped.
+            if (getattr(driver, "_active_join_plans", tuple)()
+                    and driver._join_state is None):
+                log.warning(
+                    "snapshot delta basis dropped: referential join "
+                    "plans active but the persisted join index is "
+                    "missing or drifted (first sweep will be a full "
+                    "dispatch)"
                 )
-                if join_state is None:
-                    log.warning(
-                        "snapshot delta basis dropped: referential join "
-                        "plans active but the persisted join index is "
-                        "missing or drifted (first sweep will be a full "
-                        "dispatch)"
-                    )
-                    return False
+                return False
             # device upload stays lazy: the first sweep with zero churn
             # never needs the mask at all.  Under a mesh the mask commits
             # row-sharded on "data" (the same-width check above guarantees
@@ -485,9 +474,40 @@ class SnapshotLoader:
                 # so the restored basis carries exactly that topology
                 mesh_width=live_width,
             )
-            if join_state is not None:
-                driver._join_state = join_state
         return True
+
+    @staticmethod
+    def _restore_join_index(client, state: Dict[str, Any]) -> bool:
+        """Install the persisted join-group index (ops/joinkernel.py
+        JoinState) with the inventory: the one installer, run before
+        _restore_delta, which keeps its basis only where an index
+        stands.  The review path resolves referential cells through it
+        (ops/joinreview.py), so a webhook-only replica, which restores
+        a basis it never uses or none, serves its first such review
+        from the index.  Plan drift, or a snapshot without one, installs
+        none: restore() then has the driver build it from the restored
+        pack (TpuDriver.warm_join_index)."""
+        driver = client.driver
+        plans = getattr(driver, "_active_join_plans", tuple)()
+        if not plans:
+            return False
+        # a snapshot written before the index had a place of its own
+        # kept it inside the basis
+        ji = state.get("join_index") or (state.get("delta") or {}).get(
+            "join_index")
+        with driver._lock:
+            from ..ops.joinkernel import JoinState
+
+            driver._join_state = JoinState.restore(
+                tuple(plans), ji, driver._audit_pack.rebuild_gen
+            ) if ji else None
+            if driver._join_state is None:
+                log.warning(
+                    "snapshot join index %s: it is built from the "
+                    "restored pack",
+                    "dropped (the join plans drifted)" if ji else "absent",
+                )
+            return driver._join_state is not None
 
     # ---- the whole restore --------------------------------------------------
 
@@ -544,7 +564,14 @@ class SnapshotLoader:
                             sp.set_attrs(**stats)
                     else:
                         stats = {"resync": "skipped"}
+                    self._restore_join_index(client, state)
                     self.delta_restored = self._restore_delta(client, state)
+                    # an index the snapshot did not bring is built here,
+                    # where the data arrives, not under the lock of the
+                    # first referential review
+                    warm = getattr(client.driver, "warm_join_index", None)
+                    if warm is not None:
+                        warm()
                 except Exception:
                     # any failure past validation may have left partial
                     # state (e.g. adopt_tree landed, adopt_restored did

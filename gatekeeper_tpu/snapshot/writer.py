@@ -104,7 +104,8 @@ class SnapshotWriter:
     # ---- capture ----------------------------------------------------------
 
     @staticmethod
-    def _capture_delta(driver, ap) -> Optional[Dict[str, Any]]:
+    def _capture_delta(driver, ap,
+                       join_index: Optional[dict]) -> Optional[Dict[str, Any]]:
         """The incremental-sweep basis (ops/deltasweep.py DeltaState), when
         it is current: counts, candidate lists, the rendered-result cache,
         and a REFERENCE to the base-mask source.  The mask itself resolves
@@ -131,19 +132,10 @@ class SnapshotWriter:
         # not maintain the aggregates incrementally, so when plans are
         # active and the index is stale the basis is withheld entirely
         # (the restart's first sweep rebases via one full dispatch)
-        join_index = None
-        plans = ()
-        if hasattr(driver, "_active_join_plans"):
-            plans = driver._active_join_plans()
-        if plans:
-            js = getattr(driver, "_join_state", None)
-            if (
-                js is None or not js.built
-                or js.rebuild_gen != ap.rebuild_gen
-                or js.sig != tuple(p.sig for p in plans)
-            ):
-                return None
-            join_index = js.persist()
+        if join_index is None and getattr(
+            driver, "_active_join_plans", tuple
+        )():
+            return None
         # compiled message-plan tiers per constraint: the loader re-binds
         # plans after template replay and validates the classification
         # against this map — a drift (e.g. a plan-compiler change between
@@ -171,14 +163,28 @@ class SnapshotWriter:
             },
             "render_cache": dict(st.render_cache),
             "ordered_keys": ordered_keys,
-            # the join-group index (None for row-local corpora): restores
-            # keep the O(churn) delta path for referential policies; the
-            # loader drops the whole basis on plan drift
-            "join_index": join_index,
             # resolved post-lock; a MaskSource is internally locked and
             # its value is pinned to this basis's full sweep
             "mask_src": st.mask_src,
         }
+
+    @staticmethod
+    def _capture_join_index(driver, ap) -> Optional[dict]:
+        """The join-group index (ops/joinkernel.py JoinState.persist)
+        where it is current with the captured pack, else None.  It is
+        persisted with the inventory and not only inside the delta
+        basis: the review path serves referential cells from it
+        (ops/joinreview.py), so a webhook-only replica, which never
+        sweeps and may restore no basis, restores it too."""
+        plans = getattr(driver, "_active_join_plans", tuple)()
+        js = getattr(driver, "_join_state", None)
+        if (
+            not plans or js is None or not js.built or ap.join_dirty
+            or js.rebuild_gen != ap.rebuild_gen
+            or js.sig != tuple(p.sig for p in plans)
+        ):
+            return None
+        return js.persist()
 
     @staticmethod
     def _resolve_mask(mask_src) -> Optional[np.ndarray]:
@@ -243,6 +249,7 @@ class SnapshotWriter:
                     f"store holds {n_objects} objects but the pack has "
                     f"{n_live} live rows; snapshot skipped"
                 )
+            join_index = self._capture_join_index(driver, ap)
             return {
                 "interner": list(interner._strings),
                 "templates": templates,
@@ -266,9 +273,12 @@ class SnapshotWriter:
                 "reviews": list(ap.reviews),
                 "row_gen": list(ap.row_gen),
                 "delta": (
-                    self._capture_delta(driver, ap)
+                    self._capture_delta(driver, ap, join_index)
                     if self.capture_delta else None
                 ),
+                # beside the basis, not in it: the review path serves from
+                # it whether a basis is restored or not
+                "join_index": join_index,
             }
 
     # ---- serialize --------------------------------------------------------
@@ -347,6 +357,7 @@ class SnapshotWriter:
                         "reviews": state["reviews"],
                         "row_gen": state["row_gen"],
                         "delta": state["delta"],
+                        "join_index": state["join_index"],
                     },
                     f, protocol=pickle.HIGHEST_PROTOCOL,
                 )
