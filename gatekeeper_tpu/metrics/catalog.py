@@ -394,6 +394,15 @@ JOIN_UPKEEP_M = Measure(
     "path folding in the writes since the index was last current)",
     unit="s",
 )
+CS_REFRESH_M = Measure(
+    "constraint_side_refresh",
+    "Times the packed constraint side was brought current, by outcome: "
+    "extend (the vocabulary grew inside the str-pred tables' padded "
+    "width: their new columns filled in place, those tables alone "
+    "uploaded) or repack (the constraint epoch moved or the vocabulary "
+    "crossed the width: packed from nothing, every array uploaded); a "
+    "dispatch whose tables already cover the vocabulary counts nothing",
+)
 COMPILE_LAG_M = Measure(
     "compile_epoch_lag",
     "Constraint-side mutation epochs the async background compiler is "
@@ -756,6 +765,8 @@ def catalog_views():
              AGG_COUNT),
         View("join_index_upkeep_seconds_total", JOIN_UPKEEP_M, AGG_SUM,
              tag_keys=("trigger",)),
+        View("constraint_side_refresh_total", CS_REFRESH_M, AGG_COUNT,
+             tag_keys=("outcome",)),
         View("compile_epoch_lag", COMPILE_LAG_M, AGG_LAST_VALUE),
         View("device_bytes", DEVICE_BYTES_M, AGG_LAST_VALUE,
              tag_keys=("component",)),
@@ -1364,6 +1375,15 @@ def record_join_upkeep(trigger: str, seconds: float):
         _global().record(JOIN_UPKEEP_M, seconds, {"trigger": trigger})
     except Exception:  # telemetry never blocks a sweep or a review
         record_dropped("record_join_upkeep")
+
+
+def record_cs_refresh(outcome: str):
+    """The constraint side brought current
+    (constraint_side_refresh_total{outcome}): extend or repack."""
+    try:
+        _global().record(CS_REFRESH_M, 1.0, {"outcome": outcome})
+    except Exception:  # telemetry never blocks a dispatch
+        record_dropped("record_cs_refresh")
 
 
 def record_compile_lag(lag: int):

@@ -186,7 +186,7 @@ class AsyncCompiler:
             # the constraint-side cache key the inputs were packed for —
             # read under the lock; _dispatch must not key the device cache
             # on a LATER epoch a concurrent mutation may have created
-            cs_key = (d._cs_epoch, d.interner.snapshot_size())
+            cs_key = d._cs_cache.key()
         # XLA trace + compile OUTSIDE the lock — the whole point.  Warm the
         # PACKED variant: compute_masks dispatches _packed_variant(fn), so
         # warming only the unpacked fused fn would leave the first real

@@ -21,6 +21,7 @@ from __future__ import annotations
 import copy
 import logging
 import os
+import sys
 import threading as _threading_mod
 from typing import Dict, List, Optional, Tuple
 
@@ -34,6 +35,7 @@ from ..metrics.catalog import (
     DISPATCH_M,
     PACK_M,
     record_cache,
+    record_cs_refresh,
     record_join_upkeep,
     record_render_cells,
     record_stage,
@@ -54,7 +56,7 @@ from .columns import extract_columns
 from .interning import Interner, PredicateTable
 from .matchkernel import match_kernel
 from .pack import _bucket as _bucket_pow2, pack_constraints, pack_reviews
-from .params import pack_params
+from .params import fill_table_columns, pack_params
 from .vectorizer import vectorize
 from .vexpr import EvalEnv, VProgram, eval_program
 
@@ -78,6 +80,47 @@ _REDUCTION_BLOCK = 64
 
 # _bound_plans miss sentinel (None is a valid cached "no plan")
 _PLAN_MISS = object()
+
+
+class _PackedSide:
+    """The packed constraint side with what it was packed for: the
+    constraint epoch, and — for the string-predicate tables, the only
+    leaves that read the vocabulary — the padded width they share and
+    `table_vocab`, the prefix of string ids their columns cover.  The
+    host cache, the device copy and the async compile thread's cs_key
+    all key on `key()` and ask `covers()`; nothing compares vocabulary
+    sizes at a call site."""
+
+    __slots__ = ("epoch", "width", "table_vocab", "side", "sig", "tables")
+
+    def __init__(self, epoch, side, tables, table_vocab):
+        self.epoch = epoch
+        self.side = side
+        self.sig = None  # TpuDriver._structure_sig(side), set once packed
+        # [(mat, {(pred, value): row})]: one per str-pred node and group
+        self.tables = tables
+        # a side without tables reads no string id: any vocabulary is
+        # inside its width and covered
+        self.width = min((m.shape[1] for m, _s in tables),
+                         default=sys.maxsize)
+        self.table_vocab = table_vocab if tables else sys.maxsize
+
+    def covers(self, epoch: int, vocab: int) -> bool:
+        """Do the tables hold a column for every interned string?"""
+        return self.epoch == epoch and vocab <= self.table_vocab
+
+    def extend(self, pred_cache, vocab: int) -> None:
+        """Fill the tables' columns [table_vocab, vocab) in place;
+        `vocab` is inside `width`, so no shape changes."""
+        for mat, stack in self.tables:
+            fill_table_columns(mat, stack, pred_cache, self.table_vocab,
+                               vocab)
+        self.table_vocab = max(self.table_vocab, vocab)
+
+    def key(self) -> tuple:
+        """What a device copy of this side is keyed on (epoch, width) and
+        tested against (table_vocab).  Read under the driver lock."""
+        return (self.epoch, self.width, self.table_vocab)
 
 
 def _constraint_semantics(constraint: dict):
@@ -253,6 +296,7 @@ class TpuDriver(InterpDriver):
         # re-uploading vocab-sized tables to N chips every call would cost
         # N host->device transfers; cached on the constraint epoch
         self._cs_device_cache = None
+        self._cs_uploaded = 0  # arrays _constraint_device_side last put
         # resident incremental audit packing (ops/auditpack.py) + rendered
         # cell memo: violations for an unchanged (constraint, row) pair are
         # deterministic unless the template reads data.inventory
@@ -739,15 +783,41 @@ class TpuDriver(InterpDriver):
         """Cached constraint-side packing: match pack + violation-program
         groups.  Programs are grouped by STRUCTURE, so template clones (the
         synthetic 500-template config) share one traced subgraph with their
-        constraints batched on the C axis.  Rebuilt when constraints or
-        templates change, or when the vocabulary has grown (str-pred tables
-        are vocab-sized)."""
-        ordered = self._ordered_constraints()
+        constraints batched on the C axis.  Keyed on what it depends on:
+        the constraint epoch and the padded width of the str-pred tables.
+        A vocabulary that grew inside that width extends the tables' new
+        columns in place (the rule npside.refresh_tables has) and leaves
+        the rest of the pack as it is; an epoch change or a vocabulary
+        past the width re-packs.  Caller holds the driver lock: an
+        extension must land after the packing that interned the strings
+        and before the dispatch that can carry their ids."""
+        ps = self._cs_cache
         vocab = self.interner.snapshot_size()
-        key = (self._cs_epoch, vocab)
-        if self._cs_cache and self._cs_cache[0] == key:
-            return self._cs_cache[1]
+        if ps is not None and ps.covers(self._cs_epoch, vocab):
+            return ps.side
+        if ps is not None and ps.epoch == self._cs_epoch \
+                and vocab <= ps.width:
+            outcome = "extend"
+        else:
+            outcome = "repack"
+            while True:
+                ps = self._pack_constraint_side()
+                # packing interns parameter and match strings itself: the
+                # extension below fills the columns they added; pack again
+                # in the (rare) case they carried the vocabulary past a
+                # table's width mid-pack
+                vocab = self.interner.snapshot_size()
+                if vocab <= ps.width:
+                    break
+            ps.sig = self._structure_sig(ps.side)
+            self._cs_cache = ps
+        ps.extend(self.pred_cache, vocab)
+        record_cs_refresh(outcome)
+        return ps.side
 
+    def _pack_constraint_side(self) -> _PackedSide:
+        ordered = self._ordered_constraints()
+        vocab0 = self.interner.snapshot_size()  # every table covers this
         specs = {}
         by_struct: Dict[str, list] = {}
         ungrouped: List[int] = []
@@ -775,6 +845,7 @@ class TpuDriver(InterpDriver):
         padded_cs: List[Optional[dict]] = []
         crow: List[int] = [0] * len(ordered)
         groups = []
+        tables = []
         for _sk, (prog, idxs) in sorted(by_struct.items()):
             for spec in prog.column_specs:
                 specs[spec.key] = spec
@@ -785,8 +856,13 @@ class TpuDriver(InterpDriver):
                 crow[i] = len(padded_cs)
                 padded_cs.append(ordered[i][2])
             padded_cs.extend([None] * (B - len(kcs)))
-            packed = pack_params(kcs, prog, self.interner, self.pred_cache, B)
+            meta: dict = {}
+            packed = pack_params(
+                kcs, prog, self.interner, self.pred_cache, B, meta_out=meta
+            )
             groups.append((prog, start, B, packed))
+            for pred_id, stack in meta.get("stacks", {}).items():
+                tables.append((packed[2][pred_id][0], stack))
         for i in ungrouped:
             crow[i] = len(padded_cs)
             padded_cs.append(ordered[i][2])
@@ -795,11 +871,7 @@ class TpuDriver(InterpDriver):
             ordered, cp, groups, list(specs.values()),
             np.asarray(crow, np.int64),
         )
-        # key uses the vocab size BEFORE param packing interned new strings;
-        # recompute so the cache stays valid next call
-        key = (self._cs_epoch, self.interner.snapshot_size())
-        self._cs_cache = (key, side)
-        return side
+        return _PackedSide(self._cs_epoch, side, tables, vocab0)
 
     def _structure_sig(self, side):
         """Trace signature of the fused fn for this constraint side: group
@@ -902,7 +974,9 @@ class TpuDriver(InterpDriver):
         arguments, so constraint churn that keeps shapes inside their
         power-of-two buckets reuses the warm executable as-is."""
         side = self._constraint_side()
-        sig = self._structure_sig(side)
+        # the signature is a function of the side's shapes, which an
+        # in-place extension keeps: computed once, when the side is packed
+        sig = self._cs_cache.sig
         if self._fused is not None and self._fused_key == sig:
             return self._fused, side
         if faults.ENABLED:
@@ -1267,24 +1341,28 @@ class TpuDriver(InterpDriver):
         self._fused_mask_key = self._fused_gen
         return self._fused_mask
 
-    def _repack_if_vocab_grew(self, fn, side):
-        """Row packing may have interned new strings; constraint-side string
-        predicate tables are vocab-sized, so re-pack them if so.  Shared by
-        the review and audit input paths — the invalidation rule must stay
-        identical between them."""
-        if self.interner.snapshot_size() > self._cs_cache[0][1]:
-            return self._fused_fn()
-        return fn, side
+    def _tables_cover_vocab(self) -> bool:
+        """The one question every input path asks after its row packing,
+        which may have interned new strings: do the constraint side's
+        str-pred tables cover the vocabulary?  When not, the caller asks
+        for the side again, and _constraint_side extends the tables in
+        place (or re-packs past their width) before any dispatch can carry
+        the new ids."""
+        ps = self._cs_cache
+        return ps is not None and ps.covers(
+            self._cs_epoch, self.interner.snapshot_size()
+        )
 
     def _device_inputs(self, reviews: List[dict]):
-        """Pack review-side arrays + columns; rebuild the constraint side if
-        these reviews interned new strings (pred tables are vocab-sized)."""
+        """Pack review-side arrays + columns; bring the constraint side's
+        str-pred tables current if these reviews interned new strings."""
         fn, side = self._fused_fn()
         col_specs = side[3]
         rp = pack_reviews(reviews, self.interner, self.store.cached_namespace)
         rows = len(rp.arrays["valid"])
         cols = extract_columns(reviews, col_specs, self.interner, rows)
-        fn, side = self._repack_if_vocab_grew(fn, side)
+        if not self._tables_cover_vocab():
+            fn, side = self._fused_fn()
         ordered, cp, groups, _col_specs, crow = side
         group_params = [packed for *_s, packed in groups]
         return fn, ordered, rp, cp, cols, group_params, crow
@@ -1488,10 +1566,11 @@ class TpuDriver(InterpDriver):
         cache (re-uploading vocab-sized tables to N chips every call would
         cost N host->device transfers).
 
-        cs_key: (cs_epoch, vocab) the inputs were packed for, captured under
-        the driver lock.  The async compile thread dispatches UNLOCKED, so
-        reading self._cs_epoch here could key stale constraint arrays under
-        a newer epoch (advisor r2); callers that hold the lock may omit it."""
+        cs_key: the side's key (_PackedSide.key: epoch, table width,
+        table_vocab) the inputs were packed for, read under the driver lock.  The
+        async compile thread dispatches UNLOCKED, so reading the live
+        epoch here could key stale constraint arrays under a newer one
+        (advisor r2); callers that hold the lock may omit it."""
         if faults.ENABLED:
             faults.fire(faults.TPU_DISPATCH)
         from .aotcache import aot_jit
@@ -1519,37 +1598,64 @@ class TpuDriver(InterpDriver):
 
     def _constraint_device_side(self, cp_arrays, group_params, cs_key, mesh):
         """The constraint-side trees committed on-device (replicated across
-        the mesh when one exists), cached on (epoch, vocab): vocab-sized
-        predicate tables dominate the constraint side, and re-uploading them
-        every call costs a host->device transfer per array."""
+        the mesh when one exists), kept for the life of (epoch, table
+        width, mesh).  Only the str-pred tables read the vocabulary: when
+        the side's `table_vocab` moved past the copy's, the table leaves
+        alone are uploaded again and every other leaf stays the array it
+        was.  `_cs_uploaded` is the number of arrays this call uploaded (0
+        on a hit)."""
         if cs_key is None:
-            cs_key = (self._cs_epoch, self.interner.snapshot_size())
-        key = (cs_key[0], cs_key[1], id(mesh) if mesh is not None else 0)
+            cs_key = self._cs_cache.key()
+        epoch, width, table_vocab = cs_key
+        key = (epoch, width, id(mesh) if mesh is not None else 0)
         # single read: the compile thread runs unlocked, and a concurrent
         # reset() may None the cache between a check and a re-read
         cache = self._cs_device_cache
-        if cache and cache[0] == key:
+        if cache and cache[0] == key and cache[2] >= table_vocab:
+            self._cs_uploaded = 0
             return cache[1]
         if mesh is None:
-            placed = jax.device_put((cp_arrays, group_params))
+            put = jax.device_put
         else:
             from ..parallel.mesh import replicate_tree
 
-            placed = replicate_tree(mesh, (cp_arrays, group_params))
-        # device-memory accounting (obs/compilestats.py): the replicated
-        # constraint side's footprint, refreshed per placement (cache
-        # misses only — epoch/vocab churn, not the hot path)
-        from ..obs import compilestats
+            def put(tree):
+                return replicate_tree(mesh, tree)
 
-        compilestats.record_device_bytes(
-            "constraint_side",
-            compilestats.tree_nbytes((cp_arrays, group_params)),
-            replicas=1 if mesh is None else int(mesh.devices.size),
-        )
+        if cache and cache[0] == key:
+            # the vocabulary grew inside the tables' width: their host
+            # arrays were extended in place (_constraint_side), so the
+            # device needs them and nothing else.  A dispatch in flight
+            # keeps the (immutable) arrays it was given, and carries only
+            # ids below the table_vocab it was packed for
+            cs_p, gp_p = cache[1]
+            mats = put([
+                {pid: mat for pid, (mat, _idx) in tables.items()}
+                for _params, _elems, tables in group_params
+            ])
+            placed = (cs_p, [
+                (params, elems,
+                 {pid: (new[pid], idx) for pid, (_mat, idx) in tables.items()})
+                for (params, elems, tables), new in zip(gp_p, mats)
+            ])
+            self._cs_uploaded = sum(len(m) for m in mats)
+        else:
+            placed = put((cp_arrays, group_params))
+            self._cs_uploaded = len(jax.tree_util.tree_leaves(placed))
+            # device-memory accounting (obs/compilestats.py): the
+            # replicated constraint side's footprint, refreshed per full
+            # placement (epoch / width churn, not the hot path)
+            from ..obs import compilestats
+
+            compilestats.record_device_bytes(
+                "constraint_side",
+                compilestats.tree_nbytes((cp_arrays, group_params)),
+                replicas=1 if mesh is None else int(mesh.devices.size),
+            )
         # never cache under a key the live epoch has moved past: a later
         # eval with an unchanged vocab would hit misaligned mask rows
-        if cs_key[0] == self._cs_epoch:
-            self._cs_device_cache = (key, placed)
+        if epoch == self._cs_epoch:
+            self._cs_device_cache = (key, placed, table_vocab)
         return placed
 
     def _packed_variant(self, fn):
@@ -3225,9 +3331,7 @@ class TpuDriver(InterpDriver):
         it."""
         fn, side = self._fused_audit_fn(K)
         self._audit_pack.sync(self, side[3])
-        if self.interner.snapshot_size() > self._cs_cache[0][1]:
-            # row packing interned new strings; constraint-side string
-            # predicate tables are vocab-sized, so re-pack them
+        if not self._tables_cover_vocab():
             fn, side = self._fused_audit_fn(K)
         ordered, cp, groups, _col_specs, crow = side
         group_params = [packed for *_s, packed in groups]
@@ -3449,6 +3553,7 @@ class TpuDriver(InterpDriver):
             cs_d, gp_d = self._constraint_device_side(
                 cp.arrays, group_params, None, None
             )
+            cs_uploaded = self._cs_uploaded
             if jargs is None:
                 packed_dev = fn(rv_d, cs_d, cols_d, gp_d)
                 # lazy: the [C, R] mask is its own (never-fetched)
@@ -3487,6 +3592,7 @@ class TpuDriver(InterpDriver):
             cs_p, gp_p = self._constraint_device_side(
                 cp.arrays, group_params, None, mesh
             )
+            cs_uploaded = self._cs_uploaded
             fn_mesh = self._fused_audit_mesh_fn(K, mesh)
             if jargs is None:
                 mask_dev, packed_dev = self._guarded_mesh_dispatch(
@@ -3546,6 +3652,9 @@ class TpuDriver(InterpDriver):
             "device_ms": (t2 - t1) * 1e3,
             "fetch_ms": (t3 - t2) * 1e3,
             "slice_ms": 0.0,  # a full sweep gathers no dirty-row slice
+            # constraint-side arrays this sweep uploaded: 0 on a hit, the
+            # str-pred tables when the vocabulary grew inside their width
+            "cs_upload_arrays": float(cs_uploaded),
             "enqueue_ms": (t_wait - t1) * 1e3,
             "device_wait_ms": (t2 - t_wait) * 1e3,
             "apply_ms": (_time.perf_counter() - t3) * 1e3,
@@ -3988,8 +4097,8 @@ class TpuDriver(InterpDriver):
         t0 = self._audit_clock_pack()
         side = self._constraint_side()
         self._audit_pack.sync(self, side[3])
-        if self.interner.snapshot_size() > self._cs_cache[0][1]:
-            side = self._constraint_side()  # vocab grew: re-pack tables
+        if not self._tables_cover_vocab():
+            side = self._constraint_side()
         ordered, cp, groups, _col_specs, _crow = side
         ap = self._audit_pack
         if st.layout_gen != ap.layout_gen or ap.n_rows == 0:
@@ -4128,6 +4237,7 @@ class TpuDriver(InterpDriver):
         cs_d, gp_d = self._constraint_device_side(
             cp.arrays, group_params, None, mesh
         )
+        cs_uploaded = self._cs_uploaded
         t_enq = clock.mark("enqueue")
         # [C_total, 2d] from the device; crow folds pad rows out so the
         # incremental state stays per ordered constraint
@@ -4173,6 +4283,7 @@ class TpuDriver(InterpDriver):
             "device_ms": (t_fetch - t1) * 1e3,
             "fetch_ms": (t2 - t_fetch) * 1e3,
             "slice_ms": (t_enq - t1) * 1e3,
+            "cs_upload_arrays": float(cs_uploaded),
             "enqueue_ms": (t_wait - t_enq) * 1e3,
             "device_wait_ms": (t_fetch - t_wait) * 1e3,
             "apply_ms": (_time.perf_counter() - t2) * 1e3,

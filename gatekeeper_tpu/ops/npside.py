@@ -39,7 +39,7 @@ from .columns import T_UNDEF, extract_columns
 from .interning import Interner
 from .matchkernel import match_kernel
 from .pack import PAD, pack_constraints, pack_reviews
-from .params import pack_params
+from .params import fill_table_columns, pack_params
 from .vexpr import EvalEnv, eval_program
 
 # pad values for growing each match-side buffer (axis>=1 widening and
@@ -246,10 +246,8 @@ class _Group:
             if vocab > gmat.shape[1]:
                 gmat = _grow_to(gmat, (gmat.shape[0], vocab), 0)
                 entry[0] = gmat
-            for key, grow_ in self.stacks[pred_id].items():
-                dense = pred_cache[key].dense()
-                n = min(len(dense), gmat.shape[1])
-                gmat[grow_, self.table_vocab:n] = dense[self.table_vocab:n]
+            fill_table_columns(gmat, self.stacks[pred_id], pred_cache,
+                               self.table_vocab, vocab)
         self.table_vocab = vocab
 
     def eval(self, rv_arrays, cols, R: int) -> Tuple[np.ndarray, np.ndarray]:

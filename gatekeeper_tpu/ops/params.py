@@ -62,6 +62,22 @@ def _bucket(n: int, minimum: int = 1) -> int:
     return b
 
 
+def fill_table_columns(mat: np.ndarray, stack: Dict[Tuple[str, str], int],
+                       pred_cache: Dict[Tuple[str, str], PredicateTable],
+                       lo: int, hi: int) -> None:
+    """Bring columns [lo, hi) of one string-predicate table current: row
+    `stack[key]` reads `pred_cache[key]` over those string ids.  The one
+    fill rule of every tier: pack_params builds a table with it (lo 0),
+    and the device side (driver._constraint_side) and the numpy side
+    (npside.refresh_tables) extend theirs in place with it when the
+    vocabulary grew — PredicateTable.dense() itself evaluates only the
+    strings it has not seen."""
+    if hi <= lo:
+        return
+    for key, row in stack.items():
+        mat[row, lo:hi] = pred_cache[key].dense()[lo:hi]
+
+
 def pack_params(
     constraints: List[dict],
     prog: VProgram,
@@ -72,9 +88,11 @@ def pack_params(
 ):
     """-> (params, elems, tables) for EvalEnv.  `rows` >= len(constraints)
     (padded rows read as undefined).  When `meta_out` is given, it receives
-    {"stacks": {pred_id: {(pred, value): table row}}} — the incremental
-    host side (ops/npside.py) needs the row identities to merge a single
-    constraint's tables into its growing group buffers."""
+    {"stacks": {pred_id: {(pred, value): table row}}} — the row
+    identities: the incremental host side (ops/npside.py) merges a single
+    constraint's tables into its growing group buffers with them, and
+    both tiers extend their tables over new strings with them
+    (fill_table_columns)."""
     pad = [(None, False)] * (rows - len(constraints))
 
     params: Dict[Tuple, Dict[str, np.ndarray]] = {}
@@ -159,8 +177,7 @@ def pack_params(
         # bucket both table dims so compiled executables survive vocabulary
         # growth and new predicate values (shape-stable jit cache)
         mat = np.zeros((_bucket(len(stack) + 1), _bucket(vocab, 256)), np.uint8)
-        for (pred, value), row in stack.items():
-            mat[row, :vocab] = pred_cache[(pred, value)].dense()[:vocab]
+        fill_table_columns(mat, stack, pred_cache, 0, vocab)
         tables[node.pred_id] = (mat, idx)
         if meta_out is not None:
             meta_out.setdefault("stacks", {})[node.pred_id] = dict(stack)
