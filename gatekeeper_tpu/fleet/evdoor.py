@@ -91,6 +91,7 @@ from .. import deadline as _deadline
 from .. import faults
 from .. import logging as gklog
 from ..metrics.catalog import (
+    record_frontdoor_choices,
     record_frontdoor_requests,
     record_frontdoor_stages,
     record_shed,
@@ -559,6 +560,7 @@ class EventFrontDoor:
         if self._outcomes:  # loop is stopped; drain the last tick's counts
             counts, self._outcomes = self._outcomes, {}
             record_frontdoor_requests(counts)
+        record_frontdoor_choices(self.roster.take_choices())
         if self._wstats or self._wrecs:  # and the last wire window
             wstats, self._wstats = self._wstats, {}
             wrecs, self._wrecs = self._wrecs, []
@@ -586,6 +588,9 @@ class EventFrontDoor:
         if self._outcomes:
             counts, self._outcomes = self._outcomes, {}
             record_frontdoor_requests(counts)
+            # how the tick's choices fell: a tick with no outcome made
+            # no choice worth a registry call of its own
+            record_frontdoor_choices(self.roster.take_choices())
         if self._wstats or self._wrecs:
             now = time.monotonic()
             if now - self._wflush_t >= self.WIRE_FLUSH_S:

@@ -11,7 +11,8 @@ JSON line on stdout::
 
     {"event": "ready", "replica_id": ..., "port": ..., "ready_s": ...,
      "restore_outcome": ..., "templates": N,
-     "device": {"platform": ..., "device_kind": ..., "count": N}}
+     "device": {"platform": ..., "device_kind": ..., "count": N},
+     "chip": N, "device_kind": ...}
 
 and serves until stdin closes (the parent dropping its pipe is the stop
 signal — no PID files, no signal races) or SIGTERM.
@@ -58,6 +59,8 @@ from collections import deque
 from typing import Dict, List, Optional, Sequence
 
 import logging
+
+from .placement import placement_env
 
 log = logging.getLogger("gatekeeper.fleet.replica")
 
@@ -378,6 +381,14 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             # platform / device_kind / count of the backend this replica
             # evaluates on (absent under --driver interp)
             ready["device"] = drv.device_info()
+        if hasattr(drv, "chip_info"):
+            # the chip this replica HOLDS (what it got, not what its
+            # launcher asked for: docs/fleet.md "Chips")
+            ready.update(drv.chip_info())
+            if "chip" in ready:  # absent off a TPU: no device file held
+                from ..metrics.catalog import record_replica_chip
+
+                record_replica_chip(ready["chip"])
         print(json.dumps(ready), flush=True)
         # serve until the parent closes our stdin (or EOF on a detached
         # run): the pipe IS the lifetime — a dead parent reaps the fleet.
@@ -522,7 +533,8 @@ _EOF = object()  # reader-thread sentinel: child stdout closed
 
 def _spawn_proc(replica_id: str, snapshot_dir: str, cache_dir: str,
                 extra_flags: Sequence[str],
-                env: Optional[Dict[str, str]]) -> subprocess.Popen:
+                env: Optional[Dict[str, str]],
+                index: int = 0, chips: int = 1) -> subprocess.Popen:
     cmd = [sys.executable, "-m", "gatekeeper_tpu.fleet.replica",
            "--replica-id", replica_id]
     if snapshot_dir:
@@ -533,6 +545,9 @@ def _spawn_proc(replica_id: str, snapshot_dir: str, cache_dir: str,
     child_env = dict(os.environ)
     if env:
         child_env.update(env)
+    # replica `index` of a launch on a host with `chips` chips opens
+    # chip index % chips alone (nothing with one chip: placement.py)
+    child_env.update(placement_env(index, chips))
     return subprocess.Popen(
         cmd, cwd=REPO_ROOT, env=child_env,
         stdin=subprocess.PIPE, stdout=subprocess.PIPE,
@@ -655,9 +670,13 @@ def _wait_ready(proc: subprocess.Popen, replica_id: str, pipes: _Pipes,
 
 class ReplicaHandle:
     def __init__(self, proc: subprocess.Popen, replica_id: str,
-                 ready: Dict, spawn_s: float, pipes: _Pipes):
+                 ready: Dict, spawn_s: float, pipes: _Pipes,
+                 index: int = 0):
         self.proc = proc
         self.replica_id = replica_id
+        # the replica's place in its launch: with `chips` > 1 it decides
+        # the chip, and a restart goes back to the same one
+        self.index = index
         self.ready = ready          # the child's announced ready line
         self.port: int = int(ready["port"])
         # exporter port for the metrics federator (0 on older replicas)
@@ -770,26 +789,32 @@ class ReplicaHandle:
 def spawn_replica(replica_id: str, snapshot_dir: str = "",
                   cache_dir: str = "", extra_flags: Sequence[str] = (),
                   env: Optional[Dict[str, str]] = None,
-                  timeout_s: float = 300.0) -> ReplicaHandle:
+                  timeout_s: float = 300.0,
+                  index: int = 0, chips: int = 1) -> ReplicaHandle:
     """Start one replica child and block until its ready line (raising
-    with the child's stderr tail on failure)."""
+    with the child's stderr tail on failure).  On a host with ``chips``
+    chips the child is given chip ``index % chips`` alone
+    (fleet/placement.py); with the default nothing is placed."""
     t0 = time.monotonic()
-    proc = _spawn_proc(replica_id, snapshot_dir, cache_dir, extra_flags, env)
+    proc = _spawn_proc(replica_id, snapshot_dir, cache_dir, extra_flags, env,
+                       index, chips)
     pipes = _attach_pipes(proc, replica_id)
     ready = _wait_ready(proc, replica_id, pipes, t0, timeout_s)
     return ReplicaHandle(proc, replica_id, ready,
-                         round(time.monotonic() - t0, 3), pipes)
+                         round(time.monotonic() - t0, 3), pipes, index)
 
 
 def spawn_fleet(n: int, snapshot_dir: str = "", cache_dir: str = "",
                 extra_flags: Sequence[str] = (),
                 env: Optional[Dict[str, str]] = None,
                 timeout_s: float = 300.0,
-                sequential: bool = True) -> List[ReplicaHandle]:
+                sequential: bool = True,
+                chips: int = 1) -> List[ReplicaHandle]:
     """Start n replicas (r0..r{n-1}).  ``sequential`` (default) waits for
     each before starting the next — on a small host, concurrent cold
     spawns contend for cores and every ready time degrades; a k8s fleet
-    scales up on fresh nodes, which sequential spawn approximates."""
+    scales up on fresh nodes, which sequential spawn approximates.
+    Replica i takes chip i % ``chips`` of the host (placement.py)."""
     handles: List[ReplicaHandle] = []
     procs: List = []
     try:
@@ -797,21 +822,21 @@ def spawn_fleet(n: int, snapshot_dir: str = "", cache_dir: str = "",
             for i in range(n):
                 handles.append(spawn_replica(
                     f"r{i}", snapshot_dir, cache_dir, extra_flags, env,
-                    timeout_s,
+                    timeout_s, i, chips,
                 ))
         else:
             for i in range(n):
                 rid = f"r{i}"
                 t0 = time.monotonic()
                 proc = _spawn_proc(
-                    rid, snapshot_dir, cache_dir, extra_flags, env
+                    rid, snapshot_dir, cache_dir, extra_flags, env, i, chips
                 )
                 procs.append((rid, t0, proc, _attach_pipes(proc, rid)))
-            for rid, t0, proc, pipes in procs:
+            for i, (rid, t0, proc, pipes) in enumerate(procs):
                 ready = _wait_ready(proc, rid, pipes, t0, timeout_s)
                 handles.append(ReplicaHandle(
                     proc, rid, ready, round(time.monotonic() - t0, 3),
-                    pipes,
+                    pipes, i,
                 ))
     except BaseException:
         # kill EVERY spawned child, wrapped in a handle or not — a

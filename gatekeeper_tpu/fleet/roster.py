@@ -199,6 +199,13 @@ class Roster:
             raise ValueError("front door needs at least one backend")
         self._rr = itertools.count()
         self._mu = threading.Lock()      # guards backend re-pointing
+        # (replica_id, how) -> n since the last take_choices(): how a
+        # choice fell, "least" (one backend had strictly the fewest in
+        # flight) or "tie" (rotation decided).  The door drains it once
+        # a reactor tick into frontdoor_choice_total; the hot path pays
+        # a dict increment under the chosen backend's lock, as for the
+        # request outcomes (one drainer: the door's loop thread)
+        self._choices: dict = {}
         self._prober: Optional[threading.Thread] = None
         self._prober_stop = threading.Event()
 
@@ -269,12 +276,17 @@ class Roster:
                 # shared the way the stable sort did.
                 best = None
                 best_in = 0
+                tied = False
                 for k in range(n):
                     b = candidates[(start + k) % n]
-                    if not b.ejected and (best is None
-                                          or b.inflight < best_in):
+                    if b.ejected:
+                        continue
+                    if best is None or b.inflight < best_in:
                         best = b
                         best_in = b.inflight
+                        tied = False
+                    elif b.inflight == best_in:
+                        tied = True
                 if best is not None:
                     with best.lock:
                         if not (
@@ -282,6 +294,7 @@ class Roster:
                             and best.inflight >= self.max_inflight
                         ):
                             best.inflight += 1
+                            self._note_choice(best, tied)
                             return best
                 # at-bound or all-ejected: the general path below owns
                 # the shed/fail-static decision
@@ -296,13 +309,17 @@ class Roster:
                 # least inflight, rotation as tiebreak (stable sort
                 # over the rotated order) so equal backends share
                 ordered.sort(key=lambda b: b.inflight)
-            for b in ordered:
+            for k, b in enumerate(ordered):
                 with b.lock:
                     if (
                         self.max_inflight
                         and b.inflight >= self.max_inflight
                     ):
                         continue
+                    if self.policy != ROUND_ROBIN:
+                        nxt = ordered[k + 1:k + 2]
+                        self._note_choice(
+                            b, bool(nxt) and nxt[0].inflight <= b.inflight)
                     b.inflight += 1
                 return b
             raise _deadline.OverloadShed(
@@ -320,6 +337,18 @@ class Roster:
         with b.lock:
             b.inflight += 1
         return b
+
+    def _note_choice(self, backend: Backend, tied: bool) -> None:
+        key = (backend.replica_id, "tie" if tied else "least")
+        self._choices[key] = self._choices.get(key, 0) + 1
+
+    def take_choices(self) -> dict:
+        """The least-inflight choices made since the last call, as
+        {(replica_id, how): n}, and forget them (the door's tick flush:
+        metrics/catalog.record_frontdoor_choices).  round_robin counts
+        nothing: there rotation decides every choice by definition."""
+        out, self._choices = self._choices, {}
+        return out
 
     # ---- giving a reservation back -----------------------------------------
 

@@ -13,7 +13,8 @@ individual replica failures:
   exactly it);
 - **restart** — a failed replica is killed (whole process group) and
   respawned from the same shared sealed snapshot + AOT cache, so the
-  replacement is warm in seconds (the PR 7 machinery); restart attempts
+  replacement is warm in seconds (the PR 7 machinery), on the chip its
+  slot had (``chips`` > 1: fleet/placement.py); restart attempts
   pace on a capped exponential backoff (:class:`syncutil.Backoff`);
 - **flap quarantine** — a replica that crashes ``flap_threshold`` times
   within ``flap_window_s`` is quarantined: no further restarts, state
@@ -137,8 +138,11 @@ class _Slot:
     """Supervision state for one replica identity (the identity outlives
     any single process incarnation)."""
 
-    def __init__(self, replica_id: str, backoff: Backoff):
+    def __init__(self, replica_id: str, backoff: Backoff, index: int = 0):
         self.replica_id = replica_id
+        # the replica's place in the launch: every incarnation of this
+        # identity is spawned with it, so it returns to its own chip
+        self.index = index
         self.handle: Optional[ReplicaHandle] = None
         self.state = STOPPED
         self.backoff = backoff
@@ -182,7 +186,11 @@ class ReplicaSupervisor:
         flap_window_s: float = 30.0,
         flap_threshold: int = 5,
         on_backend_change: Optional[Callable] = None,
+        chips: int = 1,
     ):
+        # chips of this host the replicas are placed on, one each
+        # (fleet/placement.py); 1 = nothing is placed
+        self.chips = int(chips)
         self.snapshot_dir = snapshot_dir
         self.cache_dir = cache_dir
         self.extra_flags = list(extra_flags)
@@ -204,11 +212,11 @@ class ReplicaSupervisor:
 
     # ---- construction -----------------------------------------------------
 
-    def _new_slot(self, replica_id: str) -> _Slot:
+    def _new_slot(self, replica_id: str, index: int) -> _Slot:
         return _Slot(replica_id, Backoff(
             base=self.backoff_base_s, factor=2.0, cap=self.backoff_cap_s,
             jitter=0.25,
-        ))
+        ), index)
 
     def _set_state(self, slot: _Slot, state: int):
         slot.state = state
@@ -220,7 +228,7 @@ class ReplicaSupervisor:
             slot = self._slots.get(handle.replica_id)
             if slot is None:
                 slot = self._slots[handle.replica_id] = self._new_slot(
-                    handle.replica_id
+                    handle.replica_id, handle.index
                 )
             slot.handle = handle
             slot.started_at = time.monotonic()
@@ -235,7 +243,7 @@ class ReplicaSupervisor:
         handles: List[ReplicaHandle] = []
         try:
             for i in range(n):
-                handles.append(self._spawn(f"r{i}"))
+                handles.append(self._spawn(f"r{i}", i))
         except BaseException:
             self.stop()
             raise
@@ -251,11 +259,12 @@ class ReplicaSupervisor:
         )
         self._monitor.start()
 
-    def _spawn(self, replica_id: str) -> ReplicaHandle:
+    def _spawn(self, replica_id: str, index: int = 0) -> ReplicaHandle:
         handle = spawn_replica(
             replica_id, self.snapshot_dir, self.cache_dir,
             extra_flags=self.extra_flags, env=self.env,
             timeout_s=self.spawn_timeout_s,
+            index=index, chips=self.chips,
         )
         self.adopt(handle)
         self._notify(replica_id, handle.wire_backend())
@@ -393,6 +402,7 @@ class ReplicaSupervisor:
                 slot.replica_id, self.snapshot_dir, self.cache_dir,
                 extra_flags=self.extra_flags, env=self.env,
                 timeout_s=self.spawn_timeout_s,
+                index=slot.index, chips=self.chips,
             )
         except Exception as e:
             log.warning("replica %s restart failed (%s: %s)",

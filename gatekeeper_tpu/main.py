@@ -720,6 +720,7 @@ class App:
 
         breaker_fn = getattr(self.client.driver, "breaker_status", None)
         device_fn = getattr(self.client.driver, "device_info", None)
+        chip_fn = getattr(self.client.driver, "chip_info", None)
         slo_engine = obsslo.get_engine()
         from .obs import brownout as obsbrownout
 
@@ -732,6 +733,8 @@ class App:
                 st["tpu_breaker"] = breaker_fn()
             if device_fn is not None:
                 st["device"] = device_fn()
+            if chip_fn is not None:
+                st.update(chip_fn())  # chip, device_kind
             return st
 
         if getattr(args, "slo_trip_breaker", False):

@@ -219,6 +219,13 @@ REPLICA_UP_M = Measure(
     "1 for a started gatekeeper process, labelled by its fleet "
     "replica_id (empty outside a fleet)",
 )
+REPLICA_CHIP_M = Measure(
+    "replica_chip_info",
+    "1, labelled by this fleet replica's replica_id and the number of "
+    "the chip device file it has open once the backend is up (what it "
+    "got, not what a launcher asked for); not recorded where it holds "
+    "none",
+)
 BATCH_TARGET_M = Measure(
     "webhook_batch_target_size",
     "The micro-batcher's current load-adapted target batch size "
@@ -290,6 +297,12 @@ FRONTDOOR_REQS_M = Measure(
     "Requests through the fleet front door by outcome (ok, "
     "backend_error, no_backend, bad_request) and serving backend "
     "replica id (empty when no backend answered)",
+)
+FRONTDOOR_CHOICE_M = Measure(
+    "frontdoor_choice",
+    "least_inflight choices of the front door's roster by chosen "
+    "replica_id and how the choice fell: least (one backend had "
+    "strictly the fewest requests in flight) or tie (rotation decided)",
 )
 FLEET_SCRAPE_OK_M = Measure(
     "fleet_scrape_ok",
@@ -720,6 +733,8 @@ def catalog_views():
              AGG_DISTRIBUTION, tag_keys=("path",), buckets=_STAGE_BUCKETS),
         View("replica_up", REPLICA_UP_M, AGG_LAST_VALUE,
              tag_keys=("replica_id",)),
+        View("replica_chip_info", REPLICA_CHIP_M, AGG_LAST_VALUE,
+             tag_keys=("replica_id", "chip")),
         View("webhook_batch_target_size", BATCH_TARGET_M, AGG_LAST_VALUE,
              tag_keys=("replica_id",)),
         View("webhook_batch_deadline_ms", BATCH_DEADLINE_M, AGG_LAST_VALUE,
@@ -741,6 +756,8 @@ def catalog_views():
              AGG_DISTRIBUTION, tag_keys=("stage",), buckets=_STAGE_BUCKETS),
         View("frontdoor_requests_total", FRONTDOOR_REQS_M, AGG_COUNT,
              tag_keys=("outcome", "backend")),
+        View("frontdoor_choice_total", FRONTDOOR_CHOICE_M, AGG_COUNT,
+             tag_keys=("replica_id", "how")),
         View("fleet_scrape_ok", FLEET_SCRAPE_OK_M, AGG_LAST_VALUE,
              tag_keys=("replica_id",)),
         View("fleet_scrape_age_seconds", FLEET_SCRAPE_AGE_M,
@@ -1121,6 +1138,17 @@ def record_replica_up():
         record_dropped("record_replica_up")
 
 
+def record_replica_chip(chip) -> None:
+    """The chip this fleet replica holds, once its backend is up (the
+    replica runtime, beside its ready line).  Guarded like
+    record_stage."""
+    try:
+        _global().record(REPLICA_CHIP_M, 1.0,
+                         {**_replica_tags(), "chip": str(chip)})
+    except Exception:  # telemetry never blocks startup
+        record_dropped("record_replica_chip")
+
+
 def record_batcher_state(target_size: int, deadline_ms: float,
                          offered_load_rps: float):
     """The micro-batcher's current adaptation state (one record per
@@ -1212,6 +1240,21 @@ def record_frontdoor_requests(counts):
             )
     except Exception:  # telemetry never blocks the wire path
         record_dropped("record_frontdoor_requests")
+
+
+def record_frontdoor_choices(counts):
+    """Tick-batched roster choices from the front door: counts maps
+    (replica_id, how) -> n with how in {least, tie} (Roster.choose),
+    flushed with the request outcomes.  Guarded like record_stage."""
+    try:
+        reg = _global()
+        for (replica_id, how), n in counts.items():
+            reg.record(
+                FRONTDOOR_CHOICE_M, 1.0,
+                {"replica_id": replica_id, "how": how}, count=n,
+            )
+    except Exception:  # telemetry never blocks the wire path
+        record_dropped("record_frontdoor_choices")
 
 
 def record_scrape(replica_id: str, ok: bool, age_s: float):

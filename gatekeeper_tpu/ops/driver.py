@@ -572,6 +572,14 @@ class TpuDriver(InterpDriver):
             self._device_info = device_info()
         return dict(self._device_info)
 
+    def chip_info(self) -> dict:
+        """{"chip", "device_kind"}: which chip this process holds
+        (parallel/mesh.py chip_info), beside device_info() on /statusz
+        and the replica ready line."""
+        from ..parallel.mesh import chip_info
+
+        return chip_info()
+
     # review-memo entry bound: each entry retains a frozen admission object
     # (~KBs); 16k entries keeps worst-case memory in the tens of MB and a
     # wholesale clear in the low ms

@@ -29,6 +29,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..util import join_thread
+from ..util.chips import held_chips
 
 
 class MeshDispatchStall(RuntimeError):
@@ -161,6 +162,20 @@ def device_info() -> dict:
         "device_kind": devs[0].device_kind,
         "count": len(devs),
     }
+
+
+def chip_info() -> dict:
+    """Which chip this process holds, for the replica ready line,
+    /statusz and `replica_chip_info`: `chip` is the number of the chip
+    device file it has open once the backend is up (the lowest, if it
+    holds several; util/chips.py: what it got, not what a launcher
+    asked for), and is left out where it holds none (off a TPU): the
+    jax device id is not a stand-in, every placed replica calls its
+    one device 0."""
+    devs = jax.devices()
+    held = held_chips()
+    return {**({"chip": held[0]} if held else {}),
+            "device_kind": devs[0].device_kind}
 
 
 def audit_mesh(n_devices: Optional[int] = None) -> Mesh:

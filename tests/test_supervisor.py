@@ -97,7 +97,8 @@ class FakeSpawner:
         self.calls = 0
 
     def __call__(self, replica_id, snapshot_dir="", cache_dir="",
-                 extra_flags=(), env=None, timeout_s=30.0):
+                 extra_flags=(), env=None, timeout_s=30.0,
+                 index=0, chips=1):
         self.calls += 1
         t0 = time.monotonic()
         proc = subprocess.Popen(
@@ -108,7 +109,8 @@ class FakeSpawner:
         pipes = rep._attach_pipes(proc, replica_id)
         ready = rep._wait_ready(proc, replica_id, pipes, t0, timeout_s)
         return rep.ReplicaHandle(
-            proc, replica_id, ready, round(time.monotonic() - t0, 3), pipes
+            proc, replica_id, ready, round(time.monotonic() - t0, 3), pipes,
+            index,
         )
 
 
