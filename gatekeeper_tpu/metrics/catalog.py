@@ -416,6 +416,22 @@ CS_REFRESH_M = Measure(
     "crossed the width: packed from nothing, every array uploaded); a "
     "dispatch whose tables already cover the vocabulary counts nothing",
 )
+DISPATCH_UPLOAD_M = Measure(
+    "tpu_dispatch_upload_arrays",
+    "Host arrays handed to a review dispatch's jit call, each a "
+    "host-to-device transfer of its own, by side: review (the one "
+    "[rows, width] buffer the review side travels in, plus every leaf "
+    "that cannot lie in it) or constraint (what the device copy of the "
+    "constraint side was missing: nothing on a hit, the str-pred tables "
+    "after an extension, every array after a re-pack)",
+)
+AOT_LOOKUP_M = Measure(
+    "aot_executable_lookups",
+    "Calls of an AOT-cached function that named their executable, by "
+    "outcome: memo (the argument layout was met before: one dict "
+    "lookup) or hashed (a layout new to the function: a SHA-256 over "
+    "the tree and one string a leaf, which is also the on-disk name)",
+)
 COMPILE_LAG_M = Measure(
     "compile_epoch_lag",
     "Constraint-side mutation epochs the async background compiler is "
@@ -783,6 +799,10 @@ def catalog_views():
         View("join_index_upkeep_seconds_total", JOIN_UPKEEP_M, AGG_SUM,
              tag_keys=("trigger",)),
         View("constraint_side_refresh_total", CS_REFRESH_M, AGG_COUNT,
+             tag_keys=("outcome",)),
+        View("tpu_dispatch_upload_arrays_total", DISPATCH_UPLOAD_M, AGG_SUM,
+             tag_keys=("side",)),
+        View("aot_executable_lookups_total", AOT_LOOKUP_M, AGG_COUNT,
              tag_keys=("outcome",)),
         View("compile_epoch_lag", COMPILE_LAG_M, AGG_LAST_VALUE),
         View("device_bytes", DEVICE_BYTES_M, AGG_LAST_VALUE,
@@ -1427,6 +1447,23 @@ def record_cs_refresh(outcome: str):
         _global().record(CS_REFRESH_M, 1.0, {"outcome": outcome})
     except Exception:  # telemetry never blocks a dispatch
         record_dropped("record_cs_refresh")
+
+
+def record_dispatch_upload(side: str, arrays: int):
+    """Host arrays one review dispatch handed to its jit call
+    (tpu_dispatch_upload_arrays_total{side})."""
+    try:
+        _global().record(DISPATCH_UPLOAD_M, float(arrays), {"side": side})
+    except Exception:  # telemetry never blocks a dispatch
+        record_dropped("record_dispatch_upload")
+
+
+def record_aot_lookup(outcome: str):
+    """One executable named (aot_executable_lookups_total{outcome})."""
+    try:
+        _global().record(AOT_LOOKUP_M, 1.0, {"outcome": outcome})
+    except Exception:  # telemetry never blocks a dispatch
+        record_dropped("record_aot_lookup")
 
 
 def record_compile_lag(lag: int):

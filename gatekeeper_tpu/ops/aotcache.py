@@ -47,6 +47,8 @@ from typing import Any, Callable, Optional
 
 import jax
 
+from ..metrics.catalog import record_aot_lookup
+
 log = logging.getLogger("gatekeeper.aotcache")
 
 _dir: Optional[str] = None
@@ -158,6 +160,13 @@ def _leaf_sig(x) -> str:
     return f"py:{type(x).__name__}:{x!r}"
 
 
+def _leaf_layout(x):
+    """What _leaf_sig formats, as objects: hashed, never formatted."""
+    if hasattr(x, "shape") and hasattr(x, "dtype"):
+        return x.shape, x.dtype
+    return type(x), repr(x)
+
+
 def load(key: str):
     """-> compiled executable or None."""
     if _dir is None:
@@ -253,6 +262,11 @@ class aot_jit:
         h.update(tag.encode())
         h.update(repr(sig).encode())
         self._prefix = h
+        # layout (backend, treedef, every leaf's shape and dtype, as
+        # objects) -> key: a layout met before names its executable by
+        # one dict lookup; _key's SHA-256 over one string a leaf runs
+        # only for a layout that is new
+        self._keys: dict = {}
         self._compiled: dict = {}  # key -> executable
         self._validated: set = set()  # keys whose output was block-checked
         self._bad: set = set()
@@ -260,19 +274,31 @@ class aot_jit:
         # jax.jit attribute parity for wrappers that reach for it
         self.__wrapped__ = fn
 
-    def _key(self, args) -> str:
+    def _key(self, args, flat=None) -> str:
+        """The executable's on-disk name."""
         h = self._prefix.copy()
         h.update(jax.default_backend().encode())
-        leaves, treedef = jax.tree_util.tree_flatten(args)
+        leaves, treedef = flat or jax.tree_util.tree_flatten(args)
         h.update(str(treedef).encode())
         for leaf in leaves:
             h.update(_leaf_sig(leaf).encode())
         return f"{self._tag}-{h.hexdigest()[:32]}"
 
+    def _lookup(self, args):
+        """-> (layout, key), through the memo."""
+        flat = jax.tree_util.tree_flatten(args)
+        layout = (jax.default_backend(), flat[1],
+                  tuple(map(_leaf_layout, flat[0])))
+        key = self._keys.get(layout)
+        record_aot_lookup("hashed" if key is None else "memo")
+        if key is None:
+            key = self._keys[layout] = self._key(args, flat)
+        return layout, key
+
     def __call__(self, *args):
         if not enabled():
             return self._jitted(*args)  # tests/no-cache: plain jit
-        key = self._key(args)
+        layout, key = self._lookup(args)
         with self._mu:
             compiled = self._compiled.get(key)
             bad = key in self._bad
@@ -359,6 +385,7 @@ class aot_jit:
                             "and falling back to jit: %s", key)
                 drop(key)
                 with self._mu:
+                    self._keys.pop(layout, None)
                     self._compiled.pop(key, None)
                     self._validated.discard(key)
                     self._bad.add(key)

@@ -179,21 +179,19 @@ class AsyncCompiler:
                     self._ready_epoch = epoch
                     self._cond.notify_all()
                 return
-            fn, _ordered, rp, cp, cols, group_params, _crow = d._device_inputs(
-                [dict(_PROBE_REVIEW)]
-            )
-            rows = len(rp.arrays["valid"])
+            fn, _ordered, buf, extras, cp, group_params, _crow = \
+                d._packed_inputs([dict(_PROBE_REVIEW)])
             # the constraint-side cache key the inputs were packed for —
             # read under the lock; _dispatch must not key the device cache
             # on a LATER epoch a concurrent mutation may have created
             cs_key = d._cs_cache.key()
         # XLA trace + compile OUTSIDE the lock — the whole point.  Warm the
-        # PACKED variant: compute_masks dispatches _packed_variant(fn), so
-        # warming only the unpacked fused fn would leave the first real
-        # review to pay the full synchronous compile anyway.
+        # PACKED variant of the probe's layout, through the packing
+        # compute_masks goes through: warming only the unpacked fused fn
+        # would leave the first real review to pay the full synchronous
+        # compile anyway.
         out = d._dispatch(
-            d._packed_variant(fn), rp.arrays, cp.arrays, cols, group_params,
-            rows, cs_key=cs_key,
+            fn, buf, extras, cp.arrays, group_params, cs_key=cs_key,
         )
         jax.block_until_ready(out)
         with self._cond:

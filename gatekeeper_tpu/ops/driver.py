@@ -36,6 +36,7 @@ from ..metrics.catalog import (
     PACK_M,
     record_cache,
     record_cs_refresh,
+    record_dispatch_upload,
     record_join_upkeep,
     record_render_cells,
     record_stage,
@@ -51,7 +52,7 @@ from ..client.drivers import (
 )
 from ..target.match import constraint_matches, needs_autoreject
 from ..target.target import K8sValidationTarget
-from . import joinreview
+from . import joinreview, reviewbuf
 from .columns import extract_columns
 from .interning import Interner, PredicateTable
 from .matchkernel import match_kernel
@@ -256,9 +257,10 @@ class TpuDriver(InterpDriver):
         self.pred_cache: Dict[Tuple[str, str], PredicateTable] = {}
         self._fused = None
         self._fused_key = None
-        # bit-packed output wrapper of the fused fn (review path): one
-        # [2C, ceil(R/8)] uint8 fetch instead of two R-byte bool fetches
-        self._fused_packed = None
+        # the fused fn as the review path calls it (_packed_variant): one
+        # review-side buffer in, one [2C, ceil(R/8)] uint8 fetch out; per
+        # review-side layout -> (executable, layout), while `fn` stands
+        self._fused_packed: dict = {}
         self._fused_packed_src = None
         # multi-chip: data-parallel mesh over every visible device (None on
         # single-chip).  GK_MESH=0 forces the single-device path, GK_MESH=1
@@ -1375,6 +1377,23 @@ class TpuDriver(InterpDriver):
         group_params = [packed for *_s, packed in groups]
         return fn, ordered, rp, cp, cols, group_params, crow
 
+    def _packed_inputs(self, reviews: List[dict]):
+        """_device_inputs in the form a review dispatch takes: the review
+        side as one [rows, width] buffer (ops/reviewbuf.py) beside the
+        executable compiled for its layout.  -> (packed fn, ordered, buf,
+        extras, cp, group_params, crow)."""
+        fn, ordered, rp, cp, cols, group_params, crow = self._device_inputs(
+            reviews
+        )
+        rows = len(rp.arrays["valid"])
+        tree = (rp.arrays, cols)
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        pv, layout = self._packed_variant(
+            fn, tree, rows, (treedef, reviewbuf.leaf_metas(leaves, rows))
+        )
+        buf, extras = layout.pack(leaves, rows)
+        return pv, ordered, buf, extras, cp, group_params, crow
+
     def _mesh(self):
         """The production device mesh: all visible devices (or the pinned
         mesh_width), data-parallel on the resource axis (parallel/mesh.py).
@@ -1566,13 +1585,14 @@ class TpuDriver(InterpDriver):
                       exc_info=True)
         return new
 
-    def _dispatch(self, fn, rv_arrays, cp_arrays, cols, group_params, rows,
+    def _dispatch(self, fn, buf, extras, cp_arrays, group_params,
                   cs_key=None):
-        """Call a fused device function with mesh-aware placement: on a
-        multi-chip mesh the review side is padded + sharded on "data" and
-        the replicated constraint side is served from the epoch-keyed device
-        cache (re-uploading vocab-sized tables to N chips every call would
-        cost N host->device transfers).
+        """Call a packed fused device function (_packed_inputs) with
+        mesh-aware placement: on a multi-chip mesh the review side's
+        buffer is padded + sharded on "data" and the replicated constraint
+        side is served from the epoch-keyed device cache (re-uploading
+        vocab-sized tables to N chips every call would cost N
+        host->device transfers).
 
         cs_key: the side's key (_PackedSide.key: epoch, table width,
         table_vocab) the inputs were packed for, read under the driver lock.  The
@@ -1587,8 +1607,12 @@ class TpuDriver(InterpDriver):
         cs_p, gp_p = self._constraint_device_side(
             cp_arrays, group_params, cs_key, mesh
         )
+        record_dispatch_upload("review", 1 + len(extras))
+        if self._cs_uploaded:
+            record_dispatch_upload("constraint", self._cs_uploaded)
         if mesh is None:
-            return fn(rv_arrays, cs_p, cols, gp_p)
+            # the buffer is a host array: its upload is inside this call
+            return fn(buf, extras, cs_p, gp_p)
         if isinstance(fn, aot_jit):
             # serialized executables pin a single-device layout; the mesh
             # path must go through the jit machinery's SPMD compile
@@ -1597,12 +1621,12 @@ class TpuDriver(InterpDriver):
 
         from ..parallel.mesh import DISPATCH_LOCK
 
-        rv_p, cols_p, _target = shard_review_side(
-            mesh, rows, rv_arrays, cols,
+        buf_p, extras_p, _target = shard_review_side(
+            mesh, buf.shape[0], buf, extras,
             record_shard=self._record_shard("review"),
         )
         with DISPATCH_LOCK, mesh:
-            return fn(rv_p, cs_p, cols_p, gp_p)
+            return fn(buf_p, extras_p, cs_p, gp_p)
 
     def _constraint_device_side(self, cp_arrays, group_params, cs_key, mesh):
         """The constraint-side trees committed on-device (replicated across
@@ -1666,16 +1690,28 @@ class TpuDriver(InterpDriver):
             self._cs_device_cache = (key, placed, table_vocab)
         return placed
 
-    def _packed_variant(self, fn):
-        """Wrap the fused fn so mask+autoreject leave the device as ONE
-        bit-packed uint8 array: one fetch instead of two, with the
-        payload cut 8x.  The packing runs inside the same jitted dispatch
-        (no separate stack op)."""
-        if self._fused_packed is not None and self._fused_packed_src is fn:
-            return self._fused_packed
+    def _packed_variant(self, fn, tree, rows, key):
+        """The fused fn as a review dispatch calls it, for one layout of
+        the review side: it takes the side as ONE [rows, width] buffer
+        (plus the leaves that cannot lie in it), rebuilds `rv` and `cols`
+        by static slices, and returns mask+autoreject as ONE bit-packed
+        uint8 array: one upload and one fetch instead of dozens and two,
+        the fetch's payload cut 8x.  Unpacking and packing run inside the
+        same jitted dispatch.  -> (executable, layout); one of each per
+        `key` (the tree's structure and every leaf's shape after the row
+        axis and dtype), which is fixed by the column specs and the padded
+        widths that key an executable anyway."""
+        if self._fused_packed_src is not fn:
+            self._fused_packed = {}
+            self._fused_packed_src = fn
+        got = self._fused_packed.get(key)
+        if got is not None:
+            return got
+        layout = reviewbuf.Layout(tree, rows)
         raw = fn.__wrapped__
 
-        def fused_packed(rv, cs, cols, gp):
+        def fused_packed(buf, extras, cs, gp):
+            rv, cols = layout.unpack(buf, extras)
             mask, autoreject = raw(rv, cs, cols, gp)
             return jnp.packbits(
                 jnp.concatenate([mask, autoreject], axis=0), axis=1
@@ -1683,11 +1719,21 @@ class TpuDriver(InterpDriver):
 
         from .aotcache import aot_jit
 
-        self._fused_packed = aot_jit(
-            fused_packed, "fused-packed", self._fused_key
+        got = self._fused_packed[key] = (
+            aot_jit(fused_packed, "fused-packed",
+                    (self._fused_key, layout.sig)),
+            layout,
         )
-        self._fused_packed_src = fn
-        return self._fused_packed
+        return got
+
+    @staticmethod
+    def _split_masks(both, crow, rows):
+        """A packed dispatch's unpacked bits -> (mask, autoreject), each
+        [constraints, rows] bool.  crow maps each ordered constraint to
+        its group-major mask row (pad block rows drop out here)."""
+        c = both.shape[0] // 2
+        return (both[:c][crow][:, :rows].astype(bool),
+                both[c:][crow][:, :rows].astype(bool))
 
     def compute_masks(self, reviews: List[dict]):
         """-> (ordered constraints, match&violation candidate mask [C, R],
@@ -1702,15 +1748,11 @@ class TpuDriver(InterpDriver):
         # tpu.dispatch interval split where the work changes hands
         clock = obstrace.running_clock(obstrace.PATH_BATCH)
         t0 = clock.mark("pack")
-        fn, ordered, rp, cp, cols, group_params, crow = self._device_inputs(
-            reviews
-        )
-        rows = len(rp.arrays["valid"])
-        t1 = clock.mark("enqueue")  # implicit upload + launch
-        packed = self._dispatch(
-            self._packed_variant(fn), rp.arrays, cp.arrays, cols,
-            group_params, rows,
-        )
+        fn, ordered, buf, extras, cp, group_params, crow = \
+            self._packed_inputs(reviews)
+        rows = buf.shape[0]
+        t1 = clock.mark("enqueue")  # the buffer's upload + launch
+        packed = self._dispatch(fn, buf, extras, cp.arrays, group_params)
         # the fetch is requested behind the compute before the host waits
         # for either: block_until_ready alone would put a host round trip
         # between the two (+0.18 ms per dispatch, measured: PERF.md PR 27)
@@ -1735,14 +1777,7 @@ class TpuDriver(InterpDriver):
                 self._cost_kind_counts(), t2 - t1, len(reviews),
                 path="review",
             )
-        c = both.shape[0] // 2
-        # crow maps each ordered constraint to its group-major mask row
-        # (pad block rows drop out here)
-        return (
-            ordered,
-            both[:c][crow][:, :rows].astype(bool),
-            both[c:][crow][:, :rows].astype(bool),
-        )
+        return (ordered,) + self._split_masks(both, crow, rows)
 
     # ---- render (exactness filter) ---------------------------------------
 

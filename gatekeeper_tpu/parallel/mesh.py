@@ -413,10 +413,11 @@ def pipelined_shard_commit(
 
 def shard_review_side(mesh: Mesh, rows: int, rv, cols, record_shard=None):
     """Pad the row axis to a mesh multiple and commit the review-side trees
-    with row-major arrays partitioned on "data" in contiguous slabs
-    (everything else, e.g. vocab-sized tables, replicated) — slab by slab
-    through the double-buffered pipeline (pipelined_shard_commit).
-    Returns (rv, cols, padded_rows)."""
+    (the audit path's `rv` and `cols`; a review dispatch's one buffer and
+    its extras, ops/reviewbuf.py) with row-major arrays partitioned on
+    "data" in contiguous slabs (everything else, e.g. vocab-sized tables,
+    replicated) — slab by slab through the double-buffered pipeline
+    (pipelined_shard_commit).  Returns (rv, cols, padded_rows)."""
     (rv_p, cols_p), target = pipelined_shard_commit(
         mesh, rows, (rv, cols), record_shard=record_shard
     )
@@ -437,20 +438,22 @@ def shard_args(mesh: Mesh, rows: int, args):
 def sharded_masks(driver, reviews, mesh: Mesh):
     """compute_masks, sharded over the mesh: the full evaluation step (match
     kernel + all violation-program groups) jitted once over the mesh with
-    the resource axis partitioned.  Returns (ordered, mask, autoreject) like
-    TpuDriver.compute_masks (R axis trimmed back to the single-device
-    bucket so results compare bit-for-bit)."""
-    fn, ordered, rp, cp, cols, group_params, crow = driver._device_inputs(
-        reviews
-    )
-    rows = len(rp.arrays["valid"])
-    args = (rp.arrays, cp.arrays, cols, group_params)
-    placed, target = shard_args(mesh, rows, args)
+    the resource axis partitioned, in the form a review dispatch takes (the
+    review side as one buffer, partitioned on "data").  Returns (ordered,
+    mask, autoreject) like TpuDriver.compute_masks (R axis trimmed back to
+    the single-device bucket so results compare bit-for-bit)."""
+    fn, ordered, buf, extras, cp, group_params, crow = \
+        driver._packed_inputs(reviews)
+    rows = buf.shape[0]
+    buf_p, extras_p, _target = shard_review_side(mesh, rows, buf, extras)
+    cs_p, gp_p = replicate_tree(mesh, (cp.arrays, group_params))
     with mesh:
-        mask, autoreject = fn(*placed)
-    both = np.asarray(jax.device_get((mask, autoreject)))
-    # crow folds the group-major pad rows out (driver._constraint_side)
-    return ordered, both[0][crow][:, :rows], both[1][crow][:, :rows]
+        # the jit machinery's SPMD compile: a serialized executable pins
+        # a single-device layout
+        packed = fn._jitted(buf_p, extras_p, cs_p, gp_p)
+    both = np.unpackbits(np.asarray(packed), axis=1)
+    mask, autoreject = driver._split_masks(both, crow, rows)
+    return ordered, mask, autoreject
 
 
 def sharded_violation_counts(driver, reviews, mesh: Mesh):

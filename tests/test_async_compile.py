@@ -158,5 +158,7 @@ def test_background_warm_covers_packed_review_fn(async_client):
         c.add_template(t)
         c.add_constraint(k)
     assert driver.wait_ready(timeout=300.0)
-    assert driver._fused_packed is not None
+    # one executable, of the probe review's layout
+    ((pv, layout),) = driver._fused_packed.values()
+    assert pv._tag == "fused-packed" and layout.width > 0
     assert driver._fused_packed_src is driver._fused

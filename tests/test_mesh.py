@@ -132,6 +132,39 @@ def test_shard_args_places_row_arrays_on_data_axis():
     assert all(p is None for p in cs_placed["valid"].sharding.spec)
 
 
+def test_sharded_review_path_takes_the_packed_form():
+    """ISSUE 37: the mesh path takes the form the single-device dispatch
+    takes: the review side is ONE row-major buffer partitioned on "data"
+    (46 arrays were), the packed executable rebuilds `rv` and `cols` from
+    each shard's slab, and the masks equal the plain fused function's on
+    the unpacked arguments."""
+    from gatekeeper_tpu.parallel.mesh import shard_review_side
+
+    driver, reviews = _workload(n_templates=12, n_resources=100)
+    driver.mesh_enabled = False
+    fn, _ordered, buf, extras, _cp, _gp, _crow = driver._packed_inputs(
+        reviews)
+    rows = buf.shape[0]
+    assert buf.dtype == np.int32 and buf.ndim == 2 and extras == ()
+    mesh = audit_mesh(8)
+    buf_p, extras_p, target = shard_review_side(mesh, rows, buf, extras)
+    assert target % 8 == 0 and buf_p.shape == (target, buf.shape[1])
+    assert buf_p.sharding.spec[0] == "data"
+    assert all(p is None for p in buf_p.sharding.spec[1:])
+    np.testing.assert_array_equal(np.asarray(buf_p)[:rows], buf)
+
+    plain, _o, rp, cp, cols, gp, crow = driver._device_inputs(reviews)
+    want_mask, want_rej = jax.jit(plain.__wrapped__)(
+        rp.arrays, cp.arrays, cols, gp)
+    for width in (8, 3):  # 3 never divides the row bucket: padded slabs
+        _o2, mask, rej = sharded_masks(driver, reviews, audit_mesh(width))
+        np.testing.assert_array_equal(
+            mask, np.asarray(want_mask)[crow][:, :rows])
+        np.testing.assert_array_equal(
+            rej, np.asarray(want_rej)[crow][:, :rows])
+    assert mask.any()
+
+
 def test_dryrun_multichip_inprocess():
     """The judge-visible entry: with 8 virtual devices already provisioned
     (conftest), dryrun runs in-process; on a 1-device env it re-execs onto a
