@@ -52,6 +52,31 @@ def _envelope(resp_dict: dict) -> bytes:
     return json.dumps(dict(_ENVELOPE_HEAD, response=resp_dict)).encode()
 
 
+def _review_row(head: list, t_prepare: float, p, t_finalize: float,
+                t_encode: float) -> Optional[list]:
+    """The review path's row (obs/trace.py PATH_REVIEW) of one review
+    the batch lane answered, `frame` .. `encode`, as ``[(stage,
+    opened_at), ...]``: the chunk's ``head``, then what the batcher
+    left on the pending (when it was made; the turn's marks from the
+    drain on, each under its stage's group, up to this member's
+    event.set), then this worker's own instants.  None for a pending
+    that carries no marks (refused, shed, or not the batcher's)."""
+    marks = getattr(p, "marks", None)
+    if not marks:
+        return None
+    t_set = p.t_set
+    row = head + [("prepare", t_prepare), ("batch_queue", p.t_submit)]
+    group = obstrace.REVIEW_BATCH_GROUPS.get
+    for stage, t in marks:
+        if t > t_set:
+            break   # the turn went on after this member was released
+        row.append((group(stage, "batch_post"), t))
+    row.append(("wake", t_set))
+    row.append(("finalize", t_finalize))
+    row.append(("encode", t_encode))
+    return row
+
+
 class _DoorConn(Conn):
     """One front-door connection: an incremental frame decoder feeding
     whole request chunks to the listener."""
@@ -59,6 +84,7 @@ class _DoorConn(Conn):
     def __init__(self, listener: "WireListener", loop: EventLoop, sock):
         self.listener = listener
         self.decoder = wireproto.FrameDecoder()
+        self.t_read = 0.0   # when the loop woke for the newest recv
         super().__init__(loop, sock)
 
     # the loop thread's share of the wire clock: `read` (recv),
@@ -66,7 +92,7 @@ class _DoorConn(Conn):
     # clock is stopped while the loop waits in select
     def _readable(self) -> None:
         clock = self.listener._loop_clock()
-        clock.mark("read")
+        self.t_read = clock.mark("read")
         try:
             super()._readable()
         finally:
@@ -80,14 +106,22 @@ class _DoorConn(Conn):
         finally:
             clock.stop()
 
-    def send_frame(self, data: bytes) -> None:
-        """A response frame, written on the loop thread."""
+    def send_frame(self, data: bytes, rows: Optional[list] = None) -> None:
+        """A response frame, written on the loop thread.  ``rows`` are
+        the review path's rows of the frame's reviews, built by the
+        worker up to `handoff`: `write` is opened and closed here, and
+        each row booked on the listener's one review clock."""
         clock = self.listener._loop_clock()
-        clock.mark("write")
+        t_write = clock.mark("write")
         try:
             self.write(data)
         finally:
-            clock.stop()
+            t_end = clock.stop()
+        if rows and not self.closed:
+            book = self.listener._rclock.add_timeline
+            for row in rows:
+                row.append(("write", t_write))
+                book(row, t_end)
 
     def on_bytes(self, data: bytes) -> None:
         self.listener._loop_clock().mark("decode")
@@ -156,6 +190,9 @@ class WireListener:
         self._wflush_t = time.monotonic()
         self._lclock = None   # the loop thread's wire clock (made there)
         self._wclocks: list = []   # the workers' (flushed at stop)
+        # the review path's totals: written by the loop thread alone
+        # (send_frame), flushed with the loop's own clock
+        self._rclock = obstrace.StageClock(obstrace.PATH_REVIEW)
 
     # ---- lifecycle -------------------------------------------------------
 
@@ -244,11 +281,13 @@ class WireListener:
         if force:
             # stop(): loop and workers are gone; what their stage clocks
             # still hold reaches the counters from this thread
-            for clock in [self._lclock] + self._wclocks:
+            for clock in [self._lclock, self._rclock] + self._wclocks:
                 if clock is not None:
                     clock.flush()
         elif self._lclock is not None:
-            self._lclock.flush_due(time.perf_counter())  # the tick hook
+            now_pc = time.perf_counter()   # the tick hook
+            self._lclock.flush_due(now_pc)
+            self._rclock.flush_due(now_pc)
         with self._mu:
             if not self._wstats and not self._wrecs:
                 return
@@ -291,7 +330,8 @@ class WireListener:
 
     def _submit(self, conn: _DoorConn, records: list) -> None:
         try:
-            self._q.put_nowait((conn, records, time.perf_counter()))
+            self._q.put_nowait(
+                (conn, records, conn.t_read, time.perf_counter()))
         except queue.Full:
             # bounded handoff: shed the WHOLE chunk with explicit
             # overload verdicts — the same 200-wrapped 429 shape the
@@ -340,13 +380,19 @@ class WireListener:
             item = self._q.get()
             if item is None or self._stop.is_set():
                 return
-            conn, records, t_put = item
+            conn, records, t_read, t_put = item
             # `queued`: the chunk's wait for a free worker, booked beside
             # the stages (it is no thread's time)
-            clock.add(obstrace.QUEUED, clock.mark("decode") - t_put)
+            t_decode = clock.mark("decode")
+            clock.add(obstrace.QUEUED, t_decode - t_put)
+            # the review path's rows of this chunk's reviews: every
+            # member carries the chunk's shared intervals whole
+            rows: Optional[list] = []
+            head = [("frame", t_read), (obstrace.QUEUED, t_put),
+                    ("decode", t_decode)]
             try:
                 data = wireproto.encode_response_chunk(
-                    self._process(records, clock))
+                    self._process(records, clock, head, rows))
             except Exception:
                 # chunk processing or framing failed (e.g. amplified
                 # deny messages pushed the response payload over
@@ -355,10 +401,19 @@ class WireListener:
                 # expiry — forever with no admission budget configured
                 log.exception("wire chunk processing failed")
                 data = self._failure_chunk(records)
+                rows = None   # answered by the fallback: nothing booked
             if data is not None:
                 self._wire_note("response_chunks", 1)
                 self._wire_note("bytes_out", len(data))
                 self._wire_sample("response", len(records))
+            if rows:
+                # `handoff` opens before the frame is posted (one clock
+                # read a chunk): the post, the loop's wake-up and whatever
+                # it runs first.  The wire clock's `encode` runs on past
+                # the post as it always did, to this thread's stop()
+                t_post = time.perf_counter()
+                for row in rows:
+                    row.append(("handoff", t_post))
             loop = self._loop
             if loop is not None and not conn.closed:
                 if data is None:
@@ -368,8 +423,8 @@ class WireListener:
                     loop.call_soon_threadsafe(
                         lambda c=conn: c.close(None))
                 else:
-                    loop.call_soon_threadsafe(lambda c=conn, d=data:
-                                              c.send_frame(d))
+                    loop.call_soon_threadsafe(lambda c=conn, d=data, r=rows:
+                                              c.send_frame(d, r))
             clock.flush_due(clock.stop())
 
     def _failure_chunk(self, records: list) -> Optional[bytes]:
@@ -392,10 +447,14 @@ class WireListener:
             log.exception("wire failure-chunk fallback failed")
             return None
 
-    def _process(self, records: list,
-                 clock=obstrace.NOOP_CLOCK) -> List[wireproto.ResponseRecord]:
+    def _process(self, records: list, clock=obstrace.NOOP_CLOCK,
+                 head: Optional[list] = None, rows: Optional[list] = None
+                 ) -> List[wireproto.ResponseRecord]:
         """One request chunk -> its response records.  ``clock`` is the
-        calling worker's wire clock, open in ``decode``."""
+        calling worker's wire clock, open in ``decode``.  ``rows``, when
+        the worker passes a list, gets the review path's row of every
+        review the batch lane answered, from ``head`` (the chunk's
+        `frame`, `queued`, `decode`) up to `encode`."""
         out: List[Optional[wireproto.ResponseRecord]] = [None] * len(records)
         server = self.server
         stopping = bool(server is not None
@@ -442,7 +501,7 @@ class WireListener:
         # prepare: budgets and root spans here, then handle_many's
         # checks and review augmentation up to the batcher enqueue
         # (handle_many marks wait / finalize on this thread's clock)
-        clock.mark("prepare")
+        t_prepare = clock.mark("prepare")
         batch: List[tuple] = []   # (pos, req, deadline, span)
         roots: dict = {}          # pos -> (rootctx, req)
         for pos, rec, req in parsed:
@@ -469,16 +528,23 @@ class WireListener:
                 continue
             batch.append((pos, req, deadline, rootctx.span))
         if batch:
+            timeline: list = []
             try:
                 resps = self.handler.handle_many(
-                    [(req, dl, span) for _pos, req, dl, span in batch])
+                    [(req, dl, span) for _pos, req, dl, span in batch],
+                    timeline=None if rows is None else timeline)
             except Exception as e:   # handler defect: per-chunk fallback
                 log.exception("bad admission request")
                 from ..webhook.policy import AdmissionResponse
 
                 resps = [AdmissionResponse(False, str(e), 500)
                          for _ in batch]
-            clock.mark("encode")
+                timeline = []
+            t_encode = clock.mark("encode")
+            for _idx, p, t_finalize in timeline:
+                row = _review_row(head, t_prepare, p, t_finalize, t_encode)
+                if row is not None:
+                    rows.append(row)
             for (pos, req, _dl, span), resp in zip(batch, resps):
                 span.set_attrs(allowed=resp.allowed, code=resp.code)
                 out[pos] = wireproto.ResponseRecord(

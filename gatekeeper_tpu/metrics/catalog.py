@@ -103,11 +103,6 @@ BATCH_SIZE_M = Measure(
     "webhook_batch_size",
     "Admission reviews coalesced into one batched evaluation",
 )
-PACK_M = Measure(
-    "tpu_pack_seconds",
-    "Host-side tensor packing time per evaluation (reviews + columns)",
-    unit="s",
-)
 COMPILE_M = Measure(
     "tpu_compile_seconds",
     "XLA trace+compile time per fused-executable build (cache misses only)",
@@ -712,8 +707,6 @@ def catalog_views():
              AGG_DISTRIBUTION, buckets=_STAGE_BUCKETS),
         View("webhook_batch_size", BATCH_SIZE_M, AGG_DISTRIBUTION,
              buckets=_BATCH_SIZE_BUCKETS),
-        View("tpu_pack_seconds", PACK_M, AGG_DISTRIBUTION,
-             tag_keys=("path",), buckets=_STAGE_BUCKETS),
         View("tpu_compile_seconds", COMPILE_M, AGG_DISTRIBUTION,
              tag_keys=("path",), buckets=_STAGE_BUCKETS),
         View("tpu_dispatch_seconds", DISPATCH_M, AGG_DISTRIBUTION,
@@ -1056,7 +1049,7 @@ def record_dropped(site: str) -> None:
 def record_stage(measure: Measure, seconds: float,
                  tags: Optional[Dict[str, str]] = None):
     """One stage-duration sample into the new per-stage histograms
-    (tpu_pack_seconds / tpu_dispatch_seconds / tpu_compile_seconds /
+    (tpu_dispatch_seconds / tpu_compile_seconds /
     webhook_batch_queue_seconds), exemplar-linked to the active trace.
     Guarded: a metrics-layer defect must never fail the admission/audit
     evaluation that is being measured."""

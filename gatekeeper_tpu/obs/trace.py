@@ -635,6 +635,27 @@ PATH_AUDIT = "audit"
 WAIT = "wait"
 QUEUED = "queued"   # wire only: a chunk's delay in the worker hand-off
 
+# The fourth path's unit is a review, not a thread: one row per admission
+# review answered through the batch lane, adjacent stages from the loop
+# thread's wake-up for the recv that completed its request frame to the
+# return of the write() of its response frame, built from the instants
+# the three thread clocks already take (docs/tracing.md "The review
+# path").  Counters only: no annotation, no ring record.
+PATH_REVIEW = "review"
+REVIEW_STAGES = (
+    "frame", "queued", "decode", "prepare", "batch_queue", "batch_pre",
+    "dispatch", "render", "batch_post", "wake", "finalize", "encode",
+    "handoff", "write",
+)
+# the batcher's turn, drain -> a member's event.set, is tiled by the
+# marks of its clock; each interval goes to the group of the stage that
+# was open in it, and a stage named nowhere here to batch_post
+REVIEW_BATCH_GROUPS = {
+    "collect": "batch_pre", "route": "batch_pre", "pack": "batch_pre",
+    "enqueue": "dispatch", "device_wait": "dispatch", "fetch": "dispatch",
+    "join_lookup": "render", "render": "render",
+}
+
 # thread ident -> the innermost running clock: where the collector's
 # hook books a pause (GIL-atomic dict ops, like _ACTIVE_BY_THREAD)
 _RUNNING_CLOCKS: Dict[int, "StageClock"] = {}
@@ -660,7 +681,7 @@ class StageClock:
 
     __slots__ = ("path", "stage", "t", "totals", "gc_full_s", "_ann",
                  "_outer", "_flushed", "_lapped", "_gc_full_lapped",
-                 "flushed_at")
+                 "flushed_at", "_lap")
 
     def __init__(self, path: str, start: Optional[float] = None):
         self.path = path
@@ -674,6 +695,7 @@ class StageClock:
         self._flushed: Optional[Dict[str, tuple]] = None
         self._lapped: Optional[Dict[str, tuple]] = None
         self._gc_full_lapped = 0.0
+        self._lap: Optional[list] = None   # begin_lap()'s list, if kept
         self.flushed_at = self.t   # perf_counter of the last flush
         if _ANNOTATION is None and "jax.profiler" in sys.modules:
             _bind_annotation()
@@ -717,6 +739,8 @@ class StageClock:
             self._close(opened, now)
         self.stage = stage
         self.t = now
+        if self._lap is not None:
+            self._lap.append((stage, now))
         if opened is None:
             # onto the collector's map only with a stage open
             ident = threading.get_ident()
@@ -733,6 +757,55 @@ class StageClock:
         only, and no part of the thread's own contiguous time."""
         self._account(stage, seconds)
 
+    def begin_lap(self) -> list:
+        """From now on keep the ``(stage, instant)`` pair of every mark
+        in ONE new list, seeded with the stage open now, and return it:
+        the batcher hands a turn's list to every member of its batch,
+        shared and not copied.  The next begin_lap() starts another;
+        stop() ends the keeping.  On a stopped clock: nothing kept."""
+        if self.stage is None:
+            self._lap = None
+            return []
+        self._lap = lap = [(self.stage, time.perf_counter())]
+        return lap
+
+    def add_timeline(self, row: list, end: float) -> None:
+        """Book one review's row (path ``review``): ``row`` is
+        ``[(stage, opened_at), ...]`` in time order and tiles
+        ``[row[0][1], end]``, so a stage's seconds are the sum of the
+        intervals under its name and all of them sum to the span
+        exactly.  A stage of zero seconds did not happen and counts no
+        call; the last one (``write``) always counts: its calls are the
+        reviews booked.  Generation-2 pauses that began inside an
+        interval go to its stage's collector seconds (no instant is
+        taken during a collection, so an interval holds a pause whole
+        or not at all).  Counters only, as add() is."""
+        sums: Dict[str, float] = {}
+        begin = row[0][1]
+        stage, t = row[0]
+        for nxt, t_nxt in row[1:]:
+            sums[stage] = sums.get(stage, 0.0) + (t_nxt - t)
+            stage, t = nxt, t_nxt
+        sums[stage] = sums.get(stage, 0.0) + (end - t)
+        totals = self.totals
+        for name, seconds in sums.items():
+            if seconds > 0.0 or name == stage:
+                acc = totals.get(name)
+                if acc is None:
+                    acc = totals[name] = [0.0, 0, 0.0]
+                acc[0] += seconds
+                acc[1] += 1
+        if _GC_FULL_LAST_STOP[0] <= begin:
+            return   # the common case: no full collection since it began
+        for start, stop in _GC_FULL_RECENT:
+            if begin <= start < end:
+                name = row[0][0]
+                for nxt, t_nxt in row:
+                    if t_nxt > start:
+                        break
+                    name = nxt
+                totals[name][2] += stop - start
+
     def stop(self) -> float:
         """Close the open stage; the clock is stopped until the next
         mark (time until then belongs to no stage)."""
@@ -748,6 +821,7 @@ class StageClock:
             else:
                 _RUNNING_CLOCKS.pop(ident, None)
             self.stage = None
+            self._lap = None
         return now
 
     def _since(self, seen: Dict[str, tuple]) -> Dict[str, tuple]:
@@ -816,6 +890,9 @@ class _NoopClock:
     def add(self, stage: str, seconds: float) -> None:
         pass
 
+    def begin_lap(self) -> tuple:
+        return ()
+
     def lapse(self) -> tuple:
         return {}, 0.0
 
@@ -862,6 +939,11 @@ _GC_PAUSE_S = [0.0, 0.0, 0.0]
 _GC_RUNS = [0, 0, 0]
 _GC_BACKGROUND_S = [0.0]
 _GC_OPEN: list = []   # (start, annotation) of the collection in progress
+# the last few generation-2 pauses as (start, stop), for the review
+# path's booking (StageClock.add_timeline): a fixed-length ring and the
+# newest stop, plain stores under the GIL
+_GC_FULL_RECENT: list = [(0.0, 0.0)] * 8
+_GC_FULL_LAST_STOP = [0.0]
 _GC_PUSHED = {"pause": [0.0, 0.0, 0.0], "runs": [0, 0, 0], "bg": 0.0,
               "cpu": 0.0, "heap": 0}
 _GC_PUSH_LOCK = threading.Lock()   # two scrapes must not push one growth
@@ -883,6 +965,9 @@ def _gc_callback(phase: str, info: dict) -> None:
     if ann is not None:
         ann.__exit__(None, None, None)
     _GC_PAUSE_S[gen] += pause
+    if gen == 2:
+        _GC_FULL_RECENT[_GC_RUNS[2] % len(_GC_FULL_RECENT)] = (t0, t0 + pause)
+        _GC_FULL_LAST_STOP[0] = t0 + pause
     _GC_RUNS[gen] += 1
     clock = _RUNNING_CLOCKS.get(threading.get_ident())
     if clock is None:

@@ -33,7 +33,6 @@ from .. import deadline as _deadline
 from .. import faults
 from ..metrics.catalog import (
     DISPATCH_M,
-    PACK_M,
     record_cache,
     record_cs_refresh,
     record_dispatch_upload,
@@ -1770,7 +1769,6 @@ class TpuDriver(InterpDriver):
             "tpu.dispatch", t1, t2, stage=obstrace.DISPATCH,
             tier="tpu", breaker=self.breaker.state, rows=rows,
         )
-        record_stage(PACK_M, t1 - t0, {"path": "review"})
         record_stage(DISPATCH_M, t2 - t1, {"path": "review", "tier": "tpu"})
         if obscosts.enabled():
             obscosts.record_dispatch(
@@ -3107,7 +3105,6 @@ class TpuDriver(InterpDriver):
                 "np.eval", t_synced, t_served, stage=obstrace.DISPATCH,
                 tier="numpy", breaker=self.breaker.state,
             )
-            record_stage(PACK_M, t_synced - t_locked, {"path": "review"})
             record_stage(
                 DISPATCH_M, t_served - t_synced,
                 {"path": "review", "tier": "numpy"},
@@ -3740,7 +3737,6 @@ class TpuDriver(InterpDriver):
         )
         obstrace.record_span("audit.fetch", t2, t3, stage=obstrace.FETCH,
                              fetch_bytes=float(packed.nbytes))
-        record_stage(PACK_M, t1 - t0, {"path": "audit"})
         record_stage(DISPATCH_M, t2 - t1, {"path": "audit", "tier": "tpu"})
         if obscosts.enabled():
             obscosts.record_dispatch(

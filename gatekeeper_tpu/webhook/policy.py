@@ -259,7 +259,9 @@ class ValidationHandler:
             if self.reporter is not None:
                 self.reporter.report_request(status, duration_s)
 
-    def handle_many(self, items: List[tuple]) -> List[AdmissionResponse]:
+    def handle_many(self, items: List[tuple],
+                    timeline: Optional[list] = None
+                    ) -> List[AdmissionResponse]:
         """Chunk admission for the wire listener (ISSUE 19): evaluate N
         parsed requests with ONE batcher enqueue instead of N.
 
@@ -274,7 +276,14 @@ class ValidationHandler:
         submit_many/wait chunk API when it has one (the MicroBatcher),
         so the whole chunk costs one producer-lock round.  Traced
         requests and clients without submit_many fall back to the
-        per-request review path."""
+        per-request review path.
+
+        ``timeline``, when the wire listener passes a list, gets one
+        ``(index into items, pending, instant)`` for every review that
+        got its verdict from the batch lane: the batcher's pending and
+        the instant this thread opened ``finalize`` for it — what the
+        review path's row needs of this call (obs/trace.py
+        PATH_REVIEW)."""
         # the calling wire worker's stage clock (open in `prepare`), or
         # the no-op clock for any other caller
         clock = obstrace.running_clock(obstrace.PATH_WIRE)
@@ -373,8 +382,10 @@ class ValidationHandler:
                 clock.mark(obstrace.WAIT)
                 try:
                     resp_obj = waiter(p)
-                    clock.mark("finalize")
+                    t_finalize = clock.mark("finalize")
                     results = resp_obj.results()
+                    if timeline is not None:
+                        timeline.append((idx, p, t_finalize))
                 except Exception as e:
                     clock.mark("finalize")
                     out[idx] = self._finalize_failure(

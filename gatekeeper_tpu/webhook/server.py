@@ -63,7 +63,7 @@ _SPAN_CURRENT = object()  # _Pending sentinel: adopt the caller's span
 class _Pending:
     __slots__ = (
         "obj", "event", "result", "error", "deadline", "low_value",
-        "span", "queue_span",
+        "span", "queue_span", "t_submit", "marks", "t_set",
     )
 
     def __init__(self, obj, deadline: Optional[float] = None,
@@ -89,6 +89,20 @@ class _Pending:
             )
             if self.span is not None else None
         )
+        # the review path's row (obs/trace.py PATH_REVIEW): when this
+        # was made; the batcher turn's marks, shared by the batch, and
+        # the instant of this member's event.set() once it was served
+        self.t_submit = (self.queue_span.start if self.queue_span
+                         is not None else time.perf_counter())
+        self.marks: Optional[list] = None
+        self.t_set = 0.0
+
+    def served(self, marks: list) -> None:
+        """Release the waiter of a member the batch lane served (result
+        or error already stored), leaving it the turn's marks."""
+        self.marks = marks
+        self.t_set = time.perf_counter()
+        self.event.set()
 
 
 class MicroBatcher:
@@ -576,10 +590,13 @@ class MicroBatcher:
                 last_batch_size = len(batch)
                 self._busy = True
             # the batch is drained: queue-wait ends here for every member
-            # (deadline-refused ones included — their wait was real)
+            # (deadline-refused ones included — their wait was real), at
+            # the instant the turn's lap begins: the marks from here to a
+            # member's event.set() are its share of the review path
+            marks = clock.begin_lap()
             for p in batch:
                 if p.queue_span is not None:
-                    p.queue_span.end()
+                    p.queue_span.end(marks[0][1])
                     record_stage(
                         WEBHOOK_QUEUE_M,
                         p.queue_span.stop - p.queue_span.start,
@@ -637,7 +654,7 @@ class MicroBatcher:
                     clock.mark("release")
                     for p, resp in zip(batch, responses):
                         p.result = resp
-                        p.event.set()
+                        p.served(marks)
             except Exception:
                 # batched failure: fall back to per-request evaluation so one
                 # poisoned review can't fail the whole window — but check
@@ -654,6 +671,9 @@ class MicroBatcher:
                     btoken = None
                     bsp.end()
                     bsp = None
+                # the review path books a fallback's whole turn, drain to
+                # set, as one `render` interval (docs/tracing.md)
+                marks = [(obstrace.RENDER, marks[0][1])]
                 for p in batch:
                     if (
                         p.deadline is not None
@@ -673,7 +693,7 @@ class MicroBatcher:
                             p.result = self._client.review(p.obj)
                     except Exception as e:
                         p.error = e
-                    p.event.set()
+                    p.served(marks)
             finally:
                 if btoken is not None:
                     obstrace.deactivate(btoken)

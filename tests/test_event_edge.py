@@ -51,7 +51,7 @@ class _Handler:
     def __init__(self):
         self.batches = []
 
-    def handle_many(self, items):
+    def handle_many(self, items, timeline=None):
         self.batches.append(items)
         return [_Resp(True, "ok") for _ in items]
 
@@ -412,7 +412,7 @@ class TestDeadlinePropagation:
         seen = []
 
         class H(_Handler):
-            def handle_many(self, items):
+            def handle_many(self, items, timeline=None):
                 seen.extend(dl for _req, dl, _sp in items)
                 return super().handle_many(items)
 

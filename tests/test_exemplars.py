@@ -125,14 +125,16 @@ def test_stage_records_capture_trace_exemplars():
     cat._GLOBAL_READY = False
     try:
         with obstrace.root_span("t") as sp:
-            cat.record_stage(catalog.PACK_M, 0.001, {"path": "review"})
+            cat.record_stage(catalog.DISPATCH_M, 0.001,
+                             {"path": "review", "tier": "tpu"})
             tid = sp.trace.trace_id
-        rows = reg.view_rows("tpu_pack_seconds")
-        dist = rows[("review",)]
+        rows = reg.view_rows("tpu_dispatch_seconds")
+        dist = rows[("review", "tpu")]
         assert len(dist.exemplars) == 1
         ex = next(iter(dist.exemplars.values()))
         assert ex.trace_id == tid and ex.value == pytest.approx(0.001)
-        cat.record_stage(catalog.PACK_M, 0.001, {"path": "review"})
+        cat.record_stage(catalog.DISPATCH_M, 0.001,
+                         {"path": "review", "tier": "tpu"})
         assert len(dist.exemplars) == 1  # no trace, no new exemplar...
     finally:
         views_mod._global = old_global

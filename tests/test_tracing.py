@@ -412,14 +412,12 @@ class TestStageMetricsExposition:
         for needle in (
             "# TYPE gatekeeper_webhook_batch_queue_seconds histogram",
             "# TYPE gatekeeper_webhook_batch_size histogram",
-            "# TYPE gatekeeper_tpu_pack_seconds histogram",
             "# TYPE gatekeeper_tpu_compile_seconds histogram",
             "# TYPE gatekeeper_tpu_dispatch_seconds histogram",
             "# TYPE gatekeeper_cache_requests_total counter",
         ):
             assert needle in out
         # real samples landed from the traffic above
-        assert 'gatekeeper_tpu_pack_seconds_bucket{path="review"' in out
         assert ('gatekeeper_tpu_dispatch_seconds_bucket{path="review",'
                 'tier="tpu"') in out
         assert 'cache_requests_total{cache="request_memo",outcome="miss"}' \

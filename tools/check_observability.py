@@ -56,6 +56,13 @@ tests/test_observability_check.py; also runnable standalone):
     reactor-health section present in docs/fleet.md — the flight deck
     is an operator contract like every other surface here.
 
+11. Review-path conformance (ISSUE 38): the stage clock's `review`
+    path books the stages of obs/trace.py REVIEW_STAGES and no others;
+    the table under "The `review` path" in docs/tracing.md must list
+    exactly those, in that order, and every group the batcher's stages
+    fold into (REVIEW_BATCH_GROUPS) must be one of them — the thirteen
+    `review_*` benchmark metrics name these label values.
+
 Run: python tools/check_observability.py   (exit 0 clean, 1 with findings)
 """
 
@@ -529,6 +536,54 @@ def check_reactor_conformance() -> list:
     return problems
 
 
+_REVIEW_HEADING = "### The `review` path"
+_TABLE_STAGE = re.compile(r"^\| `([a-z_]+)` \|")
+
+
+def check_review_stages() -> list:
+    """obs/trace.py REVIEW_STAGES vs the stage table of docs/tracing.md
+    "The `review` path", held to each other as WIRE_STAGES is."""
+    from gatekeeper_tpu.obs import trace
+
+    stages = list(trace.REVIEW_STAGES)
+    problems = [
+        f"REVIEW_BATCH_GROUPS folds {stage!r} into {group!r}, which is "
+        "not in REVIEW_STAGES"
+        for stage, group in trace.REVIEW_BATCH_GROUPS.items()
+        if group not in stages
+    ]
+    doc_path = os.path.join(REPO, "docs", "tracing.md")
+    try:
+        with open(doc_path) as f:
+            doc = f.read()
+    except OSError as e:
+        return problems + [f"docs/tracing.md unreadable: {e}"]
+    _, found, section = doc.partition(_REVIEW_HEADING)
+    if not found:
+        return problems + [
+            f"docs/tracing.md has no {_REVIEW_HEADING!r} section"]
+    documented = []
+    for line in section.split("\n#", 1)[0].splitlines():
+        m = _TABLE_STAGE.match(line)
+        if m and m.group(1) != "stage":
+            documented.append(m.group(1))
+    for s in stages:
+        if s not in documented:
+            problems.append(
+                f"review stage {s!r} (obs/trace.py REVIEW_STAGES) has no "
+                "row in docs/tracing.md's review-path table")
+    for s in documented:
+        if s not in stages:
+            problems.append(
+                f"docs/tracing.md's review-path table lists {s!r}, which "
+                "obs/trace.py REVIEW_STAGES does not book")
+    if not problems and documented != stages:
+        problems.append(
+            "docs/tracing.md's review-path table is not in "
+            f"REVIEW_STAGES' order: {documented} vs {stages}")
+    return problems
+
+
 def run_checks() -> list:
     sys.path.insert(0, REPO)
     return (
@@ -542,6 +597,7 @@ def run_checks() -> list:
         + check_flightrec_conformance()
         + check_decisionlog_conformance()
         + check_reactor_conformance()
+        + check_review_stages()
     )
 
 
