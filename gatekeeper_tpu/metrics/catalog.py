@@ -420,6 +420,14 @@ DISPATCH_UPLOAD_M = Measure(
     "constraint side was missing: nothing on a hit, the str-pred tables "
     "after an extension, every array after a re-pack)",
 )
+REVIEW_PACK_M = Measure(
+    "tpu_review_pack",
+    "Review dispatches by how their review side was packed: direct (the "
+    "native writer filled the one buffer from the reviews by the write "
+    "plan of a width tuple met before) or arrays (the per-field arrays "
+    "were packed, laid out and copied in: the first batch of a width "
+    "tuple, which leaves its plan behind, or no native extension)",
+)
 AOT_LOOKUP_M = Measure(
     "aot_executable_lookups",
     "Calls of an AOT-cached function that named their executable, by "
@@ -795,6 +803,8 @@ def catalog_views():
              tag_keys=("outcome",)),
         View("tpu_dispatch_upload_arrays_total", DISPATCH_UPLOAD_M, AGG_SUM,
              tag_keys=("side",)),
+        View("tpu_review_pack_total", REVIEW_PACK_M, AGG_COUNT,
+             tag_keys=("path",)),
         View("aot_executable_lookups_total", AOT_LOOKUP_M, AGG_COUNT,
              tag_keys=("outcome",)),
         View("compile_epoch_lag", COMPILE_LAG_M, AGG_LAST_VALUE),
@@ -1449,6 +1459,15 @@ def record_dispatch_upload(side: str, arrays: int):
         _global().record(DISPATCH_UPLOAD_M, float(arrays), {"side": side})
     except Exception:  # telemetry never blocks a dispatch
         record_dropped("record_dispatch_upload")
+
+
+def record_review_pack(path: str):
+    """One review dispatch's review side packed
+    (tpu_review_pack_total{path}): direct or arrays."""
+    try:
+        _global().record(REVIEW_PACK_M, 1.0, {"path": path})
+    except Exception:  # telemetry never blocks a dispatch
+        record_dropped("record_review_pack")
 
 
 def record_aot_lookup(outcome: str):

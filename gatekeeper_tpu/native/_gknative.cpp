@@ -13,9 +13,11 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <algorithm>
 #include <cmath>
 #include <cstdint>
 #include <cstring>
+#include <initializer_list>
 #include <string>
 #include <vector>
 
@@ -166,7 +168,141 @@ void intern_labels(PyObject *ids, PyObject *strings, PyObject *labels,
 // ==========================================================================
 // pack_reviews_core
 // ==========================================================================
-//
+
+// cached_ns(ns) through the batch's memo: new reference, nullptr on error
+PyObject *cached_lookup(PyObject *cached_ns, PyObject *ns_memo, PyObject *ns) {
+  PyObject *memo = PyDict_GetItemWithError(ns_memo, ns);
+  if (memo) {
+    Py_INCREF(memo);
+    return memo;
+  }
+  PyErr_Clear();
+  PyObject *got = PyObject_CallFunctionObjArgs(cached_ns, ns, nullptr);
+  if (!got) return nullptr;
+  PyDict_SetItem(ns_memo, ns, got);
+  return got;
+}
+
+// one review's fixed fields (pack.py pack_reviews)
+struct ReviewRow {
+  int32_t group, kind, ns_name;
+  int8_t ns_mode;
+  bool always, ns_empty, is_ns, obj_empty, old_empty, autoreject;
+};
+
+// fills `row` and appends the review's (key, value) label pairs to the
+// three flats, interning in pack_reviews' order; false on a Python error
+bool review_row(PyObject *review, PyObject *ids, PyObject *strings,
+                PyObject *cached_ns, PyObject *ns_memo, ReviewRow &row,
+                std::vector<int32_t> &obj_flat, std::vector<int32_t> &old_flat,
+                std::vector<int32_t> &nsl_flat) {
+  PyObject *rkind_raw =
+      PyDict_Check(review) ? PyDict_GetItemString(review, "kind") : nullptr;
+  PyObject *rkind =
+      (rkind_raw && PyDict_Check(rkind_raw)) ? rkind_raw : nullptr;
+  // NOTE pack.py uses rkind.get("group", _MISSING) — plain get, null is
+  // a value here (intern_value -> NON_STRING), matching the original
+  PyObject *g = rkind ? PyDict_GetItemString(rkind, "group") : nullptr;
+  PyObject *k = rkind ? PyDict_GetItemString(rkind, "kind") : nullptr;
+  row.group = g ? intern_value(ids, strings, g) : UNDEF;
+  row.kind = k ? intern_value(ids, strings, k) : UNDEF;
+
+  bool isns = is_ns_kind(rkind_raw);
+  row.is_ns = isns;
+
+  PyObject *ns = get_field(review, "namespace");  // _get default ""
+  bool nsempty = !ns || (PyUnicode_Check(ns) && PyUnicode_GetLength(ns) == 0);
+  // _get(review,"namespace","") returns "" for missing; == "" only for
+  // string empties; non-string namespace -> not empty
+  if (ns && !PyUnicode_Check(ns)) nsempty = false;
+  row.ns_empty = nsempty;
+  bool alw = !isns && nsempty;
+  row.always = alw;
+
+  // get_ns_name
+  if (isns) {
+    PyObject *obj = get_field(review, "object");
+    PyObject *meta = obj ? get_field(obj, "metadata") : nullptr;
+    PyObject *nm = meta ? get_field(meta, "name") : nullptr;
+    row.ns_name = nm ? intern_value(ids, strings, nm) : UNDEF;
+  } else {
+    row.ns_name = ns ? intern_value(ids, strings, ns) : UNDEF;
+  }
+
+  PyObject *obj = get_field(review, "object");
+  PyObject *old = get_field(review, "oldObject");
+  row.obj_empty = !obj || (PyDict_Check(obj) && PyDict_GET_SIZE(obj) == 0);
+  row.old_empty = !old || (PyDict_Check(old) && PyDict_GET_SIZE(old) == 0);
+
+  PyObject *ometa = obj ? get_field(obj, "metadata") : nullptr;
+  intern_labels(ids, strings, ometa ? get_field(ometa, "labels") : nullptr,
+                obj_flat);
+  PyObject *olmeta = old ? get_field(old, "metadata") : nullptr;
+  intern_labels(ids, strings, olmeta ? get_field(olmeta, "labels") : nullptr,
+                old_flat);
+
+  // namespaceSelector resolution mode + ns labels
+  int8_t mode;
+  PyObject *resolved_ns = nullptr;  // new reference when set
+  if (isns) {
+    mode = 3;
+  } else if (alw) {
+    mode = 0;
+  } else {
+    PyObject *unstable = get_field(review, "_unstable");
+    PyObject *uns = unstable ? get_field(unstable, "namespace") : nullptr;
+    if (uns) {
+      resolved_ns = uns;
+      Py_INCREF(resolved_ns);
+    } else if (ns && PyUnicode_Check(ns)) {
+      resolved_ns = cached_lookup(cached_ns, ns_memo, ns);
+      if (!resolved_ns) return false;
+      if (resolved_ns == Py_None) {
+        Py_DECREF(resolved_ns);
+        resolved_ns = nullptr;
+      }
+    }
+    if (!resolved_ns) {
+      mode = 2;
+    } else {
+      mode = 1;
+      PyObject *nmeta = get_field(resolved_ns, "metadata");
+      intern_labels(ids, strings,
+                    nmeta ? get_field(nmeta, "labels") : nullptr, nsl_flat);
+    }
+  }
+  Py_XDECREF(resolved_ns);
+  row.ns_mode = mode;
+
+  // needs_autoreject for a namespaceSelector constraint (match.py:236):
+  bool rejects = true;
+  PyObject *nsv =
+      PyDict_Check(review) ? PyDict_GetItemString(review, "namespace")
+                           : nullptr;
+  PyObject *ns_str =
+      (nsv && nsv != Py_None && PyUnicode_Check(nsv)) ? nsv : nullptr;
+  // treat null like _get: None -> missing
+  if (nsv == Py_None) ns_str = nullptr;
+  if (ns_str) {
+    PyObject *cached = cached_lookup(cached_ns, ns_memo, ns_str);
+    if (!cached) return false;
+    if (cached != Py_None) rejects = false;
+    Py_DECREF(cached);
+  }
+  if (rejects) {
+    PyObject *unstable = review && PyDict_Check(review)
+                             ? PyDict_GetItemString(review, "_unstable")
+                             : nullptr;
+    if (unstable && PyDict_Check(unstable)) {
+      PyObject *uv = PyDict_GetItemString(unstable, "namespace");
+      if (uv && uv != Py_False) rejects = false;
+    }
+  }
+  if (rejects && ns_str && PyUnicode_GetLength(ns_str) == 0) rejects = false;
+  row.autoreject = rejects;
+  return true;
+}
+
 // Args: reviews(list), ids(dict), strings(list), cached_ns(callable),
 //       dict of preallocated 1-D buffers:
 //         group,kind,ns_name: int32[rows]; ns_mode: int8[rows];
@@ -215,145 +351,28 @@ PyObject *pack_reviews_core(PyObject *, PyObject *args) {
   if (!ns_memo) return nullptr;
 
   for (Py_ssize_t i = 0; i < n; i++) {
-    PyObject *review = PyList_GET_ITEM(reviews, i);
     valid.b()[i] = true;
-
-    PyObject *rkind_raw =
-        PyDict_Check(review) ? PyDict_GetItemString(review, "kind") : nullptr;
-    PyObject *rkind =
-        (rkind_raw && PyDict_Check(rkind_raw)) ? rkind_raw : nullptr;
-    // NOTE pack.py uses rkind.get("group", _MISSING) — plain get, null is
-    // a value here (intern_value -> NON_STRING), matching the original
-    PyObject *g = rkind ? PyDict_GetItemString(rkind, "group") : nullptr;
-    PyObject *k = rkind ? PyDict_GetItemString(rkind, "kind") : nullptr;
-    group.i32()[i] = g ? intern_value(ids, strings, g) : UNDEF;
-    kind.i32()[i] = k ? intern_value(ids, strings, k) : UNDEF;
-
-    bool isns = is_ns_kind(rkind_raw);
-    is_ns.b()[i] = isns;
-
-    PyObject *ns = get_field(review, "namespace");  // _get default ""
-    bool nsempty = !ns || (PyUnicode_Check(ns) && PyUnicode_GetLength(ns) == 0);
-    // _get(review,"namespace","") returns "" for missing; == "" only for
-    // string empties; non-string namespace -> not empty
-    if (ns && !PyUnicode_Check(ns)) nsempty = false;
-    ns_empty.b()[i] = nsempty;
-    bool alw = !isns && nsempty;
-    always.b()[i] = alw;
-
-    // get_ns_name
-    if (isns) {
-      PyObject *obj = get_field(review, "object");
-      PyObject *meta = obj ? get_field(obj, "metadata") : nullptr;
-      PyObject *nm = meta ? get_field(meta, "name") : nullptr;
-      ns_name.i32()[i] = nm ? intern_value(ids, strings, nm) : UNDEF;
-    } else {
-      ns_name.i32()[i] = ns ? intern_value(ids, strings, ns) : UNDEF;
+    size_t obj0 = obj_flat.size(), old0 = old_flat.size(),
+           nsl0 = nsl_flat.size();
+    ReviewRow row;
+    if (!review_row(PyList_GET_ITEM(reviews, i), ids, strings, cached_ns,
+                    ns_memo, row, obj_flat, old_flat, nsl_flat)) {
+      Py_DECREF(ns_memo);
+      return nullptr;
     }
-
-    PyObject *obj = get_field(review, "object");
-    PyObject *old = get_field(review, "oldObject");
-    obj_empty.b()[i] =
-        !obj || (PyDict_Check(obj) && PyDict_GET_SIZE(obj) == 0);
-    old_empty.b()[i] =
-        !old || (PyDict_Check(old) && PyDict_GET_SIZE(old) == 0);
-
-    size_t before = obj_flat.size();
-    PyObject *ometa = obj ? get_field(obj, "metadata") : nullptr;
-    intern_labels(ids, strings, ometa ? get_field(ometa, "labels") : nullptr,
-                  obj_flat);
-    obj_counts[i] = (int32_t)((obj_flat.size() - before) / 2);
-
-    before = old_flat.size();
-    PyObject *olmeta = old ? get_field(old, "metadata") : nullptr;
-    intern_labels(ids, strings, olmeta ? get_field(olmeta, "labels") : nullptr,
-                  old_flat);
-    old_counts[i] = (int32_t)((old_flat.size() - before) / 2);
-
-    // namespaceSelector resolution mode + ns labels
-    before = nsl_flat.size();
-    int8_t mode;
-    PyObject *resolved_ns = nullptr;  // new reference when set
-    if (isns) {
-      mode = 3;
-    } else if (alw) {
-      mode = 0;
-    } else {
-      PyObject *unstable = get_field(review, "_unstable");
-      PyObject *uns = unstable ? get_field(unstable, "namespace") : nullptr;
-      if (uns) {
-        resolved_ns = uns;
-        Py_INCREF(resolved_ns);
-      } else if (ns && PyUnicode_Check(ns)) {
-        PyObject *memo = PyDict_GetItemWithError(ns_memo, ns);
-        if (memo) {
-          resolved_ns = memo;
-          Py_INCREF(resolved_ns);
-        } else {
-          PyErr_Clear();
-          resolved_ns = PyObject_CallFunctionObjArgs(cached_ns, ns, nullptr);
-          if (!resolved_ns) {
-            Py_DECREF(ns_memo);
-            return nullptr;
-          }
-          PyDict_SetItem(ns_memo, ns, resolved_ns);
-        }
-        if (resolved_ns == Py_None) {
-          Py_DECREF(resolved_ns);
-          resolved_ns = nullptr;
-        }
-      }
-      if (!resolved_ns) {
-        mode = 2;
-      } else {
-        mode = 1;
-        PyObject *nmeta = get_field(resolved_ns, "metadata");
-        intern_labels(ids, strings,
-                      nmeta ? get_field(nmeta, "labels") : nullptr, nsl_flat);
-      }
-    }
-    Py_XDECREF(resolved_ns);
-    ns_mode.i8()[i] = mode;
-    ns_counts[i] = (int32_t)((nsl_flat.size() - before) / 2);
-
-    // needs_autoreject for a namespaceSelector constraint (match.py:236):
-    bool rejects = true;
-    PyObject *nsv =
-        PyDict_Check(review) ? PyDict_GetItemString(review, "namespace")
-                             : nullptr;
-    PyObject *ns_str =
-        (nsv && nsv != Py_None && PyUnicode_Check(nsv)) ? nsv : nullptr;
-    // treat null like _get: None -> missing
-    if (nsv == Py_None) ns_str = nullptr;
-    if (ns_str) {
-      PyObject *memo = PyDict_GetItemWithError(ns_memo, ns_str);
-      PyObject *cached;
-      if (memo) {
-        cached = memo;
-        Py_INCREF(cached);
-      } else {
-        PyErr_Clear();
-        cached = PyObject_CallFunctionObjArgs(cached_ns, ns_str, nullptr);
-        if (!cached) {
-          Py_DECREF(ns_memo);
-          return nullptr;
-        }
-        PyDict_SetItem(ns_memo, ns_str, cached);
-      }
-      if (cached != Py_None) rejects = false;
-      Py_DECREF(cached);
-    }
-    if (rejects) {
-      PyObject *unstable = review && PyDict_Check(review)
-                               ? PyDict_GetItemString(review, "_unstable")
-                               : nullptr;
-      if (unstable && PyDict_Check(unstable)) {
-        PyObject *uv = PyDict_GetItemString(unstable, "namespace");
-        if (uv && uv != Py_False) rejects = false;
-      }
-    }
-    if (rejects && ns_str && PyUnicode_GetLength(ns_str) == 0) rejects = false;
-    autoreject.b()[i] = rejects;
+    group.i32()[i] = row.group;
+    kind.i32()[i] = row.kind;
+    ns_name.i32()[i] = row.ns_name;
+    ns_mode.i8()[i] = row.ns_mode;
+    always.b()[i] = row.always;
+    ns_empty.b()[i] = row.ns_empty;
+    is_ns.b()[i] = row.is_ns;
+    obj_empty.b()[i] = row.obj_empty;
+    old_empty.b()[i] = row.old_empty;
+    autoreject.b()[i] = row.autoreject;
+    obj_counts[i] = (int32_t)((obj_flat.size() - obj0) / 2);
+    old_counts[i] = (int32_t)((old_flat.size() - old0) / 2);
+    ns_counts[i] = (int32_t)((nsl_flat.size() - nsl0) / 2);
   }
   Py_DECREF(ns_memo);
 
@@ -428,31 +447,90 @@ PyObject *get_rel(PyObject *obj, PyObject *path) {
   return cur;
 }
 
+// one value as a column cell holds it (columns.py _encode): sid is set for
+// a string only, num for a number only
+struct Encoded {
+  int8_t tcode = T_UNDEF;
+  int32_t sid = ID_MISSING;
+  double num = 0.0;
+};
+
+Encoded encode_value(PyObject *v, PyObject *ids, PyObject *strings) {
+  Encoded e;
+  if (!v) {
+    e.tcode = T_UNDEF;
+  } else if (v == Py_None) {
+    e.tcode = T_NULL;
+  } else if (v == Py_True) {
+    e.tcode = T_TRUE;
+  } else if (v == Py_False) {
+    e.tcode = T_FALSE;
+  } else if (PyUnicode_Check(v)) {
+    e.tcode = T_STR;
+    e.sid = intern(ids, strings, v);
+  } else if (PyLong_Check(v) || PyFloat_Check(v)) {
+    e.tcode = T_NUM;
+    e.num = PyFloat_Check(v) ? PyFloat_AS_DOUBLE(v) : PyLong_AsDouble(v);
+    if (PyErr_Occurred()) {  // int beyond double range
+      PyErr_Clear();
+      e.num = HUGE_VAL;
+    }
+  } else {
+    e.tcode = T_COMP;
+  }
+  return e;
+}
+
 // encode one value into tcode/sid/num at index idx (columns.py _encode)
 void encode_at(PyObject *v, Py_ssize_t idx, int8_t *tcode, int32_t *sid,
                double *num, PyObject *ids, PyObject *strings) {
-  if (!v) {
-    tcode[idx] = T_UNDEF;
-  } else if (v == Py_None) {
-    tcode[idx] = T_NULL;
-  } else if (v == Py_True) {
-    tcode[idx] = T_TRUE;
-  } else if (v == Py_False) {
-    tcode[idx] = T_FALSE;
-  } else if (PyUnicode_Check(v)) {
-    tcode[idx] = T_STR;
-    sid[idx] = intern(ids, strings, v);
-  } else if (PyLong_Check(v) || PyFloat_Check(v)) {
-    tcode[idx] = T_NUM;
-    num[idx] = PyFloat_Check(v) ? PyFloat_AS_DOUBLE(v)
-                                : PyLong_AsDouble(v);
-    if (PyErr_Occurred()) {  // int beyond double range
-      PyErr_Clear();
-      num[idx] = HUGE_VAL;
+  Encoded e = encode_value(v, ids, strings);
+  tcode[idx] = e.tcode;
+  if (e.tcode == T_STR) sid[idx] = e.sid;
+  if (e.tcode == T_NUM) num[idx] = e.num;
+}
+
+// the entities one resource yields over a slot group's iteration paths
+void entities_of(PyObject *resource, PyObject *iter_paths,
+                 std::vector<PyObject *> &hits) {
+  Py_ssize_t np_ = PyTuple_GET_SIZE(iter_paths);
+  for (Py_ssize_t p = 0; p < np_; p++)
+    walk(resource, PyTuple_GET_ITEM(iter_paths, p), 0, hits);
+}
+
+// one resource's keyset (columns.py keyset columns): the interned truthy
+// string keys at the paths, first occurrence only, minus the exclusions,
+// appended to `flat`; false on a Python error
+bool keyset_row(PyObject *resource, PyObject *iter_paths, PyObject *rel_path,
+                PyObject *exclude, PyObject *ids, PyObject *strings,
+                std::vector<PyObject *> &hits, std::vector<int32_t> &flat) {
+  hits.clear();
+  entities_of(resource, iter_paths, hits);
+  PyObject *seen = PySet_New(nullptr);
+  if (!seen) return false;
+  for (PyObject *h : hits) {
+    PyObject *target = PyTuple_GET_SIZE(rel_path) ? get_rel(h, rel_path) : h;
+    if (!target || !PyDict_Check(target)) continue;
+    PyObject *k, *v;
+    Py_ssize_t pos = 0;
+    while (PyDict_Next(target, &pos, &k, &v)) {
+      if (!PyUnicode_Check(k) || v == Py_False) continue;
+      int ex = PySequence_Contains(exclude, k);
+      if (ex != 0) {
+        if (ex < 0) PyErr_Clear();
+        continue;
+      }
+      int sn = PySet_Contains(seen, k);
+      if (sn != 0) {
+        if (sn < 0) PyErr_Clear();
+        continue;
+      }
+      PySet_Add(seen, k);
+      flat.push_back(intern(ids, strings, k));
     }
-  } else {
-    tcode[idx] = T_COMP;
   }
+  Py_DECREF(seen);
+  return true;
 }
 
 // extract_scalar(resources, path, tcode_buf, sid_buf, num_buf, ids, strings)
@@ -480,16 +558,13 @@ PyObject *slot_entities(PyObject *, PyObject *args) {
   PyObject *resources, *iter_paths;
   if (!PyArg_ParseTuple(args, "OO", &resources, &iter_paths)) return nullptr;
   Py_ssize_t n = PyList_GET_SIZE(resources);
-  Py_ssize_t np_ = PyTuple_GET_SIZE(iter_paths);
   PyObject *ents = PyList_New(n);
   if (!ents) return nullptr;
   Py_ssize_t maxw = 0;
   std::vector<PyObject *> hits;
   for (Py_ssize_t i = 0; i < n; i++) {
     hits.clear();
-    for (Py_ssize_t p = 0; p < np_; p++)
-      walk(PyList_GET_ITEM(resources, i), PyTuple_GET_ITEM(iter_paths, p), 0,
-           hits);
+    entities_of(PyList_GET_ITEM(resources, i), iter_paths, hits);
     PyObject *row = PyList_New((Py_ssize_t)hits.size());
     if (!row) {
       Py_DECREF(ents);
@@ -546,41 +621,14 @@ PyObject *keyset(PyObject *, PyObject *args) {
                         &exclude, &ids, &strings))
     return nullptr;
   Py_ssize_t n = PyList_GET_SIZE(resources);
-  Py_ssize_t np_ = PyTuple_GET_SIZE(iter_paths);
   std::vector<int32_t> flat;
   std::vector<int32_t> counts(n);
   std::vector<PyObject *> hits;
   for (Py_ssize_t i = 0; i < n; i++) {
-    hits.clear();
-    for (Py_ssize_t p = 0; p < np_; p++)
-      walk(PyList_GET_ITEM(resources, i), PyTuple_GET_ITEM(iter_paths, p), 0,
-           hits);
     size_t before = flat.size();
-    PyObject *seen = PySet_New(nullptr);
-    if (!seen) return nullptr;
-    for (PyObject *h : hits) {
-      PyObject *target =
-          PyTuple_GET_SIZE(rel_path) ? get_rel(h, rel_path) : h;
-      if (!target || !PyDict_Check(target)) continue;
-      PyObject *k, *v;
-      Py_ssize_t pos = 0;
-      while (PyDict_Next(target, &pos, &k, &v)) {
-        if (!PyUnicode_Check(k) || v == Py_False) continue;
-        int ex = PySequence_Contains(exclude, k);
-        if (ex != 0) {
-          if (ex < 0) PyErr_Clear();
-          continue;
-        }
-        int sn = PySet_Contains(seen, k);
-        if (sn != 0) {
-          if (sn < 0) PyErr_Clear();
-          continue;
-        }
-        PySet_Add(seen, k);
-        flat.push_back(intern(ids, strings, k));
-      }
-    }
-    Py_DECREF(seen);
+    if (!keyset_row(PyList_GET_ITEM(resources, i), iter_paths, rel_path,
+                    exclude, ids, strings, hits, flat))
+      return nullptr;
     counts[i] = (int32_t)(flat.size() - before);
   }
   PyObject *flat_arr = np_empty_i32((Py_ssize_t)flat.size());
@@ -592,6 +640,324 @@ PyObject *keyset(PyObject *, PyObject *args) {
     return nullptr;
   }
   return Py_BuildValue("(NN)", flat_arr, counts_arr);
+}
+
+// ==========================================================================
+// review_scratch / review_write: a review dispatch's buffer, written direct
+// ==========================================================================
+//
+// The review side of a dispatch is one [rows, width] int32 buffer
+// (ops/reviewbuf.py).  pack_reviews_core + the extract_columns cores build
+// it as ~46 padded arrays that are then copied into their columns.  These
+// two passes skip the arrays: review_scratch extracts every field's values
+// per row, as the int32 cells the buffer holds, interning in the order
+// pack_reviews followed by extract_columns interns, and returns the padded
+// widths the batch's data decides; the caller looks up the write plan
+// those widths name, and review_write lays each field into its columns.
+
+constexpr int32_t ID_PAD = -2;  // pack.py PAD == Interner.PAD
+
+// one field: each row's cells (a row may hold fewer than its columns) and
+// what every other cell of its columns reads
+struct Field {
+  int32_t fill = 0;
+  std::vector<int32_t> cells;
+  std::vector<Py_ssize_t> ends;  // ends[i]: one past row i's last cell
+  void end_row() { ends.push_back((Py_ssize_t)cells.size()); }
+  void push_row(int32_t v) {
+    cells.push_back(v);
+    end_row();
+  }
+};
+
+struct Scratch {
+  Py_ssize_t n = 0;
+  std::vector<Field> fields;
+  // the first of k new fields; no pointer into `fields` outlives the next
+  Field *add(std::initializer_list<int32_t> fills) {
+    size_t base = fields.size();
+    fields.resize(base + fills.size());
+    size_t j = base;
+    for (int32_t f : fills) fields[j++].fill = f;
+    return &fields[base];
+  }
+};
+
+const char *SCRATCH_NAME = "gk.review_scratch";
+
+void scratch_free(PyObject *cap) {
+  delete static_cast<Scratch *>(PyCapsule_GetPointer(cap, SCRATCH_NAME));
+}
+
+int32_t f32_bits(double v) {  // numpy's astype(float32), as int32 bits
+  float f = (float)v;
+  int32_t bits;
+  std::memcpy(&bits, &f, sizeof bits);
+  return bits;
+}
+
+Py_ssize_t bucket(Py_ssize_t n) {  // pack.py _bucket(n, 1)
+  Py_ssize_t b = 1;
+  while (b < n) b *= 2;
+  return b;
+}
+
+void push_encoded(Field *f, const Encoded &e) {  // tcode, sid, num
+  f[0].cells.push_back(e.tcode);
+  f[1].cells.push_back(e.sid);
+  f[2].cells.push_back(f32_bits(e.num));
+}
+
+// a joinkey column from its Python extraction (columns.py
+// _extract_joinkey): array [rows] or [rows, W] of int32 or bool, padded
+// already; -> the spec's width (W, 0 for a scalar key)
+bool joinkey_field(PyObject *arr, Field &f, Py_ssize_t n, Py_ssize_t &width) {
+  Buf b;
+  if (!b.acquire(arr)) return false;
+  Py_ssize_t w = b.view.ndim == 2 ? b.view.shape[1] : 1;
+  width = b.view.ndim == 2 ? w : 0;
+  if (b.view.ndim < 1 || b.view.ndim > 2 || b.view.shape[0] < n ||
+      (b.view.itemsize != 4 && b.view.itemsize != 1)) {
+    PyErr_SetString(PyExc_ValueError, "joinkey column: unexpected array");
+    return false;
+  }
+  for (Py_ssize_t i = 0; i < n; i++) {
+    for (Py_ssize_t j = 0; j < w; j++) {
+      Py_ssize_t idx = i * w + j;
+      f.cells.push_back(b.view.itemsize == 4 ? b.i32()[idx]
+                                             : (int32_t)b.i8()[idx]);
+    }
+    f.end_row();
+  }
+  return true;
+}
+
+// review_scratch(reviews, ids, strings, cached_ns, specs, groups, joinkey)
+//   specs: per column spec (kind, rel_path, iter_paths, exclude, group),
+//          kind 0 scalar / 1 slot / 2 keyset / 3 joinkey, group the slot
+//          group's index in `groups` (the groups' iteration paths)
+//   joinkey(i): the dict _extract_joinkey makes of spec i
+//   -> (scratch capsule, widths): widths = the three label groups' padded
+//      widths, then one per spec (0 for a scalar column)
+PyObject *review_scratch(PyObject *, PyObject *args) {
+  PyObject *reviews, *ids, *strings, *cached_ns, *specs, *groups, *joinkey;
+  if (!PyArg_ParseTuple(args, "OOOOOOO", &reviews, &ids, &strings, &cached_ns,
+                        &specs, &groups, &joinkey))
+    return nullptr;
+  if (!PyList_Check(reviews) || !PyDict_Check(ids) || !PyList_Check(strings) ||
+      !PyTuple_Check(specs) || !PyTuple_Check(groups)) {
+    PyErr_SetString(PyExc_TypeError, "bad argument types");
+    return nullptr;
+  }
+  Py_ssize_t n = PyList_GET_SIZE(reviews);
+  Py_ssize_t nspecs = PyTuple_GET_SIZE(specs);
+  auto *sc = new Scratch();
+  sc->n = n;
+  PyObject *cap = PyCapsule_New(sc, SCRATCH_NAME, scratch_free);
+  if (!cap) {
+    delete sc;
+    return nullptr;
+  }
+  PyObject *widths = PyTuple_New(3 + nspecs);
+  if (!widths) {
+    Py_DECREF(cap);
+    return nullptr;
+  }
+  auto fail = [&]() -> PyObject * {
+    Py_DECREF(cap);
+    Py_DECREF(widths);
+    return nullptr;
+  };
+  auto set_width = [&](Py_ssize_t j, Py_ssize_t w) {
+    PyObject *v = PyLong_FromSsize_t(w);
+    if (v) PyTuple_SET_ITEM(widths, j, v);
+    return v != nullptr;
+  };
+
+  // the review's own fields, in reviewbuf.REVIEW_FIELDS' order
+  Field *rf = sc->add({UNDEF, UNDEF, UNDEF, 0, 0, 0, 0, 1, 1, 0, 0, ID_PAD,
+                       ID_PAD, ID_PAD});
+  PyObject *ns_memo = PyDict_New();
+  if (!ns_memo) return fail();
+  for (Py_ssize_t i = 0; i < n; i++) {
+    ReviewRow row;
+    if (!review_row(PyList_GET_ITEM(reviews, i), ids, strings, cached_ns,
+                    ns_memo, row, rf[11].cells, rf[12].cells, rf[13].cells)) {
+      Py_DECREF(ns_memo);
+      return fail();
+    }
+    int32_t vals[11] = {row.group,      row.kind,      row.ns_name,
+                        row.ns_mode,    row.always,    row.ns_empty,
+                        row.is_ns,      row.obj_empty, row.old_empty,
+                        row.autoreject, 1};
+    for (int j = 0; j < 11; j++) rf[j].push_row(vals[j]);
+    for (int j = 11; j < 14; j++) rf[j].end_row();
+  }
+  Py_DECREF(ns_memo);
+  for (int j = 0; j < 3; j++) {
+    Field &lab = rf[11 + j];
+    Py_ssize_t most = 0, start = 0;
+    for (Py_ssize_t e : lab.ends) {
+      most = std::max(most, (e - start) / 2);
+      start = e;
+    }
+    if (!set_width(j, bucket(most))) return fail();
+  }
+
+  // every slot group's entities (no interning), as slot_entities finds them;
+  // borrowed from `reviews`, which nothing below mutates
+  Py_ssize_t ngroups = PyTuple_GET_SIZE(groups);
+  std::vector<std::vector<std::vector<PyObject *>>> ents(ngroups);
+  std::vector<Py_ssize_t> group_width(ngroups);
+  for (Py_ssize_t g = 0; g < ngroups; g++) {
+    Py_ssize_t most = 0;
+    ents[g].resize(n);
+    for (Py_ssize_t i = 0; i < n; i++) {
+      entities_of(PyList_GET_ITEM(reviews, i), PyTuple_GET_ITEM(groups, g),
+                  ents[g][i]);
+      most = std::max(most, (Py_ssize_t)ents[g][i].size());
+    }
+    group_width[g] = bucket(most);
+  }
+
+  // the columns, spec by spec, rows in order: extract_columns' interning
+  std::vector<PyObject *> hits;
+  for (Py_ssize_t s = 0; s < nspecs; s++) {
+    PyObject *spec = PyTuple_GET_ITEM(specs, s);
+    if (!PyTuple_Check(spec) || PyTuple_GET_SIZE(spec) != 5 ||
+        !PyTuple_Check(PyTuple_GET_ITEM(spec, 1)) ||
+        !PyTuple_Check(PyTuple_GET_ITEM(spec, 2))) {
+      PyErr_SetString(PyExc_TypeError, "a spec is (kind, rel_path, "
+                      "iter_paths, exclude, group) with tuple paths");
+      return fail();
+    }
+    long kind = PyLong_AsLong(PyTuple_GET_ITEM(spec, 0));
+    PyObject *rel_path = PyTuple_GET_ITEM(spec, 1);
+    PyObject *iter_paths = PyTuple_GET_ITEM(spec, 2);
+    Py_ssize_t width = 0;
+    if (kind == 0) {  // scalar: tcode, sid, num
+      Field *f = sc->add({T_UNDEF, ID_MISSING, 0});
+      for (Py_ssize_t i = 0; i < n; i++) {
+        hits.clear();
+        walk(PyList_GET_ITEM(reviews, i), rel_path, 0, hits);
+        push_encoded(f, encode_value(hits.empty() ? nullptr : hits[0], ids,
+                                     strings));
+        for (int j = 0; j < 3; j++) f[j].end_row();
+      }
+    } else if (kind == 1) {  // slot: tcode, sid, num, mask per entity
+      Py_ssize_t g = PyLong_AsSsize_t(PyTuple_GET_ITEM(spec, 4));
+      if (g < 0 || g >= ngroups) {
+        PyErr_SetString(PyExc_ValueError, "slot spec without its group");
+        return fail();
+      }
+      Field *f = sc->add({T_UNDEF, ID_MISSING, 0, 0});
+      bool rel = PyTuple_GET_SIZE(rel_path) > 0;
+      for (Py_ssize_t i = 0; i < n; i++) {
+        for (PyObject *ent : ents[g][i]) {
+          push_encoded(f, encode_value(rel ? get_rel(ent, rel_path) : ent,
+                                       ids, strings));
+          f[3].cells.push_back(1);
+        }
+        for (int j = 0; j < 4; j++) f[j].end_row();
+      }
+      width = group_width[g];
+    } else if (kind == 2) {  // keyset: ids
+      Field *f = sc->add({ID_PAD});
+      Py_ssize_t most = 0;
+      for (Py_ssize_t i = 0; i < n; i++) {
+        size_t before = f->cells.size();
+        if (!keyset_row(PyList_GET_ITEM(reviews, i), iter_paths, rel_path,
+                        PyTuple_GET_ITEM(spec, 3), ids, strings, hits,
+                        f->cells))
+          return fail();
+        most = std::max(most, (Py_ssize_t)(f->cells.size() - before));
+        f->end_row();
+      }
+      width = bucket(most);
+    } else if (kind == 3) {  // joinkey: sid (and mask for a slot key)
+      PyObject *cols = PyObject_CallFunction(joinkey, "n", s);
+      if (!cols) return fail();
+      bool slot = PyTuple_GET_SIZE(iter_paths) > 0;
+      const char *names[2] = {"sid", "mask"};
+      Field *f = slot ? sc->add({ID_MISSING, 0}) : sc->add({ID_MISSING});
+      for (int j = 0; j < (slot ? 2 : 1); j++) {
+        PyObject *arr =
+            PyDict_Check(cols) ? PyDict_GetItemString(cols, names[j]) : nullptr;
+        if (!arr) {
+          PyErr_Format(PyExc_ValueError, "joinkey column without %s",
+                       names[j]);
+          Py_DECREF(cols);
+          return fail();
+        }
+        if (!joinkey_field(arr, f[j], n, width)) {
+          Py_DECREF(cols);
+          return fail();
+        }
+      }
+      Py_DECREF(cols);
+    } else {
+      PyErr_Format(PyExc_ValueError, "unknown column kind %ld", kind);
+      return fail();
+    }
+    if (!set_width(3 + s, width)) return fail();
+  }
+  return Py_BuildValue("(NN)", cap, widths);
+}
+
+// review_write(scratch, plan, rows, width) -> buf [rows, width] int32
+//   plan: int32 [L, 3], per buffer slot (field, column offset, columns);
+//   the slots tile the width, so every cell is written
+PyObject *review_write(PyObject *, PyObject *args) {
+  PyObject *cap, *plan_obj;
+  Py_ssize_t rows, width;
+  if (!PyArg_ParseTuple(args, "OOnn", &cap, &plan_obj, &rows, &width))
+    return nullptr;
+  auto *sc = static_cast<Scratch *>(PyCapsule_GetPointer(cap, SCRATCH_NAME));
+  if (!sc) return nullptr;
+  Buf plan;
+  if (!plan.acquire(plan_obj)) return nullptr;
+  if (plan.view.ndim != 2 || plan.view.shape[1] != 3 ||
+      plan.view.itemsize != 4 || rows < sc->n) {
+    PyErr_SetString(PyExc_ValueError, "bad write plan");
+    return nullptr;
+  }
+  PyObject *buf_obj = np_empty_i32(rows, width);
+  if (!buf_obj) return nullptr;
+  Buf buf;
+  if (!buf.acquire(buf_obj)) {
+    Py_DECREF(buf_obj);
+    return nullptr;
+  }
+  int32_t *out = buf.i32();
+  const int32_t *p = plan.i32();
+  for (Py_ssize_t e = 0; e < plan.view.shape[0]; e++, p += 3) {
+    int32_t fi = p[0], off = p[1], cols = p[2];
+    if (fi < 0 || (size_t)fi >= sc->fields.size() || off < 0 || cols < 0 ||
+        off + cols > width) {
+      PyErr_SetString(PyExc_ValueError, "write plan out of range");
+      Py_DECREF(buf_obj);
+      return nullptr;
+    }
+    const Field &f = sc->fields[fi];
+    Py_ssize_t start = 0;
+    for (Py_ssize_t i = 0; i < rows; i++) {
+      int32_t *dst = out + i * width + off;
+      Py_ssize_t len = 0;
+      if (i < sc->n) {
+        len = f.ends[i] - start;
+        if (len > cols) {
+          PyErr_SetString(PyExc_ValueError,
+                          "a row holds more cells than its columns");
+          Py_DECREF(buf_obj);
+          return nullptr;
+        }
+        if (len) std::memcpy(dst, f.cells.data() + start, len * sizeof *dst);
+        start = f.ends[i];
+      }
+      std::fill(dst + len, dst + cols, f.fill);
+    }
+  }
+  return buf_obj;
 }
 
 // ---- freeze: JSON-like tree -> frozen Rego value --------------------------
@@ -950,6 +1316,10 @@ PyMethodDef methods[] = {
      "encode per-entity rel-path values into padded buffers"},
     {"keyset", keyset, METH_VARARGS,
      "interned truthy keys at paths, dedup, minus exclusions"},
+    {"review_scratch", review_scratch, METH_VARARGS,
+     "a review batch's buffer fields and padded widths, interned in order"},
+    {"review_write", review_write, METH_VARARGS,
+     "lay a review_scratch into one [rows, width] int32 buffer by a plan"},
     {nullptr, nullptr, 0, nullptr}};
 
 PyModuleDef moduledef = {PyModuleDef_HEAD_INIT, "_gknative",

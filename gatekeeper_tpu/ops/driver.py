@@ -38,6 +38,7 @@ from ..metrics.catalog import (
     record_dispatch_upload,
     record_join_upkeep,
     record_render_cells,
+    record_review_pack,
     record_stage,
 )
 from ..obs import costs as obscosts
@@ -261,6 +262,11 @@ class TpuDriver(InterpDriver):
         # review-side layout -> (executable, layout), while `fn` stands
         self._fused_packed: dict = {}
         self._fused_packed_src = None
+        # beside it, per width tuple of the review side's data, the direct
+        # writer's (writer, executable, layout, write plan); and the writer
+        # of the column specs now in use
+        self._fused_plans: dict = {}
+        self._writer = None
         # multi-chip: data-parallel mesh over every visible device (None on
         # single-chip).  GK_MESH=0 forces the single-device path, GK_MESH=1
         # (the default) meshes every visible device, GK_MESH=<n> for n >= 2
@@ -1362,9 +1368,11 @@ class TpuDriver(InterpDriver):
             self._cs_epoch, self.interner.snapshot_size()
         )
 
-    def _device_inputs(self, reviews: List[dict]):
+    def _review_arrays(self, reviews: List[dict]):
         """Pack review-side arrays + columns; bring the constraint side's
-        str-pred tables current if these reviews interned new strings."""
+        str-pred tables current if these reviews interned new strings.
+        -> (fn, side, the column specs the columns were extracted for, rp,
+        cols)."""
         fn, side = self._fused_fn()
         col_specs = side[3]
         rp = pack_reviews(reviews, self.interner, self.store.cached_namespace)
@@ -1372,18 +1380,63 @@ class TpuDriver(InterpDriver):
         cols = extract_columns(reviews, col_specs, self.interner, rows)
         if not self._tables_cover_vocab():
             fn, side = self._fused_fn()
+        return fn, side, col_specs, rp, cols
+
+    @staticmethod
+    def _side_args(side):
+        """-> (ordered, cp, group_params, crow) of a constraint side."""
         ordered, cp, groups, _col_specs, crow = side
-        group_params = [packed for *_s, packed in groups]
+        return ordered, cp, [packed for *_s, packed in groups], crow
+
+    def _device_inputs(self, reviews: List[dict]):
+        fn, side, _specs, rp, cols = self._review_arrays(reviews)
+        ordered, cp, group_params, crow = self._side_args(side)
         return fn, ordered, rp, cp, cols, group_params, crow
+
+    def _review_writer(self, col_specs):
+        """The direct writer of these column specs (ops/reviewbuf.py
+        Writer), or None without the native extension."""
+        writer = self._writer
+        if writer is None or writer.specs is not col_specs:
+            from ..native import load as _load_native
+
+            native = _load_native()
+            if native is None:
+                return None
+            writer = self._writer = reviewbuf.Writer(native, col_specs)
+        return writer
 
     def _packed_inputs(self, reviews: List[dict]):
         """_device_inputs in the form a review dispatch takes: the review
         side as one [rows, width] buffer (ops/reviewbuf.py) beside the
         executable compiled for its layout.  -> (packed fn, ordered, buf,
-        extras, cp, group_params, crow)."""
-        fn, ordered, rp, cp, cols, group_params, crow = self._device_inputs(
-            reviews
-        )
+        extras, cp, group_params, crow).
+
+        A width tuple met before is written straight into the buffer by
+        the native writer and its plan (`path="direct"`); the first of a
+        width tuple, or any batch without the native extension, packs the
+        arrays, lays them out, and leaves the plan behind (`"arrays"`)."""
+        fn, side = self._fused_fn()
+        writer = self._review_writer(side[3])
+        if writer is not None:
+            rows = _bucket_pow2(len(reviews), 8)
+            scratch, widths = writer.extract(
+                reviews, self.interner, self.store.cached_namespace, rows
+            )
+            if not self._tables_cover_vocab():
+                fn, side = self._fused_fn()
+            hit = (self._fused_plans.get(widths)
+                   if self._fused_packed_src is fn else None)
+            if hit is not None and hit[0] is writer:
+                _writer, pv, layout, plan = hit
+                ordered, cp, group_params, crow = self._side_args(side)
+                buf = writer.write(scratch, plan, rows, layout.width)
+                record_review_pack("direct")
+                return pv, ordered, buf, (), cp, group_params, crow
+        # the arrays path: re-interning what the writer interned changes
+        # nothing, so the ids are those of a single pass
+        fn, side, col_specs, rp, cols = self._review_arrays(reviews)
+        ordered, cp, group_params, crow = self._side_args(side)
         rows = len(rp.arrays["valid"])
         tree = (rp.arrays, cols)
         leaves, treedef = jax.tree_util.tree_flatten(tree)
@@ -1391,6 +1444,12 @@ class TpuDriver(InterpDriver):
             fn, tree, rows, (treedef, reviewbuf.leaf_metas(leaves, rows))
         )
         buf, extras = layout.pack(leaves, rows)
+        writer = self._review_writer(col_specs)
+        plan = writer and writer.plan(layout, leaves, rp.arrays, cols)
+        if plan is not None:
+            self._fused_plans[reviewbuf.widths_of(
+                rp.arrays, cols, col_specs)] = (writer, pv, layout, plan)
+        record_review_pack("arrays")
         return pv, ordered, buf, extras, cp, group_params, crow
 
     def _mesh(self):
@@ -1702,6 +1761,7 @@ class TpuDriver(InterpDriver):
         widths that key an executable anyway."""
         if self._fused_packed_src is not fn:
             self._fused_packed = {}
+            self._fused_plans = {}
             self._fused_packed_src = fn
         got = self._fused_packed.get(key)
         if got is not None:

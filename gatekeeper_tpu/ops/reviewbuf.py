@@ -19,16 +19,26 @@ is not one of _BITS) stays an argument of its own: `extras`.
 
 Row-major on purpose: parallel/mesh.py partitions the same array on
 "data", so the mesh path takes the same form.
+
+A Writer fills the buffer from the reviews without the arrays: the native
+extension extracts every field's values (interning in the order
+`pack_reviews` and then `extract_columns` intern), reports the padded
+widths the batch's data decides, and lays the fields into their columns
+by a write plan.  A plan is derived from the Layout the arrays made the
+first time a width tuple was met (`Writer.plan`), so the buffer it writes
+is that Layout's, byte for byte.
 """
 
 from __future__ import annotations
 
-from typing import Any, List, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from .columns import _extract_joinkey
 
 _I32 = np.dtype(np.int32)
 _F32 = np.dtype(np.float32)
@@ -117,3 +127,86 @@ class Layout:
                 x = x.astype(dtype)
             leaves.append(x.reshape((rows,) + tail))
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
+
+
+# ---- the direct writer ------------------------------------------------------
+
+# the review side's fields in the order the native extension emits them:
+# pack_reviews' arrays, then each column spec's (columns.py) in spec order
+REVIEW_FIELDS = ("group", "kind", "ns_name", "ns_mode", "always", "ns_empty",
+                 "is_ns", "obj_empty", "old_empty", "autoreject", "valid",
+                 "obj_labels", "old_labels", "ns_labels")
+_SPEC_FIELDS = {"scalar": ("tcode", "sid", "num"),
+                "slot": ("tcode", "sid", "num", "mask"),
+                "keyset": ("ids",)}
+_KIND = {"scalar": 0, "slot": 1, "keyset": 2, "joinkey": 3}
+
+
+def spec_fields(spec) -> Tuple[str, ...]:
+    if spec.kind == "joinkey":
+        return ("sid", "mask") if spec.iter_paths else ("sid",)
+    return _SPEC_FIELDS[spec.kind]
+
+
+def widths_of(rv: Dict[str, np.ndarray], cols: Dict, specs) -> tuple:
+    """The padded widths of a packed review side, as the native extension
+    reports them for the same batch: the three label groups', then one a
+    column spec (its second axis; 0 for a scalar column)."""
+    firsts = [next(iter(cols[s.key].values())) for s in specs]
+    return tuple(rv[f].shape[1] for f in REVIEW_FIELDS[-3:]) + tuple(
+        a.shape[1] if a.ndim == 2 else 0 for a in firsts)
+
+
+class Writer:
+    """Writes one list of column specs' review side straight into the
+    dispatch buffer.  `extract` is the first pass (values and widths),
+    `write` the second (a plan's columns); `plan` derives the plan of a
+    width tuple from the Layout its arrays made."""
+
+    __slots__ = ("native", "specs", "descs", "groups")
+
+    def __init__(self, native, specs):
+        self.native = native
+        self.specs = specs
+        groups: Dict[Any, int] = {}
+        descs = []
+        for s in specs:
+            g = groups.setdefault(s.iter_key, len(groups)) \
+                if s.kind == "slot" else -1
+            descs.append((_KIND[s.kind], s.rel_path, tuple(s.iter_paths),
+                          tuple(s.exclude), g))
+        self.descs = tuple(descs)
+        self.groups = tuple(tuple(ik) for ik in groups)
+
+    def extract(self, reviews: List[dict], interner, cached_namespace,
+                rows: int):
+        """-> (scratch, widths).  Interns what the arrays path interns, in
+        its order; a join key column keeps its Python extraction."""
+        reviews = list(reviews)
+        specs = self.specs
+
+        def joinkey(i):
+            return _extract_joinkey(reviews, specs[i], interner, rows)
+
+        return self.native.review_scratch(
+            reviews, interner._ids, interner._strings, cached_namespace,
+            self.descs, self.groups, joinkey)
+
+    def write(self, scratch, plan: np.ndarray, rows: int, width: int):
+        return self.native.review_write(scratch, plan, rows, width)
+
+    def plan(self, layout: Layout, leaves: Sequence[np.ndarray],
+             rv: Dict[str, np.ndarray], cols: Dict) -> Optional[np.ndarray]:
+        """The write plan of `layout`: int32 [slots, 3] of (field, column
+        offset, columns), one row per leaf, `leaves` the flattened (rv,
+        cols) the layout was made from.  None where a leaf is an extra:
+        the arrays then serve every batch of this layout."""
+        field = {id(rv[f]): i for i, f in enumerate(REVIEW_FIELDS)}
+        for s in self.specs:
+            for f in spec_fields(s):
+                field[id(cols[s.key][f])] = len(field)
+        if len(field) != len(leaves) or any(s is None for s in layout.slots):
+            return None
+        return np.asarray([(field[id(x)], slot[0], slot[1])
+                           for x, slot in zip(leaves, layout.slots)],
+                          np.int32)
