@@ -49,7 +49,7 @@ def test_role_end_to_end(child_env, tmp_path, capsys):
     assert set(line) == KEYS
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 2
-    assert set(line["metrics"]) == {"setup_s", "audit_sweep_s"}
+    assert {"setup_s", "audit_sweep_s"} <= set(line["metrics"])
     assert line["device"]["platform"] == "cpu"  # run.py would refuse it
     layers = line_of(raw, CELL, "per_layer", capsys)["metrics"]
     assert JOIN_METRICS <= set(layers)
@@ -257,12 +257,11 @@ def test_new_cells_in_the_manifest():
             "max_steps_per_s": 4} == tr
     for cell in (CELL, CHURN2000):
         e2e = {x["name"] for x in harness.cell_metrics(m, cell, "end_to_end")}
-        assert e2e == {"setup_s", "audit_sweep_s"}
+        assert {"setup_s", "audit_sweep_s"} <= e2e
         layers = {x["name"] for x in
                   harness.cell_metrics(m, cell, "per_layer")}
         assert {"sweep_pack_ms", "sweep_render_ms", "sweep_full_share",
                 "device_idle_share.audit"} <= layers
-        assert "delta_sweep_hbm_roofline" not in layers
     cfg = procs.read_json(os.path.join(
         BENCH, "configs", "agilebank4x111k-audit.json"))
     assert (cfg["pods"], cfg["services"], cfg["namespaces"]) == (

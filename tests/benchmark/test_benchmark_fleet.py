@@ -7,7 +7,6 @@ that reads not correct there, and the test waives it and nothing else);
 the controls read not correct; and over a program without
 `fleet/placement.py` the role ends at once, non-zero, with a message."""
 
-import json
 import os
 import subprocess
 import sys
@@ -26,8 +25,6 @@ from test_benchmark_rehearsal import (  # noqa: F401  (child_env: fixture)
     tiny,
 )
 
-import test_benchmark_admission_inventory as admission  # noqa: E402
-import test_benchmark_cs_extend as cs_extend  # noqa: E402
 from lib import fleet  # noqa: E402
 from readers import share_min  # noqa: E402
 
@@ -74,65 +71,33 @@ def test_the_cell_resolves_to_its_files_and_says_what_it_is():
     assert tr["rate_per_s"] == cfg["replicas"] * paced["rate_per_s"]
     assert tr["warm_bursts"] == paced["warm_bursts"]
     assert (tr["warm_reviews"], tr["min_reviews"]) == (10000, 4000)
-    four = [w for w in manifest()["workloads"] if w["chips"] == 4]
-    assert [w["name"] for w in four] == [CELL]
+    # the contract's cap: at most half the cells, rounded down, ask for
+    # four chips, and one always may
+    cells = manifest()["workloads"]
+    four = [w["name"] for w in cells if w["chips"] == 4]
+    assert CELL in four and len(four) <= max(1, len(cells) // 2)
 
 
 def test_it_reports_setup_and_p50_and_the_per_layer_set_the_issue_lists():
     m = manifest()
     e2e = {x["name"] for x in harness.cell_metrics(m, CELL, "end_to_end")}
-    assert e2e == {"setup_s", "admit_p50_ms"}
+    assert {"setup_s", "admit_p50_ms"} <= e2e
     layers = {x["name"]: x for x in harness.cell_metrics(m, CELL, "per_layer")}
-    assert set(layers) == NEW | SHARED
+    assert NEW | SHARED <= set(layers)
     for name in NEW:
-        assert layers[name]["workloads"] == [CELL]
+        assert CELL in layers[name]["workloads"]
         assert layers[name]["moves"] == "admit_p50_ms"
     for name in SHARED:
         assert PACED in layers[name]["workloads"]
-    # new entries stand at the end of their lists
-    assert m["configs"][-1]["name"] == "synth500x100k-fleet4"
-    assert m["workloads"][-1]["name"] == CELL
-    assert {x["name"] for x in m["per_layer"][-len(NEW):]} == NEW
-    assert next(x for x in m["end_to_end"]
-                if x["name"] == "admit_p50_ms")["workloads"][-1] == CELL
-
-
-def _less_this_pr(m):
-    """BENCHMARK.json as it was before PR 36 appended to it."""
-    m = json.loads(json.dumps(m))
-    m["configs"] = [c for c in m["configs"]
-                    if c["name"] != "synth500x100k-fleet4"]
-    m["workloads"] = [w for w in m["workloads"] if w["name"] != CELL]
-    m["per_layer"] = [x for x in m["per_layer"] if x["name"] not in NEW]
-    for x in m["end_to_end"] + m["per_layer"]:
-        if CELL in x.get("workloads", ()):
-            x["workloads"].remove(CELL)
-    return m
-
-
-@pytest.mark.parametrize("pinned", [
-    admission.test_the_new_cell_in_the_manifest,
-    cs_extend.test_the_data_files_load_and_name_their_cells,
-], ids=lambda f: f.__name__)
-def test_what_was_there_stands_where_it_stood(pinned, monkeypatch):
-    """Two accepted tests pin `[-1]` of the manifest's lists, and the
-    contract has a PR append at the end, so both read red from their
-    first assert on (PERF.md section 7, 6i: a `benchmark` issue's).
-    Their bodies, whole, against the manifest less what this PR
-    appended: everything they hold still holds, and what is new came
-    after all of it."""
-    m, read = manifest(), procs.read_json
-
-    def read_json(path):
-        got = read(path)
-        return _less_this_pr(got) if path.endswith("BENCHMARK.json") else got
-
-    old = _less_this_pr(m)
-    for k in ("configs", "workloads", "per_layer"):
-        assert [x["name"] for x in m[k][:len(old[k])]] == [
-            x["name"] for x in old[k]]
-    monkeypatch.setattr(procs, "read_json", read_json)
-    pinned()
+    # the entries the cell brought, by name
+    config = {c["name"]: c for c in m["configs"]}["synth500x100k-fleet4"]
+    assert config["file"] == "benchmark/configs/synth500x100k-fleet4.json"
+    cell = {w["name"]: w for w in m["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"]) == ("synth500x100k-fleet4",
+                                                 "paced-unique1000")
+    assert NEW <= {x["name"] for x in m["per_layer"]}
+    assert CELL in next(x for x in m["end_to_end"]
+                        if x["name"] == "admit_p50_ms")["workloads"]
 
 
 def test_the_summed_scrape_adds_series_by_series():
@@ -281,7 +246,7 @@ def test_fleet_cell_rehearsed_on_the_cpu(child_env, tmp_path, capsys):
     raw, ctx = drive("webhook_fleet", cfg, traffic, tmp_path)
     line = line_of(raw, CELL, "end_to_end", capsys)
     assert set(line) == KEYS
-    assert set(line["metrics"]) == {"setup_s", "admit_p50_ms"}
+    assert {"setup_s", "admit_p50_ms"} <= set(line["metrics"])
     failing = {k for k, c in line["compared"].items()
                if not (c["value"] <= c["limit"] if "limit" in c
                        else c["value"] >= c["at_least"])}

@@ -87,7 +87,7 @@ def test_audit_cell_end_to_end(child_env, tmp_path, capsys):
     assert set(line) == KEYS
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 2
-    assert set(line["metrics"]) == {"setup_s", "audit_sweep_s"}
+    assert {"setup_s", "audit_sweep_s"} <= set(line["metrics"])
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert line["device"]["platform"] == "cpu"  # run.py would refuse it
     layers = line_of(raw, AUDIT_CELL, "per_layer", capsys)["metrics"]
@@ -235,7 +235,7 @@ def test_webhook_cell_end_to_end_and_broken(child_env, tmp_path, capsys):
     assert set(line) == KEYS
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 50
-    assert set(line["metrics"]) == {"setup_s", "admit_reviews_per_s"}
+    assert {"setup_s", "admit_reviews_per_s"} <= set(line["metrics"])
     layers = line_of(raw, SAT_CELL, "per_layer", capsys)["metrics"]
     for name in ("route_cal_rtt_ms.sat", "route_device_share.sat",
                  "compiles_in_window.sat", "batch_size_mean.sat",

@@ -46,14 +46,14 @@ def manifest():
 
 def test_new_metrics_are_data_over_the_readers_that_exist():
     per_layer = {m["name"]: m for m in manifest()["per_layer"]}
-    for name in AUDIT_NEW | SAT_NEW | PACED_NEW:
-        assert name in per_layer, name
-        spec = procs.read_json(os.path.join(BENCH, "metrics", name + ".json"))
-        assert spec["reader"] in ("prom_ratio", "mean_of", "value"), name
-        assert len(per_layer[name]["workloads"]) == 1
-    assert {per_layer[n]["workloads"][0] for n in AUDIT_NEW} == {AUDIT_CELL}
-    assert {per_layer[n]["workloads"][0] for n in SAT_NEW} == {SAT_CELL}
-    assert {per_layer[n]["workloads"][0] for n in PACED_NEW} == {PACED_CELL}
+    for names, cell in ((AUDIT_NEW, AUDIT_CELL), (SAT_NEW, SAT_CELL),
+                        (PACED_NEW, PACED_CELL)):
+        for name in names:
+            assert name in per_layer, name
+            spec = procs.read_json(os.path.join(BENCH, "metrics",
+                                                name + ".json"))
+            assert spec["reader"] in ("prom_ratio", "mean_of", "value"), name
+            assert cell in per_layer[name]["workloads"], name
 
 
 def test_audit_stage_metrics_are_in_the_per_layer_line(child_env, tmp_path,
